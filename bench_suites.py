@@ -6,7 +6,7 @@ ClickBench queries). This module runs every query type against datasets
 built through the normal write path, CHECKS each result against a numpy
 oracle over the same data, and reports warm per-query times. Not the
 headline — bench.py's primary shapes stay the contract — but full
-coverage so regressions in any query family surface in BENCH_r*.json.
+coverage so regressions in any query family surface in the bench record.
 
 Scale via CNOSDB_BENCH_SUITE_ROWS (default 1_000_000 hits rows,
 hits_rows // 4 readings rows).

@@ -1,8 +1,8 @@
 """Device-side decode plane: the TSM codecs as batched accelerator kernels.
 
 Cold scans were host-bound: every page decoded on the CPU (native or
-numpy) and only the finished arrays crossed the PCIe pipe (BENCH_r05:
-decode_ms 71 s cold vs 0.8 ms warm kernel time). Following "GPU
+numpy) and only the finished arrays crossed the PCIe pipe (a host-CPU
+bench: decode_ms 71 s cold vs 0.8 ms warm kernel time). Following "GPU
 Acceleration of SQL Analytics on Compressed Data" (arxiv 2506.10092),
 this module inverts that: host work stops at the byte-container stage
 (zstd et al — storage/codecs.split_for_device), the still-narrow
@@ -50,14 +50,10 @@ import jax.numpy as jnp
 from ..models.codec import Encoding
 from ..models.schema import ValueType
 from ..utils import stages
-from . import pallas_kernels
+from jax.experimental import pallas as pl
 
-try:  # pallas import is deferred-fail: CPU-only deployments keep working
-    from jax.experimental import pallas as pl
-    PALLAS_AVAILABLE = True
-except Exception:  # pragma: no cover
-    pl = None
-    PALLAS_AVAILABLE = False
+from . import pallas_kernels
+from .placement import exact_on_device
 
 # TPU lane width: value buckets are pow2 multiples of this, so the last
 # (vectorized) dimension always tiles cleanly
@@ -73,8 +69,8 @@ def enabled() -> bool:
 
 
 def disabled_reason() -> str | None:
-    """None when the lane is usable, else WHY not — bench.py reports it
-    next to pallas_disabled_reason so a silent fallback is visible."""
+    """None when the lane is usable, else WHY not — stamped into query
+    profiles next to pallas_disabled_reason."""
     mode = os.environ.get("CNOSDB_DEVICE_DECODE", "auto").lower()
     if mode in ("1", "on", "true"):
         return None
@@ -82,12 +78,9 @@ def disabled_reason() -> str | None:
         return f"disabled by env CNOSDB_DEVICE_DECODE={mode}"
     from .placement import scan_device
 
-    try:
-        dev = scan_device()
-    except Exception as e:  # no jax devices at all
-        return f"device probe failed: {e!r}"
-    if dev.platform != "tpu":
-        return f"scan device is {dev.platform!r}, not tpu (auto mode)"
+    platform = scan_device().platform
+    if platform != "tpu":
+        return f"scan device is {platform!r}, not tpu (auto mode)"
     return None
 
 
@@ -107,8 +100,7 @@ def note_engaged(n: int = 1) -> None:
 
 
 def engagements() -> int:
-    """Pages decoded by the device lane this process (bench.py records
-    this next to pallas_engagements so BENCH_r* shows lane adoption)."""
+    """Pages decoded by the device lane this process."""
     with _LOCK:
         return _engagements
 
@@ -204,14 +196,27 @@ def _make_xor_scan_body(steps: int):
     return body
 
 
+# the scan kernel's block is [_XOR_ROWS, width] u32: one sublane tile of
+# pages, the whole lane axis resident. Past _XOR_MAX_WIDTH the blocks and
+# their shifted temporaries no longer fit the kernel's 16 MiB of VMEM on
+# a v5e (a 2^18 bucket — the largest page — asks for 31.78M); those
+# buckets take the XLA scan.
+_XOR_ROWS = 8
+_XOR_MAX_WIDTH = 1 << 16
+
+
 def _pallas_xor_scan(x, interpret: bool):
+    """x: [b, width] u32, b a multiple of _XOR_ROWS, width a pow2 bucket."""
     b, width = x.shape
-    steps = max(width.bit_length() - 1, 0)   # width is a pow2 bucket
+    steps = max(width.bit_length() - 1, 0)
+    # index maps return i32 explicitly: under x64 a literal 0 is an i64,
+    # which Mosaic cannot legalize beside the i32 grid index
+    spec = pl.BlockSpec((_XOR_ROWS, width), lambda i: (i, jnp.int32(0)))
     return pl.pallas_call(
         _make_xor_scan_body(steps),
-        grid=(b,),
-        in_specs=[pl.BlockSpec((1, width), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, width), lambda i: (i, 0)),
+        grid=(b // _XOR_ROWS,),
+        in_specs=[spec],
+        out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((b, width), jnp.uint32),
         interpret=interpret,
     )(x)
@@ -266,12 +271,11 @@ class DeviceDecodeLane:
     }
 
     def __init__(self, interpret: bool | None = None):
-        if interpret is None:
-            from .placement import scan_device
-
-            interpret = scan_device().platform != "tpu"
-        self._interpret = bool(interpret)
-        self._use_pallas = PALLAS_AVAILABLE and pallas_kernels.enabled()
+        # interpret= is a test's explicit request; left alone, a TPU
+        # always compiles its kernels for the chip
+        self._interpret = pallas_kernels.interpret_mode() \
+            if interpret is None else bool(interpret)
+        self._use_pallas = pallas_kernels.enabled()
         self._jobs: list[_Job] = []
 
     def accepts(self, value_type: int, encoding: int) -> bool:
@@ -279,7 +283,14 @@ class DeviceDecodeLane:
         kernel at all? (String pages always submit — the container
         codec id is not page-visible without reading the block.)"""
         ok = self._NUMERIC_ENC.get(int(value_type))
-        return ok is not None and int(encoding) in ok
+        if ok is None or int(encoding) not in ok:
+            return False
+        if not exact_on_device(value_type):
+            # bit-identical decode is the lane's contract, and this device
+            # rounds an f64 the moment it holds one
+            self.declined("f64_inexact_on_device")
+            return False
+        return True
 
     def declined(self, reason: str, n: int = 1) -> None:
         """Book n pages the scan examined but routed to a host lane."""
@@ -356,13 +367,14 @@ class DeviceDecodeLane:
                 firsts[bi] = j.plan["first"]
             out = _delta_kernel(self._put(zz), self._put(firsts))
         elif kind == "gorilla":
+            b_pad = max(b_pad, _XOR_ROWS)
             planes = np.zeros((b_pad, 8, lane_len), dtype=np.uint8)
             for bi, j in enumerate(jobs):
                 n = j.plan["n"]
                 planes[bi, :, :n] = np.frombuffer(
                     j.plan["raw"], dtype=np.uint8).reshape(8, n)
             pd = self._put(planes)
-            if self._use_pallas:
+            if self._use_pallas and lane_len <= _XOR_MAX_WIDTH:
                 lo, hi = _gorilla_pre_kernel(pd)
                 lo = _pallas_xor_scan(lo, self._interpret)
                 hi = _pallas_xor_scan(hi, self._interpret)

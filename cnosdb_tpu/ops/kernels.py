@@ -276,29 +276,32 @@ def aggregate_column_host(values: np.ndarray, valid: np.ndarray,
     results back as numpy (sliced to num_segments by the caller).
 
     When the pallas segment kernel is enabled (ops/pallas_kernels.enabled:
-    CNOSDB_TPU_PALLAS=1 or a real TPU scan device) and the batch's segment
-    layout qualifies, the storage-layout-aware windowed kernel replaces
-    XLA's sort/scatter segment lowering; first/last (rank selection) and
-    disqualified layouts fall back to the XLA kernel below."""
+    CNOSDB_TPU_PALLAS=1 or a real TPU scan device) and this aggregation
+    qualifies (pallas_kernels.decline_reason: no first/last, a narrow
+    segment span per row tile, and on a TPU a 32-bit value dtype), the
+    storage-layout-aware windowed kernel replaces XLA's sort/scatter
+    segment lowering; everything else books the reason and takes the XLA
+    kernel below."""
     n = len(values)
     np_pad = pad_rows(max(n, 1))
     ns_pad = pad_segments(max(num_segments, 1))
     from . import pallas_kernels as pk
 
-    if pk.enabled() and not (wants.get("want_first")
-                             or wants.get("want_last")) and n \
-            and pk.applicable(seg_ids) is not None:
-        # cheap O(n/R_TILE) layout check BEFORE any padding copies —
-        # disqualified layouts fall straight through to the XLA path.
-        # Pad seg with the edge value (not 0) so trailing tiles keep
-        # their narrow window; padded rows are valid=False either way
-        v2 = _pad(values, np_pad)
-        ok2 = _pad(valid, np_pad, fill=False)
-        sg2 = _pad(seg_ids, np_pad, fill=seg_ids[n - 1])
-        out = pk.segment_partials_pallas(
-            v2, ok2, sg2.astype(np.int32, copy=False), ns_pad, wants=wants,
-            interpret=jax.default_backend() != "tpu")
-        if out is not None:
+    if pk.enabled() and n:
+        # routing BEFORE any padding copy or launch (the layout check is
+        # O(n/R_TILE))
+        reason = pk.decline_reason(values.dtype, wants, seg_ids)
+        if reason is not None:
+            pk.note_declined(reason)
+        else:
+            # pad seg with the edge value (not 0) so trailing tiles keep
+            # their narrow window; padded rows are valid=False either way
+            v2 = _pad(values, np_pad)
+            ok2 = _pad(valid, np_pad, fill=False)
+            sg2 = _pad(seg_ids, np_pad, fill=seg_ids[n - 1])
+            out = pk.segment_partials_pallas(
+                v2, ok2, sg2.astype(np.int32, copy=False), ns_pad,
+                wants=wants, interpret=pk.interpret_mode())
             pk.note_engaged()
             host = {k: v[:num_segments] for k, v in out.items()}
             if "count" in host:

@@ -165,14 +165,18 @@ def try_mesh_aggregate(batches, query):
     n_dev = mesh.mesh_size(m)
     if n_dev < _env_int("CNOSDB_MESH_MIN_DEVICES", 2):
         return _declined("few_devices")
+    from .placement import exact_on_device
+
     for b in live:
         for a in aggs:
             if a.column is None or a.column == "time":
                 continue
             f = b.fields.get(a.column)
-            if f is None or f[0] not in _NUMERIC_VTS:
+            if f is None or f[0] not in _NUMERIC_VTS \
+                    or not exact_on_device(f[0]):
                 # absent column (could be a tag → string agg), unsigned
-                # bias games, booleans, strings: legacy lanes own those
+                # bias games, booleans, strings — and FLOAT on a device
+                # that would round it: legacy lanes own those
                 return _declined("value_dtype")
     try:
         prep = _build_prep(live, query, m, n_dev)

@@ -337,13 +337,19 @@ def topk_order_indices(vals: np.ndarray, nulls, asc: bool,
         return None
     thr = None
     if not asc and vals.dtype.kind in "iuf" and _topk_device_wanted():
-        try:
-            from . import kernels
+        from .placement import f64_exact
 
-            thr = kernels.topk_threshold(vals, k)   # 0-d np scalar
-            stages.count("topk.device", 1)
-        except Exception:
-            thr = None
+        if vals.dtype.kind == "f" and not f64_exact():
+            # a threshold the device rounded up would drop the k-th row
+            stages.count("f64_kept_on_host")
+        else:
+            try:
+                from . import kernels
+
+                thr = kernels.topk_threshold(vals, k)   # 0-d np scalar
+                stages.count("topk.device", 1)
+            except Exception:
+                thr = None
     if thr is None:
         stages.count("topk.host", 1)
         part = np.partition(vals, k - 1 if asc else n - k)

@@ -66,23 +66,14 @@ def reset_counters() -> None:
 
 
 def make_mesh(n_devices: int | None = None) -> Mesh:
+    """A 1-D mesh over the default backend's first n devices; raises when
+    it has fewer (no quiet mesh of host CPU devices beside a chip)."""
     devs = jax.devices()
-    if n_devices is not None and n_devices > len(devs):
-        # default backend short on devices (e.g. one real TPU): fall back to
-        # the host platform, which xla_force_host_platform_device_count can
-        # expand into a virtual mesh
-        try:
-            cpu = jax.devices("cpu")
-        except Exception:
-            cpu = []
-        if len(cpu) >= n_devices:
-            devs = cpu
-        else:
-            raise ValueError(
-                f"requested {n_devices} devices, have {len(devs)} "
-                f"(+{len(cpu)} cpu); set XLA_FLAGS="
-                f"--xla_force_host_platform_device_count={n_devices}")
     if n_devices is not None:
+        if n_devices > len(devs):
+            raise ValueError(
+                f"requested {n_devices} devices, the {devs[0].platform} "
+                f"backend has {len(devs)}")
         devs = devs[:n_devices]
     return Mesh(np.array(devs), (SHARD_AXIS,))
 

@@ -1057,6 +1057,13 @@ class HttpServer:
             for (lane, reason), n in _mx.outcomes_snapshot().items():
                 self.metrics.set_counter("cnosdb_mesh_total", n,
                                          lane=lane, reason=reason)
+        # persistent compilation cache: hits/misses of this process's
+        # compiles (a restarted node should show hits, not recompiles)
+        _ops = _sys.modules.get("cnosdb_tpu.ops")
+        if _ops is not None:
+            for outcome, n in _ops.compile_cache_snapshot().items():
+                self.metrics.set_counter("cnosdb_compile_cache_total", n,
+                                         outcome=outcome)
         _mv = _sys.modules.get("cnosdb_tpu.sql.matview")
         if _mv is not None:
             for name, n in _mv.counters_snapshot().items():
@@ -1349,6 +1356,17 @@ def run_server(args) -> int:
     mode = getattr(args, "mode", "singleton")
     if mode == "meta":
         return run_meta_server(args)
+    # resolve the scan device now: a node that cannot initialize the
+    # backend it was given fails here, at start, and says once what
+    # every later query profile will repeat
+    from .. import ops
+    from ..ops import placement
+
+    dev = placement.device_stamp()
+    print(f"scan device: platform={dev['platform']} "
+          f"kind={dev['device_kind']!r} count={dev['device_count']} "
+          f"f64_exact={dev['f64_exact']} "
+          f"(compile cache {ops.compile_cache_dir()})", flush=True)
     if getattr(args, "meta", None):
         server = build_cluster_node(
             args.data_dir, args.meta, getattr(args, "node_id", 1) or 1,
@@ -1476,15 +1494,23 @@ def run_server(args) -> int:
         main._ttl_task = asyncio.get_running_loop().create_task(ttl_job())
         print(f"cnosdb-tpu listening on :{args.http_port} "
               f"(data dir {args.data_dir}, mode {getattr(args, 'mode', 'singleton')})")
-        while True:
-            await asyncio.sleep(3600)
+        # SIGINT through the loop's own handler (it owns a wakeup fd): the
+        # default handler only runs when the main thread next executes
+        # Python, and in a process with dozens of threads the signal
+        # rarely lands on the one thread asleep in select()
+        import signal
+
+        stop = asyncio.Event()
+        asyncio.get_running_loop().add_signal_handler(signal.SIGINT, stop.set)
+        await stop.wait()
 
     try:
         asyncio.run(main())
     except KeyboardInterrupt:
-        if server.scrubber is not None:
-            server.scrubber.stop()
-        server.coord.close()
+        pass
+    if server.scrubber is not None:
+        server.scrubber.stop()
+    server.coord.close()
     return 0
 
 

@@ -50,6 +50,11 @@ STAGE_CATALOG: dict[str, str] = {
                                  "lane instead of a host lane",
     "upload_ms": "host→device column uploads",
     "upload_bytes": "bytes moved host→device by those uploads",
+    "fused_launches": "fused filter/bucket/segment programs launched",
+    "f64_kept_on_host": "aggregations / top-k selections over FLOAT "
+                        "columns kept on the host kernels because the "
+                        "scan device does not hold f64 exactly "
+                        "(ops/placement.f64_exact)",
     "kernel_ms": "fused segment-aggregate kernels",
     "merge_ms": "cross-vnode partial merge / device delta-merge",
     "finalize_ms": "vectorized finalizers + output rendering",
@@ -61,6 +66,9 @@ STAGE_CATALOG: dict[str, str] = {
     "distinct_path.device": "count(DISTINCT) via the jax segment kernels",
     "distinct_path.fallback": "count(DISTINCT) via the scalar set fold",
     "pallas_engagements": "aggregations that ran through a Pallas kernel",
+    "pallas_declined": "aggregations the enabled Pallas segment kernel "
+                       "routed to the XLA kernel (reason in the profile's "
+                       "device telemetry)",
     "kernel_cache.hit": "segment-geometry/program cache hits on the "
                         "device batch (compile/derive skipped)",
     "kernel_cache.miss": "segment-geometry/program cache misses "
@@ -247,8 +255,8 @@ class QueryProfile:
     # ---------------------------------------------------------- rendering
     def snapshot(self) -> dict:
         """Local stage map, bench wire shape: rounded `*_ms` floats
-        merged with integer counters, sorted by key (the format BENCH_r*
-        `stages_warm`/`stages_cold` fields have always used)."""
+        merged with integer counters, sorted by key (bench.py's
+        `stages_warm`/`stages_cold` fields)."""
         with self._lock:
             out = {k: round(v, 2) for k, v in sorted(self.ms.items())}
             out.update(sorted(self.counts.items()))
@@ -334,6 +342,16 @@ class QueryProfile:
                 self.device["device_decode_enabled"] = dd.enabled()
                 self.device["device_decode_disabled_reason"] = \
                     dd.disabled_reason()
+            except Exception:  # telemetry stamp must never fail the query
+                pass
+        pl = sys.modules.get("cnosdb_tpu.ops.placement")
+        if pl is not None:
+            # the resolved scan device: a server resolves it at start, so
+            # every served query's profile says what it ran on
+            ops = sys.modules["cnosdb_tpu.ops"]
+            try:
+                self.device.update(pl.device_stamp())
+                self.device["compile_cache_dir"] = ops.compile_cache_dir()
             except Exception:  # telemetry stamp must never fail the query
                 pass
         return self

@@ -1,0 +1,216 @@
+"""Compile rehearsal: the served path's device programs, lowered and
+compiled for a described (not attached) TPU v5e at the shapes chip_smoke.py
+gives them — 1000 hosts x 6 h of TSBS devops cpu (2.16 M rows, a 4 Mi row
+size class, 8192 segments, 4096-value pages).
+
+The chip's compiler is installed in the sandbox and refuses here what it
+would refuse on the chip: block shapes off the (8, 128) rule, 64-bit
+operands of a pallas_call, kernels past their fast-memory limit. Nothing
+runs, so nothing here says anything about results or times.
+
+One file, and the topology is described inside a module-scoped fixture:
+only one process may load the TPU's library, the suite runs under several
+workers, and every worker imports every test file.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from cnosdb_tpu.ops import device_decode as dd
+from cnosdb_tpu.ops import fused, kernels
+from cnosdb_tpu.ops import pallas_kernels as pk
+from cnosdb_tpu.sql.expr import BinOp, Column, Literal
+
+ROWS = 1 << 22           # pad_rows(2_160_000)
+SEGMENTS = 8192          # pad_segments(1000 hosts x 6 hourly buckets)
+PAGES, PAGE_LEN = 16384, 4096    # one page per series per field, 2160 rows
+FIELDS = tuple(f"usage_{i}" for i in range(10))
+ALL_SIX = dict(want_count=True, want_sum=True, want_min=True, want_max=True,
+               want_first=True, want_last=True)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep it off around these
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    """shape, dtype → a ShapeDtypeStruct placed on one described chip."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return make
+
+
+def _compile(fn, *args, **static):
+    return fn.lower(*args, **static).compile()
+
+
+# ------------------------------------------------------- segment aggregate
+@pytest.mark.parametrize("dtype", [jnp.float64, jnp.int64])
+def test_xla_segment_aggregate(spec, dtype):
+    _compile(kernels.segment_aggregate,
+             spec((ROWS,), dtype), spec((ROWS,), jnp.bool_),
+             spec((ROWS,), jnp.int32), spec((ROWS,), jnp.int32),
+             num_segments=SEGMENTS, **ALL_SIX)
+
+
+# ------------------------------------------------------------ fused program
+def _fused_args(spec, n_cols, use_bucket, need_rank, n_series=1000):
+    # launch_fused's argument order for an irregular, second-aligned batch:
+    # [ts_sec], sid_ordinal, [rank], packed params, the value columns
+    args = [spec((ROWS,), jnp.int32)] * (1 + use_bucket + need_rank)
+    args.append(spec((4 + n_series,), jnp.int32))    # packed params
+    args += [spec((ROWS,), jnp.int64)] * n_cols
+    return args
+
+
+FUSED_SHAPES = {
+    # avg of every field by hour x host
+    "double-groupby-all": (None, {f: {"want_sum": True} for f in FIELDS},
+                           6, True),
+    # the pushed-down value filter under an aggregate
+    "filtered": (BinOp(">", Column(FIELDS[0]), Literal(90.0)),
+                 {FIELDS[0]: {"want_max": True, "want_sum": True}}, 6, True),
+    # last value of every field per host: rank selection, no buckets
+    "lastpoint": (None, {f: {"want_last": True} for f in FIELDS}, 1, False),
+}
+
+
+@pytest.mark.parametrize("shape", list(FUSED_SHAPES))
+def test_fused_program(spec, shape):
+    flt, col_wants, n_buckets, use_bucket = FUSED_SHAPES[shape]
+    present = tuple(sorted(col_wants))
+    need_rank = any(w.get("want_last") for w in col_wants.values())
+    fn, manifest = fused._build_kernel(
+        flt, col_wants, present, SEGMENTS, n_buckets, use_bucket, 3600,
+        need_rank, (False,) * len(present), False, False, ROWS)
+    _compile(fn, *_fused_args(spec, len(present), use_bucket, need_rank))
+    assert len(manifest) > len(present)
+
+
+# ----------------------------------------------------------- decode kernels
+def test_delta_kernel(spec):
+    _compile(dd._delta_kernel, spec((PAGES, PAGE_LEN), jnp.uint8),
+             spec((PAGES,), jnp.int64))
+
+
+def test_delta_const_kernel(spec):
+    _compile(dd._delta_const_kernel, spec((1024,), jnp.int64),
+             spec((1024,), jnp.int64), length=PAGE_LEN)
+
+
+def test_gorilla_xla_kernel(spec):
+    _compile(dd._gorilla_xla_kernel, spec((1024, 8, PAGE_LEN), jnp.uint8))
+
+
+def test_bitpack_kernel(spec):
+    _compile(dd._bitpack_kernel, spec((1024, PAGE_LEN // 8), jnp.uint8))
+
+
+def test_codes_kernel(spec):
+    _compile(dd._codes_kernel, spec((1024, PAGE_LEN), jnp.uint16))
+
+
+# ------------------------------------------------------------ Pallas kernels
+@pytest.mark.parametrize("shape", [(dd._XOR_ROWS, 128), (1024, PAGE_LEN),
+                                   (dd._XOR_ROWS, dd._XOR_MAX_WIDTH)])
+def test_pallas_xor_scan(spec, shape):
+    """The Gorilla lane's scan kernel, from the smallest bucket to the
+    widest the lane routes to it."""
+    _compile(jax.jit(lambda x: dd._pallas_xor_scan(x, False)),
+             spec(shape, jnp.uint32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
+def test_pallas_segment_kernel_32bit(spec, dtype):
+    n = 1 << 16
+    _compile(pk._windowed_partials, spec((n // pk.R_TILE,), jnp.int32),
+             spec((n,), dtype), spec((n,), jnp.bool_), spec((n,), jnp.int32),
+             num_segments=4096, interpret=False)
+
+
+@pytest.mark.parametrize("dtype,words", [
+    (jnp.float64, "not contain X64 element types"),
+    (jnp.int64, "int64 not implemented")])
+def test_pallas_segment_kernel_64bit_is_refused(spec, dtype, words):
+    """What the chip's compiler says to the engine's own column types —
+    the reason decline_reason() keeps them off the kernel on a TPU."""
+    n = 1 << 16
+    with pytest.raises(Exception, match=words):
+        _compile(pk._windowed_partials, spec((n // pk.R_TILE,), jnp.int32),
+                 spec((n,), dtype), spec((n,), jnp.bool_),
+                 spec((n,), jnp.int32), num_segments=4096, interpret=False)
+
+
+@pytest.mark.parametrize("dtype", [np.float64, np.int64, np.uint64])
+def test_64bit_columns_are_routed_off_the_pallas_kernel_on_a_tpu(
+        monkeypatch, dtype):
+    """No Pallas kernel the compiler refuses is reachable on the chip: with
+    interpret mode off (a TPU), every 64-bit aggregation declines before
+    any launch; in interpret mode (a CPU backend) it does not."""
+    seg = np.zeros(512, dtype=np.int32)
+    wants = {"want_sum": True}
+    monkeypatch.setattr(pk, "interpret_mode", lambda: False)
+    assert "64-bit" in pk.decline_reason(dtype, wants, seg)
+    assert pk.decline_reason(np.float32, wants, seg) is None
+    monkeypatch.setattr(pk, "interpret_mode", lambda: True)
+    assert pk.decline_reason(dtype, wants, seg) is None
+
+
+# ------------------------------------------------- the mesh lane, four chips
+@pytest.mark.parametrize("wants", [("count", "sum"), ("count", "last")])
+def test_mesh_merge_kernel_on_four_chips(topo, wants):
+    """chip_smoke.py --chips 4: eight shards over a 2x2 host, two batches
+    per device, one shard_map program per column with all_gather folds."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from cnosdb_tpu.parallel.distributed_agg import mesh_merge_kernel
+    from cnosdb_tpu.parallel.mesh import SHARD_AXIS
+
+    mesh = Mesh(np.array(topo.devices), (SHARD_AXIS,))
+    rows = NamedSharding(mesh, P(SHARD_AXIS))
+    total = len(topo.devices) * (1 << 19)    # 2 x 225k rows per device
+
+    def arr(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=rows)
+
+    compiled = _compile(
+        mesh_merge_kernel, arr(total, jnp.int64), arr(total, jnp.bool_),
+        arr(total, jnp.int32), arr(total, jnp.int32),
+        arr(len(topo.devices), jnp.int32), arr(len(topo.devices), jnp.int32),
+        mesh=mesh, slots=2, num_segments=SEGMENTS, wants=wants, run_pad=0)
+    assert "all-gather" in compiled.as_text()
+
+
+# ------------------------------------------- distinct / top-k, one size class
+# The smallest classes only: a 64-bit sort's compile time for this chip
+# grows steeply with the size class (top_k f64: 0.5 s at 2^12, 9 s at 2^14,
+# 48 s at 2^16 in this sandbox), and the smoke's queries use neither.
+def test_segment_distinct(spec):
+    _compile(kernels._segment_distinct, spec((1 << 12,), jnp.int64),
+             spec((), jnp.int64), num_segments=1024)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float64, jnp.int64])
+def test_topk_threshold(spec, dtype):
+    _compile(kernels._topk_threshold, spec((1 << 12,), dtype), k=10)

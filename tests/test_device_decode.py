@@ -142,8 +142,6 @@ def test_pallas_gorilla_path_parity(rng, monkeypatch):
     from cnosdb_tpu.ops import pallas_kernels
 
     monkeypatch.setenv("CNOSDB_TPU_PALLAS", "1")
-    if not device_decode.PALLAS_AVAILABLE:
-        pytest.skip("pallas import unavailable")
     vals = rng.normal(0.0, 100.0, 777)
     block = codecs.encode(vals, ValueType.FLOAT, Encoding.GORILLA)
     host = codecs.decode(block, ValueType.FLOAT)

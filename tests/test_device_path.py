@@ -113,6 +113,94 @@ def test_fused_kernel_actually_launches(db, monkeypatch):
     assert fused.launch_count > before
 
 
+# ---------------------------------------------------------------------------
+# a device without exact f64 (a TPU carries one as an f32 pair: ~49 bits
+# of mantissa, f32's range): FLOAT columns stay on the exact host lanes
+# ---------------------------------------------------------------------------
+@pytest.fixture
+def rounding_device(monkeypatch):
+    from cnosdb_tpu.ops import placement
+
+    monkeypatch.setenv("CNOSDB_TPU_FORCE_DEVICE_PATH", "1")
+    monkeypatch.setattr(placement, "_f64_exact", False)
+
+
+def _profiled(ex, sql):
+    from cnosdb_tpu.utils import stages
+
+    prof = stages.QueryProfile()
+    with stages.profile_scope(prof):
+        out = _run(ex, sql)
+    return out, prof.counts
+
+
+@pytest.mark.parametrize("sql", [
+    "SELECT host, min(usage), max(usage), first(usage), last(load) "
+    "FROM cpu GROUP BY host ORDER BY host",
+    "SELECT host, sum(cnt) FROM cpu WHERE usage > 40 GROUP BY host "
+    "ORDER BY host",                       # FLOAT only in the filter
+    "SELECT count(*) FROM cpu WHERE host = 'h1' AND load < 1.2",
+])
+def test_float_queries_stay_on_host_kernels(db, sql, monkeypatch,
+                                            rounding_device):
+    before = fused.launch_count
+    got, counts = _profiled(db, sql)
+    assert counts.get("f64_kept_on_host") and not counts.get("fused_launches")
+    assert fused.launch_count == before
+    monkeypatch.setenv("CNOSDB_TPU_FORCE_DEVICE_PATH", "0")
+    assert got == _run(db, sql)            # bit for bit the host answer
+
+
+def test_integer_queries_still_launch_fused(db, rounding_device):
+    _got, counts = _profiled(
+        db, "SELECT host, max(cnt), sum(cnt) FROM cpu GROUP BY host "
+            "ORDER BY host")
+    assert counts.get("fused_launches") and not counts.get("f64_kept_on_host")
+
+
+def test_float_columns_get_no_device_twin(rounding_device):
+    from cnosdb_tpu.models.schema import ValueType
+    from cnosdb_tpu.ops.device_cache import EagerUploader, _device_resident
+
+    assert not _device_resident(ValueType.FLOAT)
+    assert _device_resident(ValueType.INTEGER)
+    assert _device_resident(ValueType.BOOLEAN)
+    up = EagerUploader(8)
+    up.put("usage", ValueType.FLOAT, np.ones(8), np.ones(8, dtype=bool))
+    up.put("cnt", ValueType.INTEGER, np.ones(8, dtype=np.int64),
+           np.ones(8, dtype=bool))
+    assert list(up._cols) == ["cnt"]
+
+
+def test_gorilla_pages_decline_the_device_decode_lane(rounding_device):
+    from cnosdb_tpu.models.codec import Encoding
+    from cnosdb_tpu.models.schema import ValueType
+    from cnosdb_tpu.ops import device_decode
+
+    before = device_decode.outcomes_snapshot().get(
+        ("host", "f64_inexact_on_device"), 0)
+    lane = device_decode.DeviceDecodeLane(interpret=True)
+    assert not lane.accepts(int(ValueType.FLOAT), int(Encoding.GORILLA))
+    assert lane.accepts(int(ValueType.INTEGER), int(Encoding.DELTA))
+    assert device_decode.outcomes_snapshot()[
+        ("host", "f64_inexact_on_device")] == before + 1
+
+
+def test_float_topk_threshold_stays_on_host(monkeypatch, rounding_device):
+    from cnosdb_tpu.ops import strkernels
+    from cnosdb_tpu.utils import stages
+
+    monkeypatch.setenv("CNOSDB_TPU_TOPK", "1")
+    vals = np.random.default_rng(3).normal(0, 1, 1000)
+    prof = stages.QueryProfile()
+    with stages.profile_scope(prof):
+        idx = strkernels.topk_order_indices(vals, None, False, 10)
+        strkernels.topk_order_indices(np.arange(1000), None, False, 10)
+    assert idx.tolist() == np.argsort(-vals, kind="stable")[:10].tolist()
+    assert prof.counts == {"f64_kept_on_host": 1, "topk.host": 1,
+                           "topk.device": 1}
+
+
 def test_sqllogic_aggregates_forced_device(db, monkeypatch, tmp_path):
     """The aggregate slt matrix re-runs under the forced device placement
     (fresh database per file, same golden expectations)."""

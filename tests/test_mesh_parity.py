@@ -202,6 +202,23 @@ def test_mesh_off_books_disabled_and_never_engages(seeded, monkeypatch):
     assert snap.get(("exec", "disabled"), 0) == 6, snap
 
 
+def test_float_aggregates_decline_on_a_device_that_rounds_f64(
+        seeded, monkeypatch):
+    """A TPU carries an f64 as an f32 pair: the lane books value_dtype for
+    FLOAT aggregates there and the exact legacy merge answers; integer
+    aggregates still engage."""
+    from cnosdb_tpu.ops import placement
+
+    ex, s, _rows = seeded
+    monkeypatch.setattr(placement, "_f64_exact", False)
+    mesh.reset_counters()
+    _run_all(ex, s, ["SELECT host, min(v) mn, last(v) l FROM m GROUP BY host",
+                     "SELECT host, max(i) mx, sum(i) si FROM m GROUP BY host"])
+    snap = mesh.outcomes_snapshot()
+    assert snap.get(("exec", "value_dtype")) == 1, snap
+    assert snap.get(("exec", "engaged")) == 1, snap
+
+
 def test_device_loss_falls_back_bit_identical(seeded, monkeypatch):
     """The nemesis `device_loss` injection (mesh.collective:fail) kills
     the merge kernel mid-collective: the lane must book device_loss,

@@ -7,12 +7,8 @@ window-boundary layouts, the applicable() fallback, and the
 aggregate_column_host integration behind CNOSDB_TPU_PALLAS=1.
 """
 import numpy as np
-import pytest
 
 from cnosdb_tpu.ops import kernels, pallas_kernels as pk
-
-pytestmark = pytest.mark.skipif(
-    not pk.PALLAS_AVAILABLE, reason="pallas not importable")
 
 ALL4 = {"want_count": True, "want_sum": True,
         "want_min": True, "want_max": True}
