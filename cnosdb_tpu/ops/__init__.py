@@ -12,9 +12,12 @@ that does:
   small programs (one per filter/aggregate/shape class), so every
   compile is kept, not only those above JAX's one-second default.
 
+* every jitted program has a stable name on the device (`program`).
+
 Host-only layers (models/storage) do not import this, keeping
 pure-metadata use of cnosdb_tpu jax-free.
 """
+import functools
 import os
 import threading
 
@@ -44,6 +47,23 @@ def _on_jax_event(event: str, **_kw) -> None:
 
 
 jax.monitoring.register_event_listener(_on_jax_event)
+
+
+def program(name: str):
+    """Give a to-be-jitted function a stable identity on the device:
+    `__name__` "cnosdb_<name>" (so its XLA module is `jit_cnosdb_<name>`,
+    whatever closure or lambda it came from) and its whole body under
+    `jax.named_scope("cnosdb.<name>")` (so every HLO operation's op name
+    carries the program in a profiler trace). Metadata only: the compiled
+    code is the same."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def body(*args, **kwargs):
+            with jax.named_scope("cnosdb." + name):
+                return fn(*args, **kwargs)
+        body.__name__ = body.__qualname__ = "cnosdb_" + name
+        return body
+    return deco
 
 
 def compile_cache_dir() -> str | None:

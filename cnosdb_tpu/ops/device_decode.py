@@ -52,7 +52,7 @@ from ..models.schema import ValueType
 from ..utils import stages
 from jax.experimental import pallas as pl
 
-from . import pallas_kernels
+from . import pallas_kernels, program
 from .placement import exact_on_device
 
 # TPU lane width: value buckets are pow2 multiples of this, so the last
@@ -126,6 +126,7 @@ def _pow2(n: int, minimum: int) -> int:
 # kernels (pure XLA; gorilla optionally via Pallas)
 # ---------------------------------------------------------------------------
 @jax.jit
+@program("decode_delta")
 def _delta_kernel(zz, firsts):
     """[B, L] narrow zigzag deltas + [B] firsts -> [B, L] i64 values.
 
@@ -142,6 +143,7 @@ def _delta_kernel(zz, firsts):
 
 
 @functools.partial(jax.jit, static_argnames=("length",))
+@program("decode_delta_const")
 def _delta_const_kernel(firsts, strides, length):
     """Constant-stride timestamp fast path: first + stride * iota."""
     idx = jnp.arange(length, dtype=jnp.int64)
@@ -163,6 +165,7 @@ def _combine_f64(lo, hi):
 
 
 @jax.jit
+@program("decode_gorilla")
 def _gorilla_xla_kernel(planes):
     """Gorilla f64: untranspose + prefix-XOR scan, XOR running as two
     independent u32 planes (XOR is bytewise, so the split is exact)."""
@@ -173,11 +176,13 @@ def _gorilla_xla_kernel(planes):
 
 
 @jax.jit
+@program("decode_gorilla_pre")
 def _gorilla_pre_kernel(planes):
     return _assemble_planes(planes)
 
 
 @jax.jit
+@program("decode_gorilla_post")
 def _gorilla_post_kernel(lo, hi):
     return _combine_f64(lo, hi)
 
@@ -223,6 +228,7 @@ def _pallas_xor_scan(x, interpret: bool):
 
 
 @jax.jit
+@program("decode_bitpack")
 def _bitpack_kernel(packed):
     """[B, Lb] packed u8 -> [B, Lb*8] 0/1 u8 (MSB-first, np.packbits)."""
     shifts = jnp.arange(7, -1, -1, dtype=jnp.uint8)
@@ -231,6 +237,7 @@ def _bitpack_kernel(packed):
 
 
 @jax.jit
+@program("decode_codes")
 def _codes_kernel(codes):
     """Narrow dictionary codes -> i32 (the DictArray code dtype)."""
     return codes.astype(jnp.int32)
@@ -316,7 +323,11 @@ class DeviceDecodeLane:
     def run(self) -> list:
         """Execute every submitted page as batched kernels; → failed
         tokens for the caller's Python lane. Every page leaves here
-        either decoded or reason-booked (device-decode-accounting rule)."""
+        either decoded or reason-booked (device-decode-accounting rule).
+
+        Per group the lane's device round trips are stages of their own —
+        put and launch (`_run_group`), then the pull — booked once per
+        group, never per page."""
         failed: list = []
         groups: dict = {}
         for j in self._jobs:
@@ -330,9 +341,11 @@ class DeviceDecodeLane:
                     count_outcome("host", "kernel_error")
                     failed.append(j.token)
                 continue
-            for j, dev in zip(jobs, dev_rows):
+            with stages.stage("device_decode.pull_ms"):
+                dense = [np.asarray(dev) for dev in dev_rows]  # lint: disable=host-sync (audited transfer point: the decode lane's one pull per page row)
+            for j, dev, host in zip(jobs, dev_rows, dense):
                 j.dev = dev
-                self._writeback(j, np.asarray(dev))  # lint: disable=host-sync (audited transfer point: the decode lane's one pull per row group)
+                self._writeback(j, host)
             count_outcome("device", "ok", len(jobs))
             note_engaged(len(jobs))
         return failed
@@ -347,8 +360,20 @@ class DeviceDecodeLane:
 
     def _run_group(self, key, jobs):
         """One (kind, width, length-bucket) batch -> per-job device rows
-        (each sliced to its true value count, still on device)."""
+        (each sliced to its true value count, still on device). Pack the
+        pages into padded host buffers, put them, launch the kernel and
+        the per-page slices: the two device steps are stages."""
         kind, width, lane_len = key
+        packed = self._pack_group(kind, width, lane_len, jobs)
+        with stages.stage("device_decode.put_ms"):
+            operands = [self._put(a) for a in packed]
+        with stages.stage("device_decode.launch_ms"):
+            out = self._launch_group(kind, lane_len, operands)
+            return [out[bi, :j.plan["n"]] for bi, j in enumerate(jobs)]
+
+    def _pack_group(self, kind, width, lane_len, jobs) -> list:
+        """→ the group's kernel operands as host arrays, rows padded to
+        the lane length and the batch to a pow2."""
         b_pad = _pow2(len(jobs), 1)
         if kind == "delta_const":
             firsts = np.zeros(b_pad, np.int64)
@@ -356,46 +381,56 @@ class DeviceDecodeLane:
             for bi, j in enumerate(jobs):
                 firsts[bi] = j.plan["first"]
                 strides[bi] = j.plan["stride"]
-            out = _delta_const_kernel(self._put(firsts),
-                                      self._put(strides), length=lane_len)
-        elif kind == "delta":
+            return [firsts, strides]
+        if kind == "delta":
             zz = np.zeros((b_pad, lane_len), dtype=_WIDTH_DTYPE[width])
             firsts = np.zeros(b_pad, np.int64)
             for bi, j in enumerate(jobs):
                 raw = np.frombuffer(j.plan["raw"], dtype=zz.dtype)
                 zz[bi, :len(raw)] = raw
                 firsts[bi] = j.plan["first"]
-            out = _delta_kernel(self._put(zz), self._put(firsts))
-        elif kind == "gorilla":
+            return [zz, firsts]
+        if kind == "gorilla":
             b_pad = max(b_pad, _XOR_ROWS)
             planes = np.zeros((b_pad, 8, lane_len), dtype=np.uint8)
             for bi, j in enumerate(jobs):
                 n = j.plan["n"]
                 planes[bi, :, :n] = np.frombuffer(
                     j.plan["raw"], dtype=np.uint8).reshape(8, n)
-            pd = self._put(planes)
-            if self._use_pallas and lane_len <= _XOR_MAX_WIDTH:
-                lo, hi = _gorilla_pre_kernel(pd)
-                lo = _pallas_xor_scan(lo, self._interpret)
-                hi = _pallas_xor_scan(hi, self._interpret)
-                out = _gorilla_post_kernel(lo, hi)
-                pallas_kernels.note_engaged()
-            else:
-                out = _gorilla_xla_kernel(pd)
-        elif kind == "bitpack":
+            return [planes]
+        if kind == "bitpack":
             packed = np.zeros((b_pad, lane_len), dtype=np.uint8)
             for bi, j in enumerate(jobs):
                 raw = np.frombuffer(j.plan["raw"], dtype=np.uint8)
                 nb = (j.plan["n"] + 7) // 8
                 packed[bi, :nb] = raw[:nb]
-            out = _bitpack_kernel(self._put(packed))
-        else:   # dict codes
-            codes = np.zeros((b_pad, lane_len), dtype=_WIDTH_DTYPE[width])
-            for bi, j in enumerate(jobs):
-                raw = np.frombuffer(j.plan["raw"], dtype=codes.dtype)
-                codes[bi, :len(raw)] = raw
-            out = _codes_kernel(self._put(codes))
-        return [out[bi, :j.plan["n"]] for bi, j in enumerate(jobs)]
+            return [packed]
+        # dict codes
+        codes = np.zeros((b_pad, lane_len), dtype=_WIDTH_DTYPE[width])
+        for bi, j in enumerate(jobs):
+            raw = np.frombuffer(j.plan["raw"], dtype=codes.dtype)
+            codes[bi, :len(raw)] = raw
+        return [codes]
+
+    def _launch_group(self, kind, lane_len, operands):
+        """→ the [B, L] decoded batch, on device."""
+        if kind == "delta_const":
+            firsts, strides = operands
+            return _delta_const_kernel(firsts, strides, length=lane_len)
+        if kind == "delta":
+            return _delta_kernel(*operands)
+        if kind == "gorilla":
+            pd, = operands
+            if self._use_pallas and lane_len <= _XOR_MAX_WIDTH:
+                lo, hi = _gorilla_pre_kernel(pd)
+                lo = _pallas_xor_scan(lo, self._interpret)
+                hi = _pallas_xor_scan(hi, self._interpret)
+                pallas_kernels.note_engaged()
+                return _gorilla_post_kernel(lo, hi)
+            return _gorilla_xla_kernel(pd)
+        if kind == "bitpack":
+            return _bitpack_kernel(*operands)
+        return _codes_kernel(*operands)
 
     def _put(self, a: np.ndarray):
         from .device_cache import _put

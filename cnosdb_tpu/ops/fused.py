@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from ..sql.expr import Expr
 from ..utils import stages
+from . import program
 from .device_cache import DeviceBatch
 from .kernels import local_segment_partials, pad_segments
 
@@ -88,7 +89,9 @@ class PendingFused:
         self.agg_cols = agg_cols
 
     def fetch(self) -> dict[str, dict]:
-        mat = np.asarray(self.dev_out)  # [n_slots, ns_pad], one transfer
+        # blocks until the program has run, then moves its one matrix
+        with stages.stage("kernel.fetch_ms"):
+            mat = np.asarray(self.dev_out)  # [n_slots, ns_pad], one transfer
         out: dict[str, dict] = {}
         for i, (col, agg) in enumerate(self.manifest):
             row = mat[i, :self.num_segments]
@@ -321,4 +324,4 @@ def _build_kernel(filter_expr: Expr | None, col_wants: dict,
         rows = [results[slot].astype(jnp.float64) for slot in manifest]
         return jnp.stack(rows)
 
-    return jax.jit(kernel), manifest
+    return jax.jit(program("fused_aggregate")(kernel)), manifest

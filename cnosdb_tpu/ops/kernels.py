@@ -32,6 +32,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..utils import stages
+from . import program
+
 I32_MAX = np.int32(2**31 - 1)
 I32_MIN = np.int32(-(2**31) + 1)
 
@@ -104,7 +107,7 @@ def local_segment_partials(values, valid, seg_ids, rank, *, num_segments: int,
 
 
 segment_aggregate = jax.jit(
-    local_segment_partials,
+    program("segment_aggregate")(local_segment_partials),
     static_argnames=("num_segments", "want_count", "want_sum", "want_min",
                      "want_max", "want_first", "want_last"))
 
@@ -314,7 +317,8 @@ def aggregate_column_host(values: np.ndarray, valid: np.ndarray,
         rank = _pad(rank, np_pad, fill=0)
     out = segment_aggregate(values, valid, seg_ids, rank,
                             num_segments=ns_pad, **wants)
-    host = {k: np.asarray(v)[:num_segments] for k, v in out.items()}  # lint: disable=host-sync (THE audited transfer point: one batched pull per aggregate call)
+    with stages.stage("kernel.fetch_ms"):
+        host = {k: np.asarray(v)[:num_segments] for k, v in out.items()}  # lint: disable=host-sync (THE audited transfer point: one batched pull per aggregate call)
     if "count" in host:
         host["count"] = host["count"].astype(np.int64)
     return host
@@ -330,6 +334,7 @@ def _pad(a: np.ndarray, n: int, fill=0):
 # sort-based DISTINCT on device (ops/group_agg.py device path)
 # ---------------------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=("num_segments",))
+@program("segment_distinct")
 def _segment_distinct(pairs, nv, *, num_segments: int):
     """count(DISTINCT) from (group·nv + value) pair codes: sort, mark each
     first occurrence, segment-sum the indicators by group. Padded rows
@@ -343,7 +348,7 @@ def _segment_distinct(pairs, nv, *, num_segments: int):
         first.astype(jnp.int32), seg, num_segments)
 
 
-_device_sort = jax.jit(jnp.sort)
+_device_sort = jax.jit(program("sort")(jnp.sort))
 
 
 def segment_distinct_count(gid: np.ndarray, vcodes: np.ndarray,
@@ -385,6 +390,7 @@ def sorted_pair_codes(gid: np.ndarray, vcodes: np.ndarray,
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
+@program("topk_threshold")
 def _topk_threshold(vals, *, k: int):
     top, _ = jax.lax.top_k(vals, k)
     return top[k - 1]
@@ -397,8 +403,8 @@ def dict_mask_gather(mask: np.ndarray, codes):
     return _dict_mask_gather(jnp.asarray(mask), codes)
 
 
-_dict_mask_gather = jax.jit(lambda mask, codes: jnp.take(mask, codes, axis=0,
-                                                         mode="clip"))
+_dict_mask_gather = jax.jit(program("dict_mask_gather")(
+    lambda mask, codes: jnp.take(mask, codes, axis=0, mode="clip")))
 
 
 def topk_threshold(vals: np.ndarray, k: int):

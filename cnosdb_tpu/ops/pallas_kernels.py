@@ -46,6 +46,8 @@ import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
 
+from . import program
+
 R_TILE = 256     # rows per grid step
 W_WIN = 2048     # local segment window (16 × 128-lane groups)
 
@@ -123,6 +125,7 @@ def _kernel(base_ref, values_ref, valid_ref, seg_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "interpret"))
+@program("segment_aggregate_pallas")
 def _windowed_partials(bases, values, valid, seg_ids, *, num_segments: int,
                        interpret: bool = False):
     """values/valid/seg_ids padded to a tile multiple; bases[t] = window

@@ -28,12 +28,14 @@ except ImportError:   # jax 0.4.x: experimental module, check_rep kwarg
         return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
                               out_specs=out_specs, check_rep=check_vma)
 
+from ..ops import program
 from ..ops.kernels import local_segment_partials, pad_rows, pad_segments, _pad
 from .mesh import SHARD_AXIS, mesh_size
 
 
 @functools.partial(
     jax.jit, static_argnames=("mesh", "num_segments", "want_first", "want_last"))
+@program("dist_aggregate")
 def _dist_kernel(values, valid, seg_ids, rank, *, mesh: Mesh,
                  num_segments: int, want_first: bool, want_last: bool):
     def body(v, m, s, r):
@@ -70,6 +72,7 @@ def _dist_kernel(values, valid, seg_ids, rank, *, mesh: Mesh,
 @functools.partial(
     jax.jit, static_argnames=("mesh", "slots", "num_segments", "wants",
                               "run_pad"))
+@program("mesh_merge")
 def mesh_merge_kernel(values, valid, seg_ids, rank, run_sums, run_segs, *,
                       mesh: Mesh, slots: int, num_segments: int,
                       wants: tuple[str, ...], run_pad: int = 0):

@@ -130,7 +130,7 @@ class RpcServer:
                 if fn is None:
                     self._reply(404, pack({"_err": "NoSuchMethod", "_msg": method}))
                     return
-                from ..server.trace import GLOBAL_COLLECTOR
+                from ..utils.spans import GLOBAL_COLLECTOR
 
                 try:
                     if faults.ENABLED:
@@ -331,7 +331,7 @@ def rpc_call(addr: str, method: str, payload: dict | None = None,
         payload = dict(payload or {})
         payload["_profile"] = True
     body = pack(payload or {})
-    from ..server.trace import TRACE_HEADER, current_trace_header
+    from ..utils.spans import TRACE_HEADER, current_trace_header
 
     hdrs = {"Content-Type": "application/msgpack"}
     secret = cluster_secret()

@@ -71,8 +71,8 @@ class AdmissionGate:
         self.admitted_total = 0
         self.queued_total = 0
         self.shed_total = 0
-        # wait-time accounting for the queue-wait gauge
-        self._wait_sum_ms = 0.0
+        # longest wait so far (every wait goes to the caller, who observes
+        # it into the cnosdb_requests_queue_wait_ms histogram)
         self._wait_max_ms = 0.0
 
     def acquire(self, dl: deadline_mod.Deadline | None = None) -> float:
@@ -115,7 +115,6 @@ class AdmissionGate:
                         self._running += 1
                         self.admitted_total += 1
                         waited = time.monotonic() - start
-                        self._wait_sum_ms += waited * 1000.0
                         self._wait_max_ms = max(self._wait_max_ms,
                                                 waited * 1000.0)
                         return waited
@@ -151,14 +150,11 @@ class AdmissionGate:
 
     def stats(self) -> dict:
         with self._cond:
-            n_adm = self.admitted_total
-            avg = self._wait_sum_ms / n_adm if n_adm else 0.0
             return {
                 "running": self._running,
                 "queued": self._queued,
-                "admitted_total": n_adm,
+                "admitted_total": self.admitted_total,
                 "queued_total": self.queued_total,
                 "shed_total": self.shed_total,
-                "queue_wait_ms_avg": avg,
                 "queue_wait_ms_max": self._wait_max_ms,
             }

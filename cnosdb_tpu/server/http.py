@@ -66,6 +66,9 @@ class HttpServer:
         _health.configure(qc)
         self.gate = AdmissionGate(qc.max_concurrent_queries,
                                   qc.max_queued_queries)
+        # observed once per admitted request (handle_sql); declared so
+        # _sum/_count are on /metrics at 0 before the first query
+        self.metrics.declare_histogram("cnosdb_requests_queue_wait_ms")
         # memory-governance plane: push [query] memory_* knobs into the
         # broker and hand it the gate so ladder step 2 can shed QUEUED
         # queries (server/memory.py)
@@ -242,32 +245,44 @@ class HttpServer:
             pass
 
     async def handle_sql(self, request):
+        from ..utils.spans import GLOBAL_COLLECTOR, TRACE_HEADER
+
+        # the root span and the ingress wait both start at handler entry
+        span = GLOBAL_COLLECTOR.from_headers(request.headers, "http:sql")
+        t_in = time.perf_counter()
         session = self._session(request)
         sql = (await request.text()).strip()
         if not sql:
             return _err_response(400, QueryError("empty sql"))
         accept = request.headers.get("Accept", "application/csv")
-        from .trace import GLOBAL_COLLECTOR
-
-        span = GLOBAL_COLLECTOR.from_headers(request.headers, "http:sql")
         span.set_tag("sql", sql[:200]).set_tag("tenant", session.tenant)
         dl = self._request_deadline(request, self.read_timeout_ms)
         # opt-in per-query profile summary: X-CnosDB-Profile: 1 installs
         # the profile at ingress so the response can carry its totals
-        # (the full profile stays fetchable at /debug/profile?qid=)
+        # (the full profile stays fetchable at /debug/profile?qid=).
+        # That header or a propagated trace id also makes the request
+        # TRACED: every stage then records its interval as a child span
+        # of http:sql (utils/stages.py) — one timeline per request
         want_profile = request.headers.get(PROFILE_HEADER, "") \
             not in ("", "0", "false")
-        prof = stages.QueryProfile() if want_profile else None
+        prof = None
+        if want_profile or request.headers.get(TRACE_HEADER):
+            prof = stages.QueryProfile()
+            prof.traced = True
 
         def run():
             # on the executor worker thread: one thread per in-flight
             # request, so blocking in the admission gate is safe
             # profile_scope(None) is a harmless clear, so no conditional
-            with deadline_mod.scope(dl), stages.profile_scope(prof):
-                self.gate.acquire(dl)   # AdmissionRejected → 503
+            with deadline_mod.scope(dl), stages.profile_scope(prof), \
+                    span.activate():
+                waited = self.gate.acquire(dl)   # AdmissionRejected → 503
+                # observed once per admitted request, zero waits too
+                self.metrics.observe("cnosdb_requests_queue_wait_ms",
+                                     waited * 1e3)
+                stages.book("ingress_wait_ms", t_in)
                 try:
-                    with span:
-                        return self.executor.execute_sql(sql, session)
+                    return self.executor.execute_sql(sql, session)
                 except CnosError:
                     if dl.qid and dl.remote_nodes:
                         # deadline expiry / kill / disconnect unwound the
@@ -281,18 +296,29 @@ class HttpServer:
                 finally:
                     self.gate.release()
 
+        # the root span covers handler entry → rendered response (the
+        # worker thread and this one both work under it) and is finished
+        # once, on whichever path the request leaves by
         t0 = time.monotonic()
+        work = None
         try:
             self.limiters.check_query(session.tenant)
-            loop = asyncio.get_running_loop()
-            results = await loop.run_in_executor(None, run)
+            work = asyncio.get_running_loop().run_in_executor(None, run)
+            # shielded: a disconnect cancels this handler, not the future
+            # that says when the worker has really ended
+            results = await asyncio.shield(work)
         except asyncio.CancelledError:
             # aiohttp cancels the handler when the client disconnects;
             # flip the cancel flag so the (uninterruptible) worker thread
-            # unwinds at its next checkpoint and fans cancels out itself
+            # unwinds at its next checkpoint and fans cancels out itself.
+            # The span ends where the worker does: no child outlasts it
             dl.cancel("client disconnected")
+            work.add_done_callback(
+                lambda f: (f.cancelled() or f.exception(),
+                           span.finish("client disconnected")))
             raise
         except CnosError as e:
+            span.finish(str(e))
             self.metrics.incr("cnosdb_http_sql_errors_total")
             if isinstance(e, DeadlineExceeded):
                 self.metrics.incr("cnosdb_requests_deadline_exceeded_total")
@@ -301,6 +327,9 @@ class HttpServer:
             if isinstance(e, MemoryExceeded):
                 self.metrics.incr("cnosdb_requests_memory_exceeded_total")
             return _err_response(_status_for(e), e)
+        except Exception as e:
+            span.finish(f"{type(e).__name__}: {e}")
+            raise
         self.metrics.incr("cnosdb_http_queries_total")
         # reference query_sql_process_ms: end-to-end SQL latency histogram
         self.metrics.observe("cnosdb_query_sql_process_ms",
@@ -308,21 +337,19 @@ class HttpServer:
         self._record_http_usage(request, session, "http_queries", 1)
         self._record_http_usage(request, session, "http_data_in", len(sql))
         rs = results[-1] if results else ResultSet.empty()
-        if "json" in accept:
-            resp = web.Response(text=format_json(rs),
-                                content_type="application/json")
-        elif "table" in accept:
-            resp = web.Response(text=format_table(rs),
-                                content_type="text/plain")
-        else:
-            resp = web.Response(text=format_csv(rs), content_type="text/csv")
-        if prof is not None:
-            import json as _json
-
-            summary = {"qid": prof.qid, "wall_ms": prof.wall_ms,
-                       "stages": prof.stage_totals()}
-            resp.headers[PROFILE_SUMMARY_HEADER] = _json.dumps(
-                summary, separators=(",", ":"))[:4096]
+        # render_ms joins the request's profile and trace (the executor
+        # sealed wall_ms before this: rendering is the front end's time)
+        with span, stages.profile_scope(prof), stages.stage("render_ms"):
+            if "json" in accept:
+                text, ctype = format_json(rs), "application/json"
+            elif "table" in accept:
+                text, ctype = format_table(rs), "text/plain"
+            else:
+                text, ctype = format_csv(rs), "text/csv"
+        resp = web.Response(text=text, content_type=ctype)
+        if want_profile:
+            resp.headers[PROFILE_SUMMARY_HEADER] = profile_summary_header(
+                prof.qid, prof.wall_ms, prof.stage_totals())
         # gzip negotiation (reference http_service gzip layer)
         if "gzip" in request.headers.get("Accept-Encoding", ""):
             resp.enable_compression()
@@ -350,7 +377,7 @@ class HttpServer:
         """Collected spans (reference stores traces queryably via its
         jaeger-query API; embedded form returns them directly)."""
         self._require_admin(request)
-        from .trace import GLOBAL_COLLECTOR
+        from ..utils.spans import GLOBAL_COLLECTOR
 
         tid = request.query.get("trace_id")
         limit = int(self._query_number(request, "limit", 500, 1, 10_000))
@@ -970,10 +997,8 @@ class HttpServer:
         self.metrics.set_gauge("cnosdb_requests_shed_total", g["shed_total"])
         self.metrics.set_gauge("cnosdb_requests_running", g["running"])
         self.metrics.set_gauge("cnosdb_requests_queue_depth", g["queued"])
-        self.metrics.set_gauge("cnosdb_requests_queue_wait_ms",
-                               g["queue_wait_ms_avg"], stat="avg")
-        self.metrics.set_gauge("cnosdb_requests_queue_wait_ms",
-                               g["queue_wait_ms_max"], stat="max")
+        # (queue wait is the cnosdb_requests_queue_wait_ms histogram,
+        # observed once per admitted request in handle_sql)
         # cancellation fan-out + shed-before-decode observability
         for name, n in deadline_mod.counters_snapshot().items():
             self.metrics.set_gauge("cnosdb_deadline_total", n, kind=name)
@@ -1177,6 +1202,40 @@ class HttpServer:
                 writer.close()
 
         return await asyncio.start_server(on_conn, host, port)
+
+
+# ---------------------------------------------------------------------------
+# the profile summary header
+# ---------------------------------------------------------------------------
+PROFILE_SUMMARY_MAX = 4096
+
+
+def profile_summary_header(qid, wall_ms, stages_: dict,
+                           limit: int = PROFILE_SUMMARY_MAX) -> str:
+    """`X-CnosDB-Profile-Summary`: compact JSON of at most `limit` bytes.
+    The string is never cut (a cut header parses as no profile at all):
+    an oversized summary drops its zero-valued stages first, then the
+    smallest, and says how many under `"dropped"`; the full profile stays
+    at `/debug/profile?qid=`."""
+    import json as _json
+
+    def dumps(kept: dict, dropped: int) -> str:
+        summary = {"qid": qid, "wall_ms": wall_ms, "stages": kept}
+        if dropped:
+            summary["dropped"] = dropped
+        return _json.dumps(summary, separators=(",", ":"))
+
+    text = dumps(stages_, 0)
+    if len(text) <= limit:
+        return text
+    kept = {k: v for k, v in stages_.items() if v}
+    # smallest last, so they pop first
+    order = sorted(kept, key=lambda k: abs(kept[k]), reverse=True)
+    while True:
+        text = dumps(kept, len(stages_) - len(kept))
+        if len(text) <= limit or not order:
+            return text
+        del kept[order.pop()]
 
 
 # ---------------------------------------------------------------------------
@@ -1429,7 +1488,8 @@ def run_server(args) -> int:
               f"(continuous archiving + BACKUP/RESTORE enabled)")
 
     if cfg.trace.otlp_endpoint:
-        from .trace import GLOBAL_COLLECTOR, OtlpExporter
+        from ..utils.spans import GLOBAL_COLLECTOR
+        from .trace import OtlpExporter
 
         OtlpExporter(cfg.trace.otlp_endpoint, GLOBAL_COLLECTOR,
                      batch_size=cfg.trace.batch_size,

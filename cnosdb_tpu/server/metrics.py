@@ -48,6 +48,14 @@ class MetricsRegistry:
         with self._lock:
             self._counters[(name, _lk(labels))] = value
 
+    def declare_histogram(self, name: str, **labels):
+        """Export the series at 0 before its first observation (a reader
+        that takes the rise of `_sum` / `_count` over a window needs both
+        ends to exist)."""
+        with self._lock:
+            self._histograms.setdefault(
+                (name, _lk(labels)), _Hist(len(self._hist_bounds)))
+
     def observe(self, name: str, value: float, **labels):
         with self._lock:
             h = self._histograms.get((name, _lk(labels)))

@@ -773,24 +773,28 @@ class ServingPlane:
             # separate outcome so SELECT bypasses stay a useful signal
             _count_serving("result_cache", "non_select")
             return None
-        fpp = self._fingerprint(sql)
-        if fpp is None:
-            # non-fingerprintable SELECT (session-dependent scalar,
-            # multi-statement, session var): invisible to all three layers
-            _count_serving("result_cache", "bypass")
-            return None
-        fp, params = fpp
-        key = (session.tenant, session.database, fp, params)
-        ent = self.result_cache.get(key)
-        if ent is not None:
-            rs = self._probe_result(ex, ent, key, session)
-            if rs is not None:
-                _count_serving("result_cache", "hit")
-                stages.count("serving.result_hit")
-                return [rs]
-        else:
-            _count_serving("result_cache", "miss")
-            stages.count("serving.result_miss")
+        # the lookups are the plan layer's time: plan_ms covers them and,
+        # on a miss, the parse + analyze + plan_select behind them
+        with stages.stage("plan_ms"):
+            fpp = self._fingerprint(sql)
+            if fpp is None:
+                # non-fingerprintable SELECT (session-dependent scalar,
+                # multi-statement, session var): invisible to all three
+                # layers
+                _count_serving("result_cache", "bypass")
+                return None
+            fp, params = fpp
+            key = (session.tenant, session.database, fp, params)
+            ent = self.result_cache.get(key)
+            if ent is not None:
+                rs = self._probe_result(ex, ent, key, session)
+                if rs is not None:
+                    _count_serving("result_cache", "hit")
+                    stages.count("serving.result_hit")
+                    return [rs]
+            else:
+                _count_serving("result_cache", "miss")
+                stages.count("serving.result_miss")
         return self._execute_miss(ex, key, sql, session)
 
     def _probe_result(self, ex, ent: _ResultEntry, key, session):
@@ -812,13 +816,14 @@ class ServingPlane:
         state = {"key": key, "tenant": tenant, "db": db0,
                  "tokens": None, "bypass": None, "stmt": None}
         # ---- plan cache
-        pe = self.plan_cache.get_exact(key)
-        how = "hit"
-        if pe is None:
-            tpl = self.plan_cache.get_template(tenant, db0, fp)
-            if tpl is not None:
-                pe = self._rebind_template(ex, tpl, params, key)
-                how = "rebind"
+        with stages.stage("plan_ms"):
+            pe = self.plan_cache.get_exact(key)
+            how = "hit"
+            if pe is None:
+                tpl = self.plan_cache.get_template(tenant, db0, fp)
+                if tpl is not None:
+                    pe = self._rebind_template(ex, tpl, params, key)
+                    how = "rebind"
         if pe is not None:
             rs = self._exec_planned(ex, pe, key, session, state, how)
             if rs is not None:
@@ -829,7 +834,8 @@ class ServingPlane:
         # ---- full path, instrumented: parse here (once), let _select's
         # observation hook capture the analyzed stmt + plan + tokens
         try:
-            stmts = parse_sql(sql)
+            with stages.stage("plan_ms"):
+                stmts = parse_sql(sql)
         except Exception:
             _count_serving("result_cache", "bypass")
             raise               # same error the legacy path would raise

@@ -184,7 +184,7 @@ class QueryExecutor:
         session = session or Session()
         from contextlib import nullcontext
 
-        from ..server import trace as _trace
+        from ..utils import spans as _trace
         from ..utils import deadline as _deadline_mod
 
         # adopt the ambient request context (installed at HTTP ingress);
@@ -223,7 +223,9 @@ class QueryExecutor:
                         self._record_query_usage(sql, session)
                         return out
                 out = []
-                for s in parse_sql(sql):
+                with stages.stage("plan_ms"):
+                    stmts = parse_sql(sql)
+                for s in stmts:
                     self.tracker.check_cancelled(qid)
                     out.append(self.execute_statement(s, session))
                 self._record_query_usage(sql, session)
@@ -1515,8 +1517,12 @@ class QueryExecutor:
         # plan/result caches
         sv_state = self.serving.claim() if self.serving is not None \
             else None
-        stmt = self._fold_session_scalars(stmt, session)
-        stmt = analyze(self._resolve_subqueries(stmt, session))
+        with stages.stage("plan_ms"):
+            stmt = self._fold_session_scalars(stmt, session)
+        # (subqueries execute here: their scans book their own stages)
+        stmt = self._resolve_subqueries(stmt, session)
+        with stages.stage("plan_ms"):
+            stmt = analyze(stmt)
         if stmt.from_item is not None or self._needs_relational(stmt):
             return self._select_relational(stmt, session)
         if stmt.table is not None:
@@ -1603,10 +1609,11 @@ class QueryExecutor:
             return self._ts_gen_func(stmt, session)
         schema = self.meta.table(session.tenant, db, table)
         try:
-            plan = plan_select(stmt, schema)
-            if sv_state is not None:
-                self.serving.observe_plan(sv_state, stmt, plan, session,
-                                          db, table, schema)
+            with stages.stage("plan_ms"):
+                plan = plan_select(stmt, schema)
+                if sv_state is not None:
+                    self.serving.observe_plan(sv_state, stmt, plan, session,
+                                              db, table, schema)
             if isinstance(plan, AggregatePlan):
                 return self._exec_aggregate(plan, session.tenant, db)
             return self._exec_raw(plan, session.tenant, db)
@@ -3293,14 +3300,21 @@ class QueryExecutor:
                 if len(batches) > 1:
                     # per-vnode kernel prep (bucket/segment derivation +
                     # reductions) is independent: run on a pool, like the
-                    # scan fan-out
+                    # scan fan-out — each task inside the submitter's
+                    # contextvars.Context, so the batches' stages, counts
+                    # and spans reach this query's profile and trace
+                    import contextvars
                     from concurrent.futures import ThreadPoolExecutor
+
+                    def one(b):
+                        return finish_scan_aggregate(
+                            launch_scan_aggregate(b, q))
 
                     with ThreadPoolExecutor(
                             max_workers=min(8, len(batches))) as tp:
-                        results = list(tp.map(
-                            lambda b: finish_scan_aggregate(
-                                launch_scan_aggregate(b, q)), batches))
+                        results = [f.result() for f in [
+                            tp.submit(contextvars.copy_context().run, one, b)
+                            for b in batches]]
                 else:
                     results = [finish_scan_aggregate(
                         launch_scan_aggregate(b, q)) for b in batches]

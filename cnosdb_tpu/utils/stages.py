@@ -19,6 +19,17 @@ breakdown, HTTP exposes an opt-in summary header plus
 profiles attach to their root trace span as tags, and the slow-query
 log writes threshold-exceeding profiles into usage_schema.
 
+One timeline per request: a profile whose `traced` flag is set (HTTP
+ingress sets it when the client sent `X-CnosDB-Profile` or a
+`cnos-trace-id` header) makes every `stage()` an interval as well as a
+sum — a child `Span` of the context span in the one collector
+(`utils/spans.py`; `GET /debug/traces?trace_id=` shows the tree), a
+`jax.profiler.TraceAnnotation("cnosdb.<stage>")` on the thread that did
+the work (so the interval lies in a profiler trace, on the device
+operations' clock), and a bare (start, end) pair the profile holds until
+`finish()` has derived `untraced_ms` from it. With the flag off a stage
+costs what it always did plus one attribute read.
+
 Stage catalog — every *literal* name passed to stage()/count() must
 appear in STAGE_CATALOG (enforced by the `stage-catalog` lint rule in
 cnosdb_tpu/analysis); dynamically-built names must use a prefix from
@@ -28,11 +39,12 @@ byte totals; everything else is a plain count.
 from __future__ import annotations
 
 import contextvars
+import sys
 import threading
 import time
 from collections import deque
 from contextlib import contextmanager
-from . import lockwatch
+from . import lockwatch, spans
 
 # The documented profile schema. A name missing here is invisible to
 # every dashboard/bench consumer, so the lint plane refuses it.
@@ -48,6 +60,20 @@ STAGE_CATALOG: dict[str, str] = {
                         "(the accelerator half of decode_ms)",
     "device_decode_engagements": "pages decoded by the device-decode "
                                  "lane instead of a host lane",
+    "device_decode.put_ms": "device-decode lane: host→device puts of the "
+                            "packed group buffers",
+    "device_decode.launch_ms": "device-decode lane: the group's codec "
+                               "kernel + one slice launch per page "
+                               "(dispatch, asynchronous)",
+    "device_decode.pull_ms": "device-decode lane: the blocking "
+                             "device→host pull of every page's row",
+    "plan_ms": "parse + analyze + plan_select, the serving plane's "
+               "fingerprint / plan-cache / result-cache lookups included",
+    "ingress_wait_ms": "HTTP handler entry → worker thread past the "
+                       "admission gate (executor hand-off + queue wait)",
+    "render_ms": "result set → CSV / JSON / table text",
+    "untraced_ms": "wall_ms minus the union of the request's stage "
+                   "intervals: time no span covers (traced requests only)",
     "upload_ms": "host→device column uploads",
     "upload_bytes": "bytes moved host→device by those uploads",
     "fused_launches": "fused filter/bucket/segment programs launched",
@@ -56,6 +82,9 @@ STAGE_CATALOG: dict[str, str] = {
                         "scan device does not hold f64 exactly "
                         "(ops/placement.f64_exact)",
     "kernel_ms": "fused segment-aggregate kernels",
+    "kernel.fetch_ms": "the aggregate's blocking result fetch: what is "
+                       "left of the device's run + the device→host "
+                       "transfer",
     "merge_ms": "cross-vnode partial merge / device delta-merge",
     "finalize_ms": "vectorized finalizers + output rendering",
     "factorize_ms": "group-key factorization (values → dense codes)",
@@ -192,6 +221,11 @@ _profile: contextvars.ContextVar = contextvars.ContextVar(
 _err_lock = lockwatch.Lock("stages.errors")
 _errors: dict[str, int] = {}
 
+# a traced profile holds at most this many stage intervals for
+# `untraced_ms`; the rest are counted in `dropped` (their sums and spans
+# are kept all the same)
+MAX_INTERVALS = 1024
+
 
 class QueryProfile:
     """Stage timings/counters + device telemetry for ONE query.
@@ -203,7 +237,7 @@ class QueryProfile:
 
     __slots__ = ("qid", "sql", "trace_id", "node_id", "started_at",
                  "wall_ms", "error", "ms", "counts", "device",
-                 "subprofiles", "_lock")
+                 "subprofiles", "traced", "intervals", "dropped", "_lock")
 
     def __init__(self, qid: str | None = None, node_id=None,
                  sql: str | None = None):
@@ -212,6 +246,12 @@ class QueryProfile:
         self.trace_id: str | None = None
         self.node_id = node_id
         self.started_at = time.time()
+        # one timeline per request: set at ingress (module docstring)
+        self.traced = False
+        # (start, end) of each stage on the perf_counter clock, whatever
+        # thread it ran on; emptied once finish() has read them
+        self.intervals: list[tuple] = []
+        self.dropped = 0
         self.wall_ms: float | None = None
         self.error: str | None = None
         self.ms: dict[str, float] = {}
@@ -230,6 +270,15 @@ class QueryProfile:
     def add_count(self, name: str, n: int = 1) -> None:
         with self._lock:
             self.counts[name] = self.counts.get(name, 0) + n
+
+    def add_interval(self, t0: float, t1: float) -> None:
+        if self.wall_ms is not None:
+            return      # sealed: what follows (rendering) is not its wall
+        with self._lock:
+            if len(self.intervals) < MAX_INTERVALS:
+                self.intervals.append((t0, t1))
+            else:
+                self.dropped += 1
 
     def merge_remote(self, entry: dict) -> None:
         """Fold one remote node's wire sub-profile in (keyed by
@@ -308,7 +357,8 @@ class QueryProfile:
                     "ms": {k: round(v, 3) for k, v in sorted(self.ms.items())},
                     "counts": dict(sorted(self.counts.items())),
                     "device": dict(self.device),
-                    "subprofiles": [dict(s) for s in self.subprofiles]}
+                    "subprofiles": [dict(s) for s in self.subprofiles],
+                    "traced": self.traced, "dropped": self.dropped}
 
     # ---------------------------------------------------------- lifecycle
     def finish(self, wall_ms: float | None = None,
@@ -316,10 +366,16 @@ class QueryProfile:
         """Stamp wall time + device telemetry. Captures only from
         modules that are ALREADY imported — finishing a profile must
         never drag the jax stack in on a cold text-only query."""
-        import sys
-
         if wall_ms is not None:
             self.wall_ms = round(wall_ms, 3)
+            if self.traced:
+                # the request ran [now - wall, now]; what no stage's
+                # interval covers, whatever thread it ran on
+                hi = time.perf_counter()
+                with self._lock:
+                    ivs, self.intervals = self.intervals, []
+                    self.ms["untraced_ms"] = uncovered_ms(
+                        wall_ms, ivs, hi - wall_ms / 1e3, hi)
         if error is not None:
             self.error = error
         pk = sys.modules.get("cnosdb_tpu.ops.pallas_kernels")
@@ -415,17 +471,92 @@ PROFILES = ProfileRing()
 
 
 # --------------------------------------------------------------- recording
+def uncovered_ms(wall_ms: float, intervals, lo: float, hi: float) -> float:
+    """wall_ms minus the length of the union of `intervals` ((start, end)
+    in seconds) clipped to [lo, hi] — overlapping threads count once."""
+    covered, end = 0.0, lo
+    for a, b in sorted(intervals):
+        a, b = max(a, end), min(b, hi)
+        if b > a:
+            covered += b - a
+            end = b
+    return max(0.0, wall_ms - covered * 1e3)
+
+
+def _child_span(prof: "QueryProfile", name: str):
+    """→ a span under the context span or, with none, a root of the
+    profile's trace."""
+    return spans.GLOBAL_COLLECTOR.span(
+        name, trace_id=None if spans.current_span() is not None
+        else prof.trace_id)
+
+
+class _TracedStage:
+    """One stage of a traced request: the sum, the collector span, the
+    profiler annotation, and the (start, end) pair for `untraced_ms`."""
+
+    __slots__ = ("prof", "name", "span", "ann", "t0")
+
+    def __init__(self, prof: QueryProfile, name: str):
+        self.prof, self.name = prof, name
+
+    def __enter__(self):
+        self.span = _child_span(self.prof, self.name)
+        self.span.__enter__()
+        # never import jax from here: a text-only query stays jax-free
+        jax = sys.modules.get("jax")
+        self.ann = None
+        if jax is not None:
+            self.ann = jax.profiler.TraceAnnotation(
+                "cnosdb." + self.name, qid=str(self.prof.qid))
+            self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        t1 = time.perf_counter()
+        if self.ann is not None:
+            self.ann.__exit__(exc_type, exc, tb)
+        self.span.__exit__(exc_type, exc, tb)
+        self.prof.add_ms(self.name, (t1 - self.t0) * 1e3)
+        self.prof.add_interval(self.t0, t1)
+        return False
+
+
 @contextmanager
 def stage(name: str):
     prof = _profile.get()
     if prof is None:
         yield
         return
+    if prof.traced:
+        with _TracedStage(prof, name):
+            yield
+        return
     t0 = time.perf_counter()
     try:
         yield
     finally:
         prof.add_ms(name, (time.perf_counter() - t0) * 1e3)
+
+
+def book(name: str, t0: float) -> None:
+    """Book [t0, now] (`perf_counter` seconds), an interval the caller
+    timed itself because it starts on another thread (the ingress wait).
+    The sum always; for a traced request also the span and the pair for
+    `untraced_ms`. No profiler annotation: that cannot be back-dated."""
+    prof = _profile.get()
+    if prof is None:
+        return
+    t1 = time.perf_counter()
+    prof.add_ms(name, (t1 - t0) * 1e3)
+    if not prof.traced:
+        return
+    span = _child_span(prof, name)
+    span.duration_ns = int((t1 - t0) * 1e9)
+    span.start_ns -= span.duration_ns
+    spans.GLOBAL_COLLECTOR.record(span)
+    prof.add_interval(t0, t1)
 
 
 def count(name: str, n: int = 1) -> None:

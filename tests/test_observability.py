@@ -7,7 +7,7 @@ import time
 
 import pytest
 
-from cnosdb_tpu.server.trace import (
+from cnosdb_tpu.utils.spans import (
     GLOBAL_COLLECTOR, TRACE_HEADER, TraceCollector, current_trace_header,
 )
 
@@ -222,7 +222,8 @@ def test_otlp_span_export():
     import threading
     from http.server import BaseHTTPRequestHandler, HTTPServer
 
-    from cnosdb_tpu.server.trace import OtlpExporter, TraceCollector
+    from cnosdb_tpu.server.trace import OtlpExporter
+    from cnosdb_tpu.utils.spans import TraceCollector
 
     received = []
 

@@ -80,7 +80,7 @@ def test_profile_ring_is_bounded_and_queryable():
 def test_profile_and_trace_cross_pool_workers():
     """The classic contextvar loss: work submitted to the shared pools
     must keep billing the submitting query's profile and trace."""
-    from cnosdb_tpu.server.trace import GLOBAL_COLLECTOR, current_trace_header
+    from cnosdb_tpu.utils.spans import GLOBAL_COLLECTOR, current_trace_header
 
     prof = stages.QueryProfile()
     seen = []
@@ -444,6 +444,376 @@ def test_histogram_memory_bounded_under_soak():
     m = re.search(r'cnosdb_soak_ms_bucket\{route="q",le="5"\} (\d+)', text)
     # values are (i % 1000)/10 ∈ [0, 99.9]; ≤5 → i%1000 ∈ [0, 50] → 51/1000
     assert m and int(m.group(1)) == 51 * (n // 1000)
+
+
+# ------------------------------------------- one timeline per request
+def _seed_flushed_ints(h, hosts=4, steps=300):
+    """INTEGER fields in TSM files: what the device-decode lane takes."""
+    lines = "\n".join(
+        f"cpu,host=h{i} usage={(t * 7 + i) % 100}i "
+        f"{1672531200000000000 + t * 10 * 10**9}"
+        for i in range(hosts) for t in range(steps))
+    status, body, _ = h.request("POST", "/api/v1/write?db=public", lines)
+    assert status == 200, body
+    status, body, _ = h.request("POST", "/api/v1/sql?db=public", "FLUSH")
+    assert status == 200, body
+
+
+# a time-bucketed aggregate: no page statistic answers it, so pages decode
+_BUCKETED = ("SELECT date_bin(INTERVAL '10 minutes', time) AS t, host, "
+             "avg(usage) FROM cpu GROUP BY t, host")
+
+
+def _trace_spans(h, trace_id):
+    status, body, _ = h.request("GET", f"/debug/traces?trace_id={trace_id}")
+    assert status == 200
+    return json.loads(body)
+
+
+def _ancestors(span, by_id):
+    out = []
+    while span.get("parent_id") in by_id:
+        span = by_id[span["parent_id"]]
+        out.append(span["name"])
+    return out
+
+
+def test_traced_request_is_one_tree_under_http_sql(http, monkeypatch):
+    monkeypatch.setenv("CNOSDB_DEVICE_DECODE", "1")
+    _seed_flushed_ints(http)
+    tid = "feedc0de0001"
+    status, _body, hdrs = http.request(
+        "POST", "/api/v1/sql?db=public", _BUCKETED,
+        headers={"X-CnosDB-Profile": "1", "cnos-trace-id": tid})
+    assert status == 200
+    spans = _trace_spans(http, tid)
+    by_id = {s["span_id"]: s for s in spans}
+    roots = [s for s in spans if s["parent_id"] is None]
+    assert [r["name"] for r in roots] == ["http:sql"]
+    assert len(spans) > 5
+    for s in spans:
+        assert s["trace_id"] == tid
+        assert s["parent_id"] is None or s["parent_id"] in by_id, s
+        assert s["duration_ns"] >= 0
+        if s["parent_id"] is not None:
+            assert _ancestors(s, by_id)[-1] == "http:sql"
+    names = {s["name"] for s in spans}
+    assert {"ingress_wait_ms", "plan_ms", "decode_ms", "device_decode_ms",
+            "device_decode.put_ms", "device_decode.launch_ms",
+            "device_decode.pull_ms", "kernel_ms", "render_ms"} <= names, sorted(names)
+    # decode_ms → device_decode_ms → device_decode.pull_ms, in that order
+    pull = next(s for s in spans if s["name"] == "device_decode.pull_ms")
+    chain = _ancestors(pull, by_id)
+    assert chain[:2] == ["device_decode_ms", "decode_ms"], chain
+    # every span is a documented stage (or the root)
+    for n in names - {"http:sql"}:
+        assert n in stages.STAGE_CATALOG \
+            or n.startswith(stages.DYNAMIC_STAGE_PREFIXES), n
+    # children lie inside the root: it covers handler entry → rendered
+    root = roots[0]
+    for s in spans:
+        assert s["start_ns"] >= root["start_ns"] - 10**6, s
+        assert s["start_ns"] + s["duration_ns"] \
+            <= root["start_ns"] + root["duration_ns"] + 10**6, s
+    # the profile says what the intervals leave uncovered, and keeps none
+    summary = json.loads(hdrs["X-CnosDB-Profile-Summary"])
+    assert 0 <= summary["stages"]["untraced_ms"] <= summary["wall_ms"]
+    status, body, _ = http.request(
+        "GET", f"/debug/profile?qid={summary['qid']}")
+    full = json.loads(body)
+    assert full["traced"] and full["trace_id"] == tid
+    assert full["dropped"] == 0 and "intervals" not in full
+
+
+def test_decode_stage_terms_sum_to_decode_ms(http, monkeypatch):
+    """What the three decode metrics would read: pull, put + launch, and
+    the host remainder tile decode_ms; the lane's device stages lie inside
+    device_decode_ms, itself inside decode_ms."""
+    monkeypatch.setenv("CNOSDB_DEVICE_DECODE", "1")
+    _seed_flushed_ints(http)
+    status, _body, hdrs = http.request(
+        "POST", "/api/v1/sql?db=public", _BUCKETED,
+        headers={"X-CnosDB-Profile": "1"})
+    assert status == 200
+    st = json.loads(hdrs["X-CnosDB-Profile-Summary"])["stages"]
+    pull = st["device_decode.pull_ms"]
+    dispatch = st["device_decode.put_ms"] + st["device_decode.launch_ms"]
+    host = st["decode_ms"] - pull - dispatch
+    assert pull > 0 and dispatch > 0 and host > 0
+    assert pull + dispatch + host == pytest.approx(st["decode_ms"])
+    assert pull + dispatch <= st["device_decode_ms"] + 0.01 \
+        <= st["decode_ms"] + 0.02
+    assert st["device_decode_engagements"] > 0
+
+
+def test_unprofiled_request_leaves_one_span_and_no_intervals(http):
+    from cnosdb_tpu.utils.spans import GLOBAL_COLLECTOR
+
+    _seed_http(http)
+    seen = {s["span_id"] for s in GLOBAL_COLLECTOR.spans(limit=10**6)}
+    status, _b, hdrs = http.request(
+        "POST", "/api/v1/sql?db=public",
+        "SELECT host, max(usage) FROM cpu GROUP BY host")
+    assert status == 200 and "X-CnosDB-Profile-Summary" not in hdrs
+    new = [s for s in GLOBAL_COLLECTOR.spans(limit=10**6)
+           if s["span_id"] not in seen]
+    assert [s["name"] for s in new] == ["http:sql"]
+    status, body, _ = http.request(
+        "GET", f"/debug/profile?qid={new[0]['tags']['profile.qid']}")
+    full = json.loads(body)
+    assert full["traced"] is False and full["dropped"] == 0
+    assert "untraced_ms" not in full["ms"]
+
+
+def test_stage_with_flag_off_keeps_no_interval_and_opens_no_span():
+    from cnosdb_tpu.utils.spans import GLOBAL_COLLECTOR
+
+    before = len(GLOBAL_COLLECTOR.spans(limit=10**6))
+    prof = stages.QueryProfile()
+    with stages.profile_scope(prof):
+        with stages.stage("decode_ms"):
+            pass
+        stages.book("ingress_wait_ms", time.perf_counter() - 0.001)
+    assert prof.ms["decode_ms"] >= 0 and prof.ms["ingress_wait_ms"] >= 1.0
+    assert prof.intervals == [] and prof.dropped == 0
+    assert len(GLOBAL_COLLECTOR.spans(limit=10**6)) == before
+    assert "untraced_ms" not in prof.finish(wall_ms=5.0).ms
+
+
+@pytest.mark.parametrize("intervals,want", [
+    ([], 100.0),
+    ([(0.010, 0.030)], 80.0),
+    # two threads overlapping: the union counts once
+    ([(0.010, 0.050), (0.030, 0.070)], 40.0),
+    # nested, touching, and outside the request's window
+    ([(0.0, 0.040), (0.010, 0.020), (0.040, 0.060), (-1.0, -0.5),
+      (0.090, 0.500)], 30.0),
+    ([(-0.010, 0.200)], 0.0),
+])
+def test_untraced_is_wall_minus_union(intervals, want):
+    assert stages.uncovered_ms(100.0, intervals, 0.0, 0.100) \
+        == pytest.approx(want)
+
+
+def test_finish_books_untraced_over_threads_and_bounds_intervals():
+    prof = stages.QueryProfile(qid="u1")
+    prof.traced = True
+    now = time.perf_counter()
+    # hand-made: main thread [−90, −60] ms, a pool thread [−70, −20] ms
+    for i in range(stages.MAX_INTERVALS - 2):
+        prof.add_interval(now - 0.5, now - 0.5)     # before the request
+    prof.add_interval(now - 0.090, now - 0.060)
+    prof.add_interval(now - 0.070, now - 0.020)
+    for i in range(8):
+        prof.add_interval(now - 0.010, now)         # over the bound: counted
+    assert len(prof.intervals) == stages.MAX_INTERVALS
+    assert prof.dropped == 8
+    prof.finish(wall_ms=100.0)
+    assert prof.ms["untraced_ms"] == pytest.approx(30.0, abs=1.0)
+    # read once, then let go; a sealed profile takes no more (rendering)
+    prof.add_interval(now, now)
+    assert prof.intervals == [] and prof.dropped == 8
+
+
+def test_span_clock_is_wall_start_monotonic_duration(monkeypatch):
+    from cnosdb_tpu.utils import spans
+
+    wall = [1_700_000_000_000_000_000]
+    monkeypatch.setattr(spans.time, "time_ns", lambda: wall[0])
+    col = spans.TraceCollector()
+    with col.span("stepped") as s:
+        wall[0] -= 3_600 * 10**9          # the wall clock steps back an hour
+        time.sleep(0.002)
+    d = col.spans()[0]
+    assert d["start_ns"] == 1_700_000_000_000_000_000
+    assert 1_000_000 <= d["duration_ns"] < 10**9 and s.duration_ns > 0
+
+
+def test_new_metrics_series_at_zero_before_first_query(http):
+    status, text, _ = http.request("GET", "/metrics")
+    assert status == 200
+    _types, samples = _check_prometheus(text)
+    got = {(n, l): v for n, l, v in samples}
+    assert got[("cnosdb_requests_queue_wait_ms_sum", "")] == 0.0
+    assert got[("cnosdb_requests_queue_wait_ms_count", "")] == 0.0
+    # the lifetime-average gauges gave way to the histogram
+    assert not any("stat=" in l for n, l, _v in samples
+                   if n == "cnosdb_requests_queue_wait_ms")
+    # one observation per admitted request, zero waits included
+    _seed_http(http)
+    for _ in range(2):
+        http.request("POST", "/api/v1/sql?db=public",
+                     "SELECT count(*) FROM cpu")
+    _t, samples = _check_prometheus(http.request("GET", "/metrics")[1])
+    got = {(n, l): v for n, l, v in samples}
+    assert got[("cnosdb_requests_queue_wait_ms_count", "")] == 2.0
+    assert got[("cnosdb_requests_queue_wait_ms_sum", "")] == 0.0
+    stats = http.server.gate.stats()
+    assert stats["queue_wait_ms_max"] == 0.0
+    assert "queue_wait_ms_avg" not in stats
+
+
+def test_render_error_is_not_an_sql_error(http, monkeypatch):
+    """Rendering runs outside the CnosError handler: an error raised there
+    propagates (500) instead of counting as a failed query, and the root
+    span is finished with it."""
+    from cnosdb_tpu.server import http as http_mod
+
+    _seed_http(http)
+
+    def boom(_rs):
+        raise QueryError("cannot render")
+
+    monkeypatch.setattr(http_mod, "format_csv", boom)
+    tid = "feedc0de0002"
+    status, _b, _h = http.request(
+        "POST", "/api/v1/sql?db=public", "SELECT count(*) FROM cpu",
+        headers={"cnos-trace-id": tid})
+    assert status == 500
+    _t, samples = _check_prometheus(http.request("GET", "/metrics")[1])
+    got = {(n, l): v for n, l, v in samples}
+    assert got.get(("cnosdb_http_sql_errors_total", ""), 0.0) == 0.0
+    by_name = {s["name"]: s for s in _trace_spans(http, tid)}
+    assert "cannot render" in by_name["http:sql"]["tags"]["error"]
+    assert "cannot render" in by_name["render_ms"]["tags"]["error"]
+
+
+def test_disconnect_ends_the_root_span_where_the_worker_ends(http,
+                                                             monkeypatch):
+    """The handler is cancelled while the worker thread still records
+    stages: the root span is finished when the worker has unwound, so no
+    child outlasts its parent."""
+    import asyncio
+
+    tid = "feedc0de0003"
+    started, release = threading.Event(), threading.Event()
+
+    def slow(_sql, _session):
+        with stages.stage("decode_ms"):
+            started.set()
+            assert release.wait(10)
+        return []
+
+    monkeypatch.setattr(http.server.executor, "execute_sql", slow)
+
+    class Req:
+        headers = {"cnos-trace-id": tid}
+        query = {"db": "public"}
+
+        async def text(self):
+            return "SELECT 1"
+
+    async def drive():
+        task = asyncio.ensure_future(http.server.handle_sql(Req()))
+        while not started.is_set():
+            await asyncio.sleep(0.005)
+        task.cancel()
+        with pytest.raises(asyncio.CancelledError):
+            await task
+
+    asyncio.run_coroutine_threadsafe(drive(), http._loop).result(10)
+    # the worker is still in there: the root stays open
+    assert "http:sql" not in {s["name"] for s in _trace_spans(http, tid)}
+    release.set()
+    for _ in range(200):
+        spans = {s["name"]: s for s in _trace_spans(http, tid)}
+        if "http:sql" in spans:
+            break
+        time.sleep(0.01)
+    root, child = spans["http:sql"], spans["decode_ms"]
+    assert root["tags"]["error"] == "client disconnected"
+    assert child["parent_id"] == root["span_id"]
+    assert child["start_ns"] + child["duration_ns"] \
+        <= root["start_ns"] + root["duration_ns"] + 10**6
+
+
+def test_jitted_programs_have_stable_names():
+    """`ops.program`: the XLA module is `jit_cnosdb_<name>` and the body's
+    operations lie under the `cnosdb.<name>` scope — what a chip trace's
+    `XLA Modules` line shows."""
+    import jax
+    import jax.numpy as jnp
+
+    from cnosdb_tpu import ops
+    from cnosdb_tpu.ops import device_decode, kernels
+
+    f = jax.jit(ops.program("test_named")(lambda x: x * 2 + 1))
+    lowered = f.lower(jnp.ones(8))
+    assert "jit_cnosdb_test_named" in lowered.as_text()
+    assert "cnosdb.test_named" in lowered.as_text(debug_info=True)
+    assert f(jnp.ones(8)).tolist() == [3.0] * 8
+    for fn, name in [(kernels.segment_aggregate, "segment_aggregate"),
+                     (kernels._device_sort, "sort"),
+                     (device_decode._delta_kernel, "decode_delta"),
+                     (device_decode._codes_kernel, "decode_codes")]:
+        assert fn.__name__ == "cnosdb_" + name
+
+
+def test_profile_summary_header_is_never_cut():
+    from cnosdb_tpu.server.http import (PROFILE_SUMMARY_MAX,
+                                        profile_summary_header)
+
+    small = {"decode_ms": 12.5, "scan_miss": 1, "merge_ms": 0.0}
+    d = json.loads(profile_summary_header("7", 20.0, small))
+    assert d == {"qid": "7", "wall_ms": 20.0, "stages": small}
+    # oversized: zero-valued keys go first, then the smallest
+    big = {f"rpc_method_number_{i:04d}_ms": float(i) for i in range(400)}
+    big.update({f"string_path.zero_{i:04d}": 0 for i in range(50)})
+    text = profile_summary_header("8", 99.0, big)
+    assert len(text) <= PROFILE_SUMMARY_MAX
+    d = json.loads(text)                 # parses: never a cut string
+    kept = d["stages"]
+    assert d["qid"] == "8" and d["dropped"] == len(big) - len(kept)
+    assert 0 < len(kept) < len(big)
+    assert all(v for v in kept.values())
+    assert min(kept.values()) > max(
+        v for k, v in big.items() if k not in kept)
+    # zero-valued keys alone were enough here: nothing else is dropped
+    some_zero = dict(small, **{f"string_path.z{i:04d}": 0
+                               for i in range(300)})
+    d = json.loads(profile_summary_header("9", 1.0, some_zero))
+    assert d["stages"] == {"decode_ms": 12.5, "scan_miss": 1}
+    assert d["dropped"] == 301
+
+
+def test_multi_batch_kernel_pool_keeps_the_query_context(tmp_path,
+                                                         monkeypatch):
+    """Two shards → two batches → the kernel pool: the batches' counters,
+    stages and spans must reach the submitting query's profile and trace."""
+    monkeypatch.setenv("CNOSDB_TPU_FORCE_DEVICE_PATH", "1")
+    monkeypatch.setenv("CNOSDB_MESH", "0")
+    meta = MetaStore(str(tmp_path / "meta.json"))
+    engine = TsKv(str(tmp_path / "data"))
+    coord = Coordinator(meta, engine)
+    ex = QueryExecutor(meta, coord)
+    try:
+        ex.execute_one("CREATE DATABASE d2 WITH SHARD 2")
+        sess = Session(database="d2")
+        ex.execute_one("CREATE TABLE m (v BIGINT, TAGS(h))", sess)
+        rows = ", ".join(f"({i * 10**9}, 'h{i % 16}', {i})"
+                         for i in range(400))
+        ex.execute_one(f"INSERT INTO m (time, h, v) VALUES {rows}", sess)
+        from cnosdb_tpu.utils.spans import GLOBAL_COLLECTOR
+
+        prof = stages.QueryProfile()
+        prof.traced = True
+        with GLOBAL_COLLECTOR.span("http:sql") as root, \
+                stages.profile_scope(prof):
+            rs = ex.execute_one(
+                "SELECT h, max(v), count(v) FROM m GROUP BY h", sess)
+        assert rs.n_rows == 16
+        assert prof.counts.get("scan_miss") == 2, prof.counts
+        assert prof.counts.get("fused_launches") == 2, prof.counts
+        assert prof.counts.get("upload_bytes", 0) > 0
+        assert prof.ms["kernel.fetch_ms"] > 0
+        mine = GLOBAL_COLLECTOR.spans(root.trace_id)
+        by_id = {s["span_id"]: s for s in mine}
+        fetches = [s for s in mine if s["name"] == "kernel.fetch_ms"]
+        assert len(fetches) == 2
+        assert all(by_id[s["parent_id"]]["name"] == "kernel_ms"
+                   for s in fetches)
+    finally:
+        coord.close()
 
 
 # ------------------------------------------------------- cluster breakdown
