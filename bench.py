@@ -471,7 +471,7 @@ def decode_bench():
             plan, reason = codecs.split_for_device(b, vt)
             assert plan is not None, reason
             sink = (lambda dense: None) if out_vals is None else None
-            lane.submit(plan, i, "c", vt, i * page_len, page_len, None,
+            lane.submit(plan, i, vt, i * page_len, page_len, None,
                         out_vals, out_valid, sink=sink)
         failed = lane.run()
         assert not failed, f"{len(failed)} device pages failed"

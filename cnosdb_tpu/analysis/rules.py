@@ -560,7 +560,7 @@ class StageCatalog(Rule):
 _DDA_FUNCS = {
     "cnosdb_tpu/storage/codecs.py": ("split_for_device",),
     "cnosdb_tpu/storage/scan.py": ("_submit_device_page",),
-    "cnosdb_tpu/ops/device_decode.py": ("run", "attach_device_columns"),
+    "cnosdb_tpu/ops/device_decode.py": ("run",),
 }
 _DDA_ACCOUNTING = {"_rejected", "_count_fallback", "count_outcome",
                    "declined", "submit", "note_engaged", "count_error"}

@@ -217,31 +217,6 @@ class EagerUploader:
         except Exception:
             stages.count_error("scan.eager_upload")
 
-    def put_device(self, name: str, vt: ValueType, parts: list):
-        """Stage a column already ON DEVICE (ops/device_decode's lane):
-        `parts` are per-page device rows in output order, null-free by
-        contract (attach_device_columns filters). The decoded values
-        never re-cross the pipe — this is the payoff of decoding on the
-        accelerator."""
-        try:
-            with stages.stage("upload_ms"):
-                import jax
-                import jax.numpy as jnp
-
-                cat = parts[0] if len(parts) == 1 \
-                    else jnp.concatenate(parts)
-                if vt == ValueType.UNSIGNED:
-                    cat = jax.lax.bitcast_convert_type(cat, jnp.uint64)
-                elif vt == ValueType.BOOLEAN:
-                    cat = cat.astype(jnp.int64)
-                n = int(cat.shape[0])  # lint: disable=host-sync (shape metadata only — no device data crosses; see EagerUploader docstring)
-                if n < self.n_pad:
-                    cat = jnp.concatenate(
-                        [cat, jnp.zeros(self.n_pad - n, dtype=cat.dtype)])
-                self._cols[name] = (vt, cat, None, True)
-        except Exception:
-            stages.count_error("scan.eager_upload")
-
     def attach(self, batch):
         if self._cols:
             batch._preuploaded = (self.n_pad, self._cols)

@@ -21,7 +21,11 @@ transforms run there as batched jitted kernels:
 
 Batching: pages are padded into fixed-shape [B, L] buffers keyed by
 (kind, width, pow2 length bucket) and B is padded to a pow2, so the jit
-cache sees a handful of shapes regardless of page-size jitter. Outputs
+cache sees a handful of shapes regardless of page-size jitter. The page
+group is also the lane's unit of device work: per group one put of each
+packed operand, one kernel launch and one pull of the whole [B, L]
+batch — a page's row is a numpy view of that pull, never a device array
+of its own. Outputs
 are bit-identical to storage/codecs.decode (verified by the property
 suite in tests/test_device_decode.py) because every transform is
 integer/bitwise: XOR scans, two's-complement cumsum and bitcasts have no
@@ -115,6 +119,12 @@ def count_outcome(lane: str, reason: str, n: int = 1) -> None:
 def outcomes_snapshot() -> dict[tuple[str, str], int]:
     with _LOCK:
         return dict(sorted(_outcomes.items()))
+
+
+def _called(n: int = 1) -> None:
+    """Book n calls the lane made to the device (a put, a kernel launch,
+    a pull): with device_decode_engagements, the round trips a page."""
+    stages.count("device_decode.device_calls", n)
 
 
 def _pow2(n: int, minimum: int) -> int:
@@ -247,8 +257,8 @@ def _codes_kernel(codes):
 # the scan-facing lane
 # ---------------------------------------------------------------------------
 class _Job:
-    __slots__ = ("plan", "token", "colname", "vt", "out_off", "n_rows",
-                 "nm", "out_vals", "out_valid", "sink", "dev")
+    __slots__ = ("plan", "token", "vt", "out_off", "n_rows", "nm",
+                 "out_vals", "out_valid", "sink")
 
 
 class DeviceDecodeLane:
@@ -257,14 +267,12 @@ class DeviceDecodeLane:
     Driven by storage/scan._scan_vnode_native: `submit()` during page
     planning (plans come from codecs.split_for_device — storage stays
     jax-free, this object crosses the boundary via `decode_hook`), one
-    `run()` that executes the batched kernels, writes host outputs back
-    (null-mask expansion included) and returns the tokens of pages whose
-    kernel failed (the caller re-routes those through the Python lane),
-    then `attach_device_columns()` hands fully device-decoded, null-free,
-    contiguously-covering columns to the EagerUploader ON DEVICE — the
-    decoded values never re-cross the pipe, and tpu_exec's fused
-    filter->segment-aggregate launch consumes them via the existing
-    `_preuploaded` plumbing.
+    `run()` that executes the batched kernels, pulls each group's batch
+    once, writes host outputs back (null-mask expansion included) and
+    returns the tokens of pages whose group failed (the caller re-routes
+    those through the Python lane). The decoded columns then live in the
+    scan's host arrays; the scan ships them to the device like any other
+    finished column (EagerUploader.put).
     """
 
     _NUMERIC_ENC = {
@@ -306,17 +314,16 @@ class DeviceDecodeLane:
     def pending(self) -> int:
         return len(self._jobs)
 
-    def submit(self, plan: dict, token, colname, vt, out_off: int,
+    def submit(self, plan: dict, token, vt, out_off: int,
                n_rows: int, nm, out_vals, out_valid, sink=None) -> None:
         """Queue one page. Numeric/time pages write into
         out_vals/out_valid at out_off (nm = null mask, as
         read_field_page returns); string pages deliver dense i32 codes
         to `sink` instead."""
         j = _Job()
-        j.plan, j.token, j.colname, j.vt = plan, token, colname, vt
+        j.plan, j.token, j.vt = plan, token, vt
         j.out_off, j.n_rows, j.nm = out_off, n_rows, nm
         j.out_vals, j.out_valid, j.sink = out_vals, out_valid, sink
-        j.dev = None
         self._jobs.append(j)
 
     # ------------------------------------------------------------- execute
@@ -325,30 +332,47 @@ class DeviceDecodeLane:
         tokens for the caller's Python lane. Every page leaves here
         either decoded or reason-booked (device-decode-accounting rule).
 
-        Per group the lane's device round trips are stages of their own —
-        put and launch (`_run_group`), then the pull — booked once per
-        group, never per page."""
+        The group is the unit of device work: put and launch every group
+        (`_run_group`), then pull each group's whole batch once and write
+        its pages back from numpy views of it — the device runs group k+1
+        while the host lands group k. Dispatch is asynchronous, so a
+        kernel's failure may only surface at its pull: both halves route
+        the group's pages to the Python lane."""
         failed: list = []
         groups: dict = {}
         for j in self._jobs:
             groups.setdefault(self._group_key(j), []).append(j)
+        launched = []
         for key, jobs in groups.items():
             try:
-                dev_rows = self._run_group(key, jobs)
+                launched.append((jobs, self._run_group(key, jobs)))
             except Exception:
-                stages.count_error("device_decode.kernel")
-                for j in jobs:
-                    count_outcome("host", "kernel_error")
-                    failed.append(j.token)
+                failed.extend(self._kernel_error(jobs))
+        launched.reverse()
+        while launched:
+            jobs, out = launched.pop()   # a landed batch leaves the device
+            try:
+                with stages.stage("device_decode.pull_ms"):
+                    # the lane's audited transfer point: one device→host
+                    # pull per page group
+                    host = np.asarray(out)
+                    _called()
+            except Exception:
+                failed.extend(self._kernel_error(jobs))
                 continue
-            with stages.stage("device_decode.pull_ms"):
-                dense = [np.asarray(dev) for dev in dev_rows]  # lint: disable=host-sync (audited transfer point: the decode lane's one pull per page row)
-            for j, dev, host in zip(jobs, dev_rows, dense):
-                j.dev = dev
-                self._writeback(j, host)
+            for bi, j in enumerate(jobs):
+                self._writeback(j, host[bi, :j.plan["n"]])
             count_outcome("device", "ok", len(jobs))
             note_engaged(len(jobs))
         return failed
+
+    @staticmethod
+    def _kernel_error(jobs) -> list:
+        """A group's put, launch or pull raised: book its pages to the
+        host lane → their tokens."""
+        stages.count_error("device_decode.kernel")
+        count_outcome("host", "kernel_error", len(jobs))
+        return [j.token for j in jobs]
 
     def _group_key(self, j: _Job):
         p = j.plan
@@ -359,17 +383,17 @@ class DeviceDecodeLane:
         return (kind, width, _pow2(p["n"], _MIN_LANE))
 
     def _run_group(self, key, jobs):
-        """One (kind, width, length-bucket) batch -> per-job device rows
-        (each sliced to its true value count, still on device). Pack the
-        pages into padded host buffers, put them, launch the kernel and
-        the per-page slices: the two device steps are stages."""
+        """One (kind, width, length-bucket) batch -> its decoded [B, L]
+        batch, still on device and possibly still running. Pack the pages
+        into padded host buffers, put them, launch the kernel: the two
+        device steps are stages."""
         kind, width, lane_len = key
         packed = self._pack_group(kind, width, lane_len, jobs)
         with stages.stage("device_decode.put_ms"):
             operands = [self._put(a) for a in packed]
+            _called(len(operands))
         with stages.stage("device_decode.launch_ms"):
-            out = self._launch_group(kind, lane_len, operands)
-            return [out[bi, :j.plan["n"]] for bi, j in enumerate(jobs)]
+            return self._launch_group(kind, lane_len, operands)
 
     def _pack_group(self, kind, width, lane_len, jobs) -> list:
         """→ the group's kernel operands as host arrays, rows padded to
@@ -414,20 +438,22 @@ class DeviceDecodeLane:
 
     def _launch_group(self, kind, lane_len, operands):
         """→ the [B, L] decoded batch, on device."""
+        if kind == "gorilla" and self._use_pallas \
+                and lane_len <= _XOR_MAX_WIDTH:
+            _called(4)
+            lo, hi = _gorilla_pre_kernel(*operands)
+            lo = _pallas_xor_scan(lo, self._interpret)
+            hi = _pallas_xor_scan(hi, self._interpret)
+            pallas_kernels.note_engaged()
+            return _gorilla_post_kernel(lo, hi)
+        _called()
         if kind == "delta_const":
             firsts, strides = operands
             return _delta_const_kernel(firsts, strides, length=lane_len)
         if kind == "delta":
             return _delta_kernel(*operands)
         if kind == "gorilla":
-            pd, = operands
-            if self._use_pallas and lane_len <= _XOR_MAX_WIDTH:
-                lo, hi = _gorilla_pre_kernel(pd)
-                lo = _pallas_xor_scan(lo, self._interpret)
-                hi = _pallas_xor_scan(hi, self._interpret)
-                pallas_kernels.note_engaged()
-                return _gorilla_post_kernel(lo, hi)
-            return _gorilla_xla_kernel(pd)
+            return _gorilla_xla_kernel(*operands)
         if kind == "bitpack":
             return _bitpack_kernel(*operands)
         return _codes_kernel(*operands)
@@ -456,34 +482,3 @@ class DeviceDecodeLane:
         else:
             j.out_vals[off:off + n][~j.nm] = dense
             j.out_valid[off:off + n] = ~j.nm
-
-    # ------------------------------------------------------ device columns
-    def attach_device_columns(self, uploader, total: int) -> None:
-        """Hand columns whose EVERY page decoded on-device, null-free and
-        covering [0, total) contiguously, to the EagerUploader as device
-        arrays (no host round-trip). Anything else already landed in the
-        host arrays and uploads lazily/eagerly as before."""
-        bycol: dict[str, list[_Job]] = {}
-        for j in self._jobs:
-            if j.colname is None or j.sink is not None:
-                continue
-            bycol.setdefault(j.colname, []).append(j)
-        for name, jobs in bycol.items():
-            jobs.sort(key=lambda j: j.out_off)
-            if any(j.dev is None or j.nm is not None for j in jobs):
-                count_outcome("device", "column_not_resident")
-                continue
-            off = 0
-            for j in jobs:
-                if j.out_off != off:
-                    off = -1
-                    break
-                off += j.n_rows
-            if off != total:
-                count_outcome("device", "column_not_resident")
-                continue
-            try:
-                uploader.put_device(name, jobs[0].vt,
-                                    [j.dev for j in jobs])
-            except Exception:
-                stages.count_error("device_decode.attach")

@@ -399,7 +399,7 @@ def _topk_threshold(vals, *, k: int):
 def dict_mask_gather(mask: np.ndarray, codes):
     """Per-unique predicate mask → row mask on device: one integer gather
     through the dictionary codes (the strkernels broadcast for codes that
-    already live on the accelerator via EagerUploader.put_device)."""
+    already live on the accelerator)."""
     return _dict_mask_gather(jnp.asarray(mask), codes)
 
 

@@ -780,7 +780,7 @@ def _submit_device_page(dev_lane, r, pm, colname, out_off, vt,
     n = pm.n_rows
     token = (r, pm, colname, out_off, vt)
     if colname is None:
-        dev_lane.submit(plan, token, None, ValueType.INTEGER, out_off, n,
+        dev_lane.submit(plan, token, ValueType.INTEGER, out_off, n,
                         None, ts_all, None)
         return True
     if vt in (ValueType.STRING, ValueType.GEOMETRY):
@@ -798,11 +798,11 @@ def _submit_device_page(dev_lane, r, pm, colname, out_off, vt,
             parts.append((_off, DictArray(codes, _values)))
             sv[_off:_off + _n] = valid_p
 
-        dev_lane.submit(plan, token, colname, vt, out_off, n, nm,
+        dev_lane.submit(plan, token, vt, out_off, n, nm,
                         None, None, sink=_sink)
         return True
     out_vals, out_valid = numeric_cols[colname]
-    dev_lane.submit(plan, token, colname, vt, out_off, n, nm,
+    dev_lane.submit(plan, token, vt, out_off, n, nm,
                     out_vals, out_valid)
     return True
 
@@ -1095,9 +1095,12 @@ def _scan_vnode_native(vnode: VnodeStorage, table: str,
         uploader = upload_hook(total)
     dirty_cols = {j[2] for j in py_jobs}
     if uploader is not None and dev_lane is not None:
-        # columns whose every page decoded on-device attach as device
-        # arrays — decoded values never re-cross the PCIe pipe
-        dev_lane.attach_device_columns(uploader, total)
+        # a column the device lane decoded whole has no native task whose
+        # end would ship it: its host array is complete now, so it ships
+        # now, while the other columns' tasks run
+        for name, (vals, valid) in numeric_cols.items():
+            if name not in col_remaining and name not in dirty_cols:
+                uploader.put(name, ftypes[name], vals, valid)
 
     def _run(task):
         g, _colname, desc, out_vals, out_valid, _jobs = task

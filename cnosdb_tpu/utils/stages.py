@@ -63,10 +63,13 @@ STAGE_CATALOG: dict[str, str] = {
     "device_decode.put_ms": "device-decode lane: host→device puts of the "
                             "packed group buffers",
     "device_decode.launch_ms": "device-decode lane: the group's codec "
-                               "kernel + one slice launch per page "
-                               "(dispatch, asynchronous)",
+                               "kernel launch (dispatch, asynchronous)",
     "device_decode.pull_ms": "device-decode lane: the blocking "
-                             "device→host pull of every page's row",
+                             "device→host pull of each group's batch",
+    "device_decode.device_calls": "calls the device-decode lane made to "
+                                  "the device: each put, kernel launch "
+                                  "and group pull (÷ device_decode_"
+                                  "engagements = round trips a page)",
     "plan_ms": "parse + analyze + plan_select, the serving plane's "
                "fingerprint / plan-cache / result-cache lookups included",
     "ingress_wait_ms": "HTTP handler entry → worker thread past the "

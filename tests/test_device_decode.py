@@ -2,8 +2,9 @@
 bit-identical parity against the host decoder across every codec, dtype
 and null pattern (interpret mode on CPU), reason accounting for rejected
 pages, and the end-to-end scan lane — engagements > 0 and batch
-equivalence vs the legacy Python scan, plus device-resident column
-attachment through the EagerUploader."""
+equivalence vs the legacy Python scan, the page group as the lane's unit
+of device work, and the decoded columns' staging through the
+EagerUploader."""
 import os
 
 import numpy as np
@@ -36,13 +37,12 @@ def _device_decode_block(block: bytes, vt: ValueType) -> np.ndarray:
         def sink(dense, _plan=plan):
             got["vals"] = np.asarray(_plan["values"])[dense]
 
-        lane.submit(plan, "tok", "c", vt, 0, n, None, None, None,
-                    sink=sink)
+        lane.submit(plan, "tok", vt, 0, n, None, None, None, sink=sink)
         assert lane.run() == []
         return got["vals"]
     out_vals = np.zeros(n, dtype=vt.numpy_dtype())
     out_valid = np.zeros(n, dtype=bool)
-    lane.submit(plan, "tok", "c", vt, 0, n, None, out_vals, out_valid)
+    lane.submit(plan, "tok", vt, 0, n, None, out_vals, out_valid)
     assert lane.run() == []
     assert out_valid.all()
     return out_vals
@@ -221,7 +221,7 @@ def _schema():
 def _write(v, host, ts, **cols):
     types = {"f": ValueType.FLOAT, "i": ValueType.INTEGER,
              "b": ValueType.BOOLEAN, "s": ValueType.STRING,
-             "u": ValueType.UNSIGNED}
+             "u": ValueType.UNSIGNED, "n": ValueType.INTEGER}
     fields = {name: (int(types[name]),
                      [None if x is None
                       else (x.item() if isinstance(x, np.generic) else x)
@@ -312,28 +312,177 @@ def test_scan_device_lane_multi_flush_and_trim(tmp_engine_dir, rng):
     v.close()
 
 
-def test_scan_device_lane_attaches_device_columns(tmp_engine_dir, rng):
-    """Null-free columns fully decoded on device attach to the batch as
-    `_preuploaded` device arrays through EagerUploader.put_device — and
-    the staged values match the host arrays exactly."""
-    from cnosdb_tpu.ops.device_cache import EagerUploader
-
+def test_scan_device_lane_stages_decoded_columns(tmp_engine_dir, rng):
+    """Columns the device lane decoded whole have no native task to ship
+    them: the scan stages them through EagerUploader.put as soon as the
+    lane has landed — one column over several pages in two page groups,
+    the last page short — and the staged values match the host arrays
+    exactly, zero pad included."""
     v = VnodeStorage(1, tmp_engine_dir, schemas=_schema())
-    n = 800
+    sizes = (800, 700, 130)          # length buckets 1024, 1024, 256
+    n = sum(sizes)
     f = rng.normal(size=n)
     i = rng.integers(-1000, 1000, n)
-    _write(v, "h1", range(n), f=f, i=i)
+    off = 0
+    for k, size in enumerate(sizes):
+        _write(v, f"h{k}", range(size), f=f[off:off + size],
+               i=i[off:off + size])
+        off += size
     v.flush()
-    got = scan_vnode(
-        v, "m", upload_hook=EagerUploader,
-        decode_hook=lambda: device_decode.DeviceDecodeLane(interpret=True))
+    got, lane, _counts = _profiled_device_scan(v)
+    assert len({lane._group_key(j) for j in lane._jobs
+                if j.token[2] == "i"}) == 2
     pre = getattr(got, "_preuploaded", None)
     assert pre is not None, "no columns were staged on device"
     n_pad, cols = pre
-    for name, host_vals in (("f", f), ("i", i)):
+    assert n_pad == 2048
+    for name in ("f", "i"):
         assert name in cols, f"column {name} not device-resident"
         vt, dev_vals, dev_valid, all_valid = cols[name]
         assert all_valid and dev_valid is None
-        np.testing.assert_array_equal(
-            np.asarray(dev_vals)[:n].astype(host_vals.dtype), host_vals)
+        host_vals = got.fields[name][1]
+        staged = np.asarray(dev_vals)
+        assert staged.shape == (n_pad,) and staged.dtype == host_vals.dtype
+        np.testing.assert_array_equal(staged[:n], host_vals)
+        assert not staged[n:].any()
+    np.testing.assert_array_equal(got.fields["i"][1], i)
+    v.close()
+
+
+# ---------------------------------------------------------------------------
+# the page group is the lane's unit of device work
+# ---------------------------------------------------------------------------
+def _mixed_schema():
+    return {"m": TskvTableSchema.new_measurement(
+        "t", "db", "m", tags=["host"],
+        fields=[("i", ValueType.INTEGER), ("u", ValueType.UNSIGNED),
+                ("b", ValueType.BOOLEAN), ("s", ValueType.STRING),
+                ("n", ValueType.INTEGER)])}
+
+
+def _write_mixed(v, n_series, rng):
+    """n_series series of 6 pages each (time, i, u, b, s, n), rows
+    alternating 90 / 300 so every column lies in two length buckets."""
+    for k in range(n_series):
+        rows = 300 if k % 2 else 90
+        _write(v, f"h{k:03d}", range(rows),
+               i=rng.integers(-10**6, 10**6, rows),
+               u=rng.integers(2**63, 2**64, rows, dtype=np.uint64),
+               b=rng.integers(0, 2, rows) > 0,
+               s=[f"v{x}" for x in rng.integers(0, 7, rows)],
+               n=[int(x) if x % 3 else None for x in range(rows)])
+    v.flush()
+
+
+def _profiled_device_scan(v):
+    """→ (batch, its lane, the scan's stage counts), columns staged
+    through an EagerUploader as the coordinator scans."""
+    from cnosdb_tpu.ops.device_cache import EagerUploader
+    from cnosdb_tpu.utils import stages
+
+    lanes = []
+
+    def hook():
+        lanes.append(device_decode.DeviceDecodeLane(interpret=True))
+        return lanes[-1]
+
+    prof = stages.QueryProfile()
+    with stages.profile_scope(prof):
+        got = scan_vnode(v, "m", upload_hook=EagerUploader,
+                         decode_hook=hook)
+    lane, = lanes
+    return got, lane, prof.counts
+
+
+def _host_scan(v):
+    os.environ["CNOSDB_NO_NATIVE_SCAN"] = "1"
+    try:
+        return scan_vnode(v, "m")
+    finally:
+        del os.environ["CNOSDB_NO_NATIVE_SCAN"]
+
+
+@pytest.mark.parametrize("n_series", [2, 7, 70])
+def test_device_calls_follow_groups_not_pages(tmp_engine_dir, rng, n_series):
+    """12, 42 and 420 pages in the same page groups: the scan is bit for
+    bit the host lanes', and the lane calls the device once per operand
+    put, kernel launch and pull of a GROUP — never per page."""
+    v = VnodeStorage(1, tmp_engine_dir, schemas=_mixed_schema())
+    _write_mixed(v, n_series, rng)
+    got, lane, counts = _profiled_device_scan(v)
+    _assert_batches_equal(got, _host_scan(v))
+    assert got.fields["u"][1].dtype == np.uint64
+    assert got.fields["b"][1].dtype == np.bool_
+    assert not got.fields["n"][2].all()
+
+    pages = 6 * n_series
+    assert counts["device_decode_engagements"] == pages == lane.pending()
+    groups = {lane._group_key(j) for j in lane._jobs}
+    # time (const stride), i/u/n deltas, bits, codes — each column in two
+    # length buckets, the null-masked one in narrower ones of its own
+    assert {k[0] for k in groups} == {"delta_const", "delta", "bitpack",
+                                      "dict"}
+    assert {k[2] for k in groups if k[0] == "delta"} == {128, 256, 512}
+    puts = {"delta_const": 2, "delta": 2}
+    want = sum(puts.get(k[0], 1) + 2 for k in groups)
+    assert counts["device_decode.device_calls"] == want <= 4 * len(groups)
+    # every numeric column landed whole, so the scan stages each from its
+    # host array, the null-masked one with its validity
+    n_pad, cols = got._preuploaded
+    assert set(cols) == {"i", "u", "b", "n"}
+    for name, (vt, dev_vals, dev_valid, all_valid) in cols.items():
+        _vt, host_vals, host_valid = got.fields[name]
+        assert all_valid == (name != "n") == (dev_valid is None)
+        staged = np.asarray(dev_vals)
+        if vt == ValueType.BOOLEAN:
+            assert staged.dtype == np.int64
+            staged = staged.astype(np.bool_)
+        assert staged.dtype == host_vals.dtype
+        np.testing.assert_array_equal(staged[:got.n_rows], host_vals)
+        assert not staged[got.n_rows:].any()
+        if dev_valid is not None:
+            np.testing.assert_array_equal(
+                np.asarray(dev_valid)[:got.n_rows], host_valid)
+    v.close()
+
+
+def test_failure_at_the_pull_routes_the_group_to_the_python_lane(
+        tmp_engine_dir, rng, monkeypatch):
+    """Dispatch is asynchronous: a kernel's failure can surface only when
+    its batch is pulled. The group's pages then decode on the Python lane
+    with `kernel_error` booked, the other groups stay on the device, and
+    the scan still answers."""
+    from cnosdb_tpu.utils import stages
+
+    class _FailsAtPull:
+        shape = (1, 1)
+
+        def __array__(self, *_a, **_k):
+            raise RuntimeError("device halted")
+
+    launch = device_decode.DeviceDecodeLane._launch_group
+
+    def launch_group(self, kind, lane_len, operands):
+        out = launch(self, kind, lane_len, operands)
+        return _FailsAtPull() if kind == "delta" else out
+
+    monkeypatch.setattr(device_decode.DeviceDecodeLane, "_launch_group",
+                        launch_group)
+    v = VnodeStorage(1, tmp_engine_dir, schemas=_mixed_schema())
+    _write_mixed(v, 5, rng)
+    key = ("host", "kernel_error")
+    before = device_decode.outcomes_snapshot().get(key, 0)
+    errs = stages.errors_snapshot().get("device_decode.kernel", 0)
+    got, lane, counts = _profiled_device_scan(v)
+    _assert_batches_equal(got, _host_scan(v))
+    n_delta = sum(1 for j in lane._jobs if j.plan["kind"] == "delta")
+    assert n_delta == 3 * 5        # i, u, n of every series
+    assert device_decode.outcomes_snapshot()[key] - before == n_delta
+    n_groups = len({lane._group_key(j) for j in lane._jobs
+                    if j.plan["kind"] == "delta"})
+    assert stages.errors_snapshot()["device_decode.kernel"] - errs \
+        == n_groups > 1
+    assert counts["device_decode_engagements"] == 6 * 5 - n_delta
+    # a column with a page still to decode is not staged yet
+    assert set(got._preuploaded[1]) == {"b"}
     v.close()

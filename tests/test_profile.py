@@ -530,7 +530,7 @@ def test_decode_stage_terms_sum_to_decode_ms(http, monkeypatch):
     the host remainder tile decode_ms; the lane's device stages lie inside
     device_decode_ms, itself inside decode_ms."""
     monkeypatch.setenv("CNOSDB_DEVICE_DECODE", "1")
-    _seed_flushed_ints(http)
+    _seed_flushed_ints(http, hosts=12)
     status, _body, hdrs = http.request(
         "POST", "/api/v1/sql?db=public", _BUCKETED,
         headers={"X-CnosDB-Profile": "1"})
@@ -544,6 +544,10 @@ def test_decode_stage_terms_sum_to_decode_ms(http, monkeypatch):
     assert pull + dispatch <= st["device_decode_ms"] + 0.01 \
         <= st["decode_ms"] + 0.02
     assert st["device_decode_engagements"] > 0
+    # stages are booked per page group, so the lane calls the device
+    # fewer times than it decodes pages (12 hosts: 24 pages, 2 groups)
+    assert 3 <= st["device_decode.device_calls"] \
+        < st["device_decode_engagements"]
 
 
 def test_unprofiled_request_leaves_one_span_and_no_intervals(http):
