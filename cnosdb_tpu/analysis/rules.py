@@ -1255,7 +1255,7 @@ class MemoryAccounting(Rule):
 _MA_FUNCS = {
     "cnosdb_tpu/ops/mesh_exec.py": ("try_mesh_aggregate",),
 }
-_MA_ACCOUNTING = {"count_outcome", "_declined", "count_error"}
+_MA_ACCOUNTING = {"count_outcome", "_declined", "_failed", "count_error"}
 
 
 def _ma_has_accounting(node: ast.AST) -> bool:

@@ -32,6 +32,7 @@ mid-collective, and the lane answers by declining (reason
 """
 from __future__ import annotations
 
+import logging
 import os
 import weakref
 
@@ -48,6 +49,8 @@ faults.register_point(
          "here is a device lost mid-collective — the lane books "
          "device_loss and the query transparently falls back to the "
          "legacy host-merge path")
+
+log = logging.getLogger(__name__)
 
 _MESH_FUNCS = {"count", "sum", "min", "max", "first", "last"}
 _NUMERIC_VTS = (ValueType.FLOAT, ValueType.INTEGER)
@@ -69,6 +72,23 @@ def _declined(reason: str):
 
     mesh.count_outcome("exec", reason)
     return None
+
+
+_logged_failures: set = set()
+
+
+def _failed(reason: str, counter: str, exc: Exception):
+    """A caught failure of the lane: book the error counter and the
+    decline, and say once per (reason, exception type) what it was — the
+    fallback answers, so nothing else would."""
+    stages.count_error(counter)
+    kind = (reason, type(exc).__name__)
+    if kind not in _logged_failures:
+        _logged_failures.add(kind)
+        log.warning("mesh lane declined (%s), the host merge answers: "
+                    "%s: %s", reason, type(exc).__name__, exc,
+                    exc_info=exc)
+    return _declined(reason)
 
 
 # ------------------------------------------------------------ prep cache
@@ -180,9 +200,8 @@ def try_mesh_aggregate(batches, query):
                 return _declined("value_dtype")
     try:
         prep = _build_prep(live, query, m, n_dev)
-    except Exception:
-        stages.count_error("mesh.plan")
-        return _declined("plan_error")
+    except Exception as e:
+        return _failed("plan_error", "mesh.plan", e)
     if prep is None:
         return _declined("segments")
     if prep["n_out"] == 0:
@@ -195,11 +214,10 @@ def try_mesh_aggregate(batches, query):
         faults.fire("mesh.collective")
         with stages.stage("mesh.collective_ms"):
             fetched = _run_collectives(prep, m)
-    except Exception:
+    except Exception as e:
         # a mesh participant died mid-collective (nemesis device_loss,
         # real XLA failure): fall back to the host merge transparently
-        stages.count_error("mesh.collective")
-        return _declined("device_loss")
+        return _failed("device_loss", "mesh.collective", e)
     with stages.stage("mesh.assemble_ms"):
         res = _assemble_merged(prep, query, fetched)
     mesh.count_outcome("exec", "engaged")
@@ -541,22 +559,28 @@ def _build_prep(live, query, m, n_dev):
 
 
 def _run_collectives(prep, m) -> dict:
-    """One collective merge program per aggregated column; fetch the
-    replicated [n_seg] outputs in a single host pull each."""
+    """One collective merge program per aggregated column, every one
+    launched before the first fetch; then the replicated [n_seg] outputs
+    in a single host pull each. The two halves are stages: dispatch is
+    host time, the fetch waits for the devices."""
     from ..parallel.distributed_agg import mesh_merge_kernel
 
     n_seg = prep["n_seg"]
-    outs = {}
-    for c, (vals_dev, valid_dev) in prep["cols_dev"].items():
-        rids, rsegs, rpad = prep["runs_dev"].get(
-            c, (prep["runs_dummy"], prep["runs_dummy"], 0))
-        out = mesh_merge_kernel(
-            vals_dev, valid_dev, prep["seg_dev"], prep["rank_dev"],
-            rids, rsegs, mesh=m, slots=prep["slots"],
-            num_segments=prep["seg_pad"], wants=prep["wants"][c],
-            run_pad=rpad)
-        outs[c] = {k: np.asarray(v)[:n_seg] for k, v in out.items()}  # lint: disable=host-sync (audited transfer point: one replicated pull per merged column)
-    return outs
+    launched = {}
+    with stages.stage("mesh.launch_ms"):
+        for c, (vals_dev, valid_dev) in prep["cols_dev"].items():
+            rids, rsegs, rpad = prep["runs_dev"].get(
+                c, (prep["runs_dummy"], prep["runs_dummy"], 0))
+            launched[c] = mesh_merge_kernel(
+                vals_dev, valid_dev, prep["seg_dev"], prep["rank_dev"],
+                rids, rsegs, mesh=m, slots=prep["slots"],
+                num_segments=prep["seg_pad"], wants=prep["wants"][c],
+                run_pad=rpad)
+    stages.count("mesh.columns", len(launched))
+    with stages.stage("mesh.fetch_ms"):
+        # the lane's one transfer point: a replicated pull per output
+        return {c: {k: np.asarray(v)[:n_seg] for k, v in out.items()}
+                for c, out in launched.items()}
 
 
 def _empty_result(query):

@@ -1108,35 +1108,43 @@ class Coordinator:
                                   frozenset(t["file_ids"]), t["mem_seq"])
         return toks
 
+    @staticmethod
+    def _scan_platform() -> str | None:
+        """The scan device's platform; None where JAX cannot initialize
+        the backend it was given — the one failure a host without an
+        accelerator is allowed, scans then run the host lanes. Whatever
+        else a device lane raises while it is probed or imported fails
+        the scan: a broken lane is not a quiet host path."""
+        from ..ops.placement import scan_device
+
+        try:
+            return scan_device().platform
+        except RuntimeError:
+            return None
+
     def _upload_hook(self):
         """Eager-upload factory for the scan pipeline — only when queries
         will actually take the device path; on pure-CPU placements the
         staging copy is wasted work."""
-        try:
-            from ..ops.placement import scan_device
-            from ..ops.tpu_exec import _FORCE_DEVICE
+        from ..ops.tpu_exec import _FORCE_DEVICE
 
-            if scan_device().platform != "cpu" or _FORCE_DEVICE():
-                from ..ops.device_cache import EagerUploader
+        platform = self._scan_platform()
+        if platform is None or (platform == "cpu" and not _FORCE_DEVICE()):
+            return None
+        from ..ops.device_cache import EagerUploader
 
-                return EagerUploader
-        except Exception:  # lint: disable=swallowed-exception (device probe: no accelerator is the normal case on CPU hosts, not an error)
-            pass
-        return None
+        return EagerUploader
 
     def _decode_hook(self):
         """Device-decode lane factory for the scan pipeline: a fresh
         DeviceDecodeLane per scan when the plane is enabled (real TPU, or
         forced via CNOSDB_DEVICE_DECODE=1), else None — scans then use
         the native/Python host lanes exactly as before."""
-        try:
-            from ..ops import device_decode
+        from ..ops import device_decode
 
-            if device_decode.enabled():
-                return device_decode.DeviceDecodeLane
-        except Exception:  # lint: disable=swallowed-exception (device probe: no accelerator is the normal case on CPU hosts, not an error)
-            pass
-        return None
+        if self._scan_platform() is None or not device_decode.enabled():
+            return None
+        return device_decode.DeviceDecodeLane
 
     def _scan_remote(self, split: PlacedSplit, field_names,
                      fingerprint: str | None = None) -> ScanBatch | None:

@@ -1415,6 +1415,9 @@ def run_server(args) -> int:
     mode = getattr(args, "mode", "singleton")
     if mode == "meta":
         return run_meta_server(args)
+    from . import process
+
+    kept = process.keep_freed_memory()
     # resolve the scan device now: a node that cannot initialize the
     # backend it was given fails here, at start, and says once what
     # every later query profile will repeat
@@ -1552,6 +1555,9 @@ def run_server(args) -> int:
             print(f"flight sql disabled: {e}")
         # hold a strong reference: the loop keeps only weak refs to tasks
         main._ttl_task = asyncio.get_running_loop().create_task(ttl_job())
+        print(f"process: freed memory {'kept' if kept else 'left to the allocator'}, "
+              f"{process.freeze_startup_objects()} start-up objects frozen "
+              f"out of full collections")
         print(f"cnosdb-tpu listening on :{args.http_port} "
               f"(data dir {args.data_dir}, mode {getattr(args, 'mode', 'singleton')})")
         # SIGINT through the loop's own handler (it owns a wakeup fd): the

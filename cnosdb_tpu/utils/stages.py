@@ -187,6 +187,14 @@ STAGE_CATALOG: dict[str, str] = {
                           "per-shard partials folded over the mesh in "
                           "batch order (distributed_agg.mesh_merge_"
                           "kernel) + the replicated-result fetch",
+    "mesh.launch_ms": "mesh exec lane, inside mesh.collective_ms: "
+                      "dispatch of the per-column merge programs (host "
+                      "time; nothing is waited for)",
+    "mesh.fetch_ms": "mesh exec lane, inside mesh.collective_ms: the "
+                     "blocking pulls of the replicated outputs — the "
+                     "devices' run of the programs plus the transfer",
+    "mesh.columns": "collective merge programs launched (one per "
+                    "aggregated column)",
     "mesh.assemble_ms": "mesh exec lane: merged partials → the legacy "
                         "vec-merge AggResult shape",
     "mesh.plan_cache_hit": "mesh prep cache hits — sharded operands "

@@ -447,15 +447,18 @@ def test_histogram_memory_bounded_under_soak():
 
 
 # ------------------------------------------- one timeline per request
-def _seed_flushed_ints(h, hosts=4, steps=300):
+def _seed_flushed_ints(h, hosts=4, steps=300, db="public",
+                       fields=("usage",)):
     """INTEGER fields in TSM files: what the device-decode lane takes."""
     lines = "\n".join(
-        f"cpu,host=h{i} usage={(t * 7 + i) % 100}i "
-        f"{1672531200000000000 + t * 10 * 10**9}"
+        f"cpu,host=h{i} "
+        + ",".join(f"{f}={(t * 7 + i + j) % 100}i"
+                   for j, f in enumerate(fields))
+        + f" {1672531200000000000 + t * 10 * 10**9}"
         for i in range(hosts) for t in range(steps))
-    status, body, _ = h.request("POST", "/api/v1/write?db=public", lines)
+    status, body, _ = h.request("POST", f"/api/v1/write?db={db}", lines)
     assert status == 200, body
-    status, body, _ = h.request("POST", "/api/v1/sql?db=public", "FLUSH")
+    status, body, _ = h.request("POST", f"/api/v1/sql?db={db}", "FLUSH")
     assert status == 200, body
 
 
@@ -548,6 +551,69 @@ def test_decode_stage_terms_sum_to_decode_ms(http, monkeypatch):
     # fewer times than it decodes pages (12 hosts: 24 pages, 2 groups)
     assert 3 <= st["device_decode.device_calls"] \
         < st["device_decode_engagements"]
+
+
+def _seed_sharded_ints(h, monkeypatch, hosts=16, steps=200):
+    """A `WITH SHARD 4` database of two INTEGER fields, flushed, and the
+    mesh lane opened to a table this small on four of the virtual devices."""
+    monkeypatch.setenv("CNOSDB_MESH_MIN_ROWS", "0")
+    monkeypatch.setenv("CNOSDB_MESH_DEVICES", "4")
+    status, body, _ = h.request("POST", "/api/v1/sql?db=public",
+                                "CREATE DATABASE mesh4 WITH SHARD 4")
+    assert status == 200, body
+    _seed_flushed_ints(h, hosts, steps, db="mesh4", fields=("usage", "idle"))
+    return hosts * steps
+
+
+_MESH_KEYS = ("mesh.plan_ms", "mesh.upload_ms", "mesh.collective_ms",
+              "mesh.launch_ms", "mesh.fetch_ms", "mesh.assemble_ms",
+              "mesh.columns", "mesh.rows", "mesh.shards")
+
+
+def test_mesh_request_splits_the_collective_into_launch_and_fetch(
+        http, monkeypatch):
+    """What `mesh_launch_ms` / `mesh_fetch_ms` would read: dispatch of the
+    per-column programs and the blocking pulls, both inside
+    `mesh.collective_ms`; `mesh.columns` counts the programs."""
+    rows = _seed_sharded_ints(http, monkeypatch)
+    status, _body, hdrs = http.request(
+        "POST", "/api/v1/sql?db=mesh4",
+        "SELECT date_bin(INTERVAL '10 minutes', time) AS t, host, "
+        "avg(usage), max(idle) FROM cpu GROUP BY t, host",
+        headers={"X-CnosDB-Profile": "1"})
+    assert status == 200
+    st = json.loads(hdrs["X-CnosDB-Profile-Summary"])["stages"]
+    for k in _MESH_KEYS:
+        assert k in stages.STAGE_CATALOG, k
+        assert k in st, (k, sorted(st))
+    assert st["mesh.launch_ms"] > 0 and st["mesh.fetch_ms"] > 0
+    assert st["mesh.launch_ms"] + st["mesh.fetch_ms"] \
+        <= st["mesh.collective_ms"] + 0.01
+    assert st["mesh.columns"] == 2          # usage and idle: one program each
+    assert st["mesh.rows"] == rows and st["mesh.shards"] == 4
+
+
+def test_traced_mesh_request_holds_the_lane_under_http_sql(http, monkeypatch):
+    _seed_sharded_ints(http, monkeypatch)
+    tid = "feedc0de0029"
+    status, _body, _hdrs = http.request(
+        "POST", "/api/v1/sql?db=mesh4", _BUCKETED,
+        headers={"X-CnosDB-Profile": "1", "cnos-trace-id": tid})
+    assert status == 200
+    spans = _trace_spans(http, tid)
+    by_id = {s["span_id"]: s for s in spans}
+    names = {s["name"] for s in spans}
+    assert {k for k in _MESH_KEYS if k.endswith("_ms")} <= names, sorted(names)
+    for leaf in ("mesh.launch_ms", "mesh.fetch_ms"):
+        chain = _ancestors(next(s for s in spans if s["name"] == leaf), by_id)
+        assert chain[0] == "mesh.collective_ms" and chain[-1] == "http:sql", \
+            chain
+    coll = next(s for s in spans if s["name"] == "mesh.collective_ms")
+    for s in spans:
+        if s["name"] in ("mesh.launch_ms", "mesh.fetch_ms"):
+            assert s["start_ns"] >= coll["start_ns"]
+            assert s["start_ns"] + s["duration_ns"] \
+                <= coll["start_ns"] + coll["duration_ns"] + 10**6
 
 
 def test_unprofiled_request_leaves_one_span_and_no_intervals(http):
