@@ -612,6 +612,22 @@ def from_page_metadata(counts: dict) -> bool:
         and not counts.get("compressed.bytes_materialized")
 
 
+RUN_PATH_COUNTS = ("fused_launches", "mesh.columns", "segment_runs.engaged",
+                   "segment_runs.fallback")
+
+
+def tally_run_path(total: dict, q: Query, prof: dict) -> None:
+    """Add a query's launches and what its reductions said of their run
+    path. The smoke's scans are sorted, so one reduction that counted more
+    runs than its bound and took the row scatter is a fault."""
+    counts = prof.get("counts") or {}
+    for k in RUN_PATH_COUNTS:
+        total[k] = total.get(k, 0) + counts.get(k, 0)
+    if counts.get("segment_runs.fallback"):
+        raise Fail(f"{q.name}: a segment reduction fell back to the row "
+                   f"scatter: {stage_row(prof)}")
+
+
 def stage_row(prof: dict) -> dict:
     row = {k: v for k, v in (prof.get("ms") or {}).items()}
     row.update(prof.get("counts") or {})
@@ -680,6 +696,7 @@ def one_chip(args, workdir: str, state: dict) -> None:
     cold_queries = make_queries(ds, rng, 0)
     readings: dict = {}
     dtypes_on_device: dict = {}
+    run_path: dict = {}
     for variant in range(4):
         for q in cold_queries if variant == 0 \
                 else make_queries(ds, rng, variant):
@@ -690,6 +707,7 @@ def one_chip(args, workdir: str, state: dict) -> None:
             dtypes_on_device.update(
                 (prof.get("device") or {}).get("fused_column_dtypes") or {})
             counts = prof.get("counts") or {}
+            tally_run_path(run_path, q, prof)
             if q.fused and on_device and not from_page_metadata(counts):
                 if not counts.get("fused_launches"):
                     raise Fail(f"{q.name} (variant {variant}): no fused "
@@ -718,6 +736,7 @@ def one_chip(args, workdir: str, state: dict) -> None:
           "decode_fallback": labelled(m, "cnosdb_decode_fallback_total"),
           "errors": labelled(m, "cnosdb_errors_total"),
           "compile_cache": labelled(m, "cnosdb_compile_cache_total"),
+          "run_path": run_path,
           "column_dtypes_on_device": dtypes_on_device,
           "native_library_built": os.path.exists(NATIVE_LIB)})
     check_no_device_errors(m)
@@ -802,8 +821,10 @@ def four_chips(args, workdir: str, state: dict) -> None:
             srv.sql("smoke", "FLUSH")
         shards = 0
         readings = {}
+        run_path: dict = {}
         for q in queries:
             prof, ms = run_query(srv, "smoke", q)
+            tally_run_path(run_path, q, prof)
             shards = max(shards, (prof.get("counts") or {})
                          .get("mesh.shards", 0))
             readings[q.name] = {"ms": round(ms, 1), "stages": stage_row(prof)}
@@ -812,7 +833,7 @@ def four_chips(args, workdir: str, state: dict) -> None:
         emit({"phase": "four_chips", "server": label, "device": device,
               "start_seconds": round(start_s, 2), "queries": readings,
               "matches_oracle": True, "mesh": mesh_table,
-              "mesh_shards": shards,
+              "mesh_shards": shards, "run_path": run_path,
               "errors": labelled(m, "cnosdb_errors_total"),
               "note": "smoke reading, not a benchmark number"})
         check_no_device_errors(m)

@@ -21,12 +21,17 @@ epoch E and query origin O:
 The final index subtracts bmin host-side (folded into `offset`), so no
 per-query recompilation: I_s, rA_s, rA_ns, offset are traced scalars.
 
-Segment reductions here stay on XLA's segment_sum/min/max: this kernel
-derives seg ids ON DEVICE (group_of_series[sid] × n_buckets + bucket), so
-the pallas windowed kernel's host-side applicability check
-(pallas_kernels.applicable — per-tile span < W_WIN over a host seg array)
-cannot run. The pallas route lives in kernels.aggregate_column_host,
-where the host-prep device path has the seg array in host memory.
+Segment reductions are kernels.local_segment_partials: the seg ids are
+derived ON DEVICE (group_of_series[sid] × n_buckets + bucket), and a batch
+is series-major and time-ascending, so they lie in at most
+n_series × n_buckets + 1 contiguous runs — the static bound launch_fused
+hands the program, which then reduces runs, not rows (count, integer sum,
+min, max; the program counts its runs and takes the row scatter itself if
+the bound does not hold, and says so in its last packed row). The pallas
+windowed kernel's host-side applicability check (pallas_kernels.applicable
+— per-tile span < W_WIN over a host seg array) cannot run here; that route
+lives in kernels.aggregate_column_host, where the host-prep device path
+has the seg array in host memory.
 """
 from __future__ import annotations
 
@@ -39,7 +44,8 @@ from ..sql.expr import Expr
 from ..utils import stages
 from . import program
 from .device_cache import DeviceBatch
-from .kernels import local_segment_partials, pad_segments
+from .kernels import (local_segment_partials, note_run_path, pad_segments,
+                      run_pad_for)
 
 _kernel_cache: dict = {}
 
@@ -94,6 +100,10 @@ class PendingFused:
             mat = np.asarray(self.dev_out)  # [n_slots, ns_pad], one transfer
         out: dict[str, dict] = {}
         for i, (col, agg) in enumerate(self.manifest):
+            if col == "__runs__":
+                # the program's own word on its run path, booked per launch
+                note_run_path(mat[i, 0] != 0)
+                continue
             row = mat[i, :self.num_segments]
             if agg == "count" or agg.endswith("_rank") or col in self.int_cols:
                 # exact below 2^53; integer sums beyond that would lose
@@ -140,6 +150,10 @@ def launch_fused(dbatch: DeviceBatch, filter_expr: Expr | None,
     valid_flags = tuple(dbatch.fields[n][2] is not None for n in present)
     has_ts_ns = use_bucket and not dbatch.ns_all_zero
     regular = dbatch.series_params is not None
+    # series-major, time-ascending rows: every series passes each bucket
+    # once, and the zero-padded tail adds a run
+    run_pad = run_pad_for(dbatch.n_pad,
+                          max(dbatch.n_series, 1) * n_buckets + 1)
     # the divisor i_s MUST be a compile-time constant: division by a traced
     # i32 is software-emulated on TPU (~1000× slower); XLA strength-reduces
     # constant divisors to multiplies. Intervals are few (1m/5m/1h/...), so
@@ -150,12 +164,13 @@ def launch_fused(dbatch: DeviceBatch, filter_expr: Expr | None,
     # never uploaded.
     key = (filter_key, cols_key, dtypes_key, ns_pad, n_buckets,
            use_bucket, i_s, dbatch.n_pad, need_rank, valid_flags, has_ts_ns,
-           regular)
+           regular, run_pad)
     entry = _kernel_cache.get(key)
     if entry is None:
         entry = _build_kernel(filter_expr, col_wants, tuple(present), ns_pad,
                               n_buckets, use_bucket, i_s, need_rank,
-                              valid_flags, has_ts_ns, regular, dbatch.n_pad)
+                              valid_flags, has_ts_ns, regular, dbatch.n_pad,
+                              run_pad)
         _kernel_cache[key] = entry
     fn, manifest = entry
 
@@ -212,12 +227,13 @@ def _build_kernel(filter_expr: Expr | None, col_wants: dict,
                   present: tuple, ns_pad: int, n_buckets: int,
                   use_bucket: bool, i_s: int, need_rank: bool,
                   valid_flags: tuple, has_ts_ns: bool, regular: bool,
-                  n_pad: int = 0):
+                  n_pad: int = 0, run_pad: int = 0):
     """→ (jitted fn, manifest). The kernel packs every partial into ONE
     [n_slots, ns_pad] float64 matrix so the host fetches a single transfer
     (one blocking pull per launch, not one per slot). f64 holds counts and
     i32 ranks exactly (< 2^53). Optional inputs are compile-time variants — see
-    launch_fused."""
+    launch_fused. With run_pad > 0 (kernels.run_pad_for) the reductions go
+    by runs and the matrix's last row says whether they did."""
     manifest: list[tuple[str, str]] = [("__presence__", "count")]
     agg_cols = [n for n in present if n in col_wants]
     valid_of = dict(zip(present, valid_flags))
@@ -233,6 +249,8 @@ def _build_kernel(filter_expr: Expr | None, col_wants: dict,
                 manifest.append((name, agg))
                 if agg in ("first", "last"):
                     manifest.append((name, agg + "_rank"))
+    if run_pad:
+        manifest.append(("__runs__", "engaged"))
 
     def kernel(*args):
         i = 0
@@ -299,24 +317,33 @@ def _build_kernel(filter_expr: Expr | None, col_wants: dict,
         else:
             bucket = jnp.zeros_like(sid_ord)
         seg = (group_of_series[sid_ord] * n_buckets + bucket).astype(jnp.int32)
-        seg = jnp.where(mask, seg, 0)
-        presence = jax.ops.segment_sum(mask.astype(jnp.int32), seg, ns_pad)
+        # the ids stay UNMASKED: the filter's mask goes into `valid` only
+        # (a masked row carries the identity wherever it lands), or it
+        # would cut a run at every filtered row
+        part = local_segment_partials(
+            seg, mask, seg, seg, num_segments=ns_pad, run_pad=run_pad,
+            want_sum=False, want_min=False, want_max=False)
+        presence = part["count"]
         results = {("__presence__", "count"): presence}
+        if run_pad:
+            results[("__runs__", "engaged")] = jnp.broadcast_to(
+                part["by_runs"], (ns_pad,))
         for name in agg_cols:
             vals, valid = fields[name]
             w = col_wants[name]
             part = local_segment_partials(
                 vals, (valid & mask) if valid is not None else mask, seg,
                 rank if rank is not None else seg,  # rank unused w/o first/last
-                num_segments=ns_pad,
+                num_segments=ns_pad, run_pad=run_pad,
                 # an all-valid column's count IS the presence count: skip
-                # the extra scatter
+                # the extra reduction
                 want_count=valid is not None,
                 want_sum=w.get("want_sum", False),
                 want_min=w.get("want_min", False),
                 want_max=w.get("want_max", False),
                 want_first=w.get("want_first", False),
                 want_last=w.get("want_last", False))
+            part.pop("by_runs", None)
             if "count" not in part:
                 part["count"] = presence
             for agg, arr in part.items():

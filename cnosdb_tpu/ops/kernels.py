@@ -14,16 +14,23 @@ TPU-first choices:
 - Static shapes: rows and segment counts are padded to size classes
   (pad_rows/pad_segments) so jit caches a handful of programs, not one per
   query.
-- All aggregates in one jit: XLA fuses the mask/select/scatter pipeline
-  over a single pass of the data.
+- All aggregates in one jit: masking, bucket math and reductions compile
+  into one program per query shape.
+- Runs, not rows: XLA lowers jax.ops.segment_sum/min/max to a scatter that
+  applies one update per row in a serial loop (68 ns a row for an i64 sum
+  on a v5e: 143 ms for 2^21 rows). Scan rows arrive series-major and
+  time-ascending, so equal segment ids lie in contiguous runs; given a
+  static bound on their number (`run_pad`) the reduction scans the rows
+  and scatters one partial per run instead (see "run structure" below).
 
 `local_segment_partials` is the single implementation of the reduction
-body; the single-device jit here and the shard_map body in
-parallel/distributed_agg.py both call it.
+body; the single-device jit here, the fused program (ops/fused.py) and
+the shard_map bodies in parallel/distributed_agg.py all call it.
 """
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import numpy as np
 
@@ -61,34 +68,214 @@ def type_extrema(dtype):
     return jnp.array(info.max, dtype), jnp.array(info.min, dtype)
 
 
+# ---------------------------------------------------------------------------
+# run structure: reduce contiguous equal-segment runs, not rows
+# ---------------------------------------------------------------------------
+# A scan batch is series-major and time-ascending and a segment id is
+# group_of_series[sid] · n_buckets + bucket, so equal ids lie in contiguous
+# runs — far fewer runs than rows. XLA's scatter (what jax.ops.segment_*
+# lowers to on the TPU) applies one update per ROW in a serial loop; the
+# run path scans the rows once, reads the scan at each run's last row, and
+# scatters only the run partials. The host lanes' twin is
+# run_segment_partials (ufunc.reduceat over runs).
+
+# Chip readings behind the constants below (one TPU v5 lite, PR 30, a
+# scratch microbench of segment_aggregate, i64 values, count + sum; ms a
+# blocking call / ms a call with ten to sixty in flight):
+#   rows → segments, run_pad     row scatter        run path
+#   2^21 → 8 192,   8 192      162.3  / 161.0      3.81 / 2.72   (fleet)
+#   2^19 → 16 384,  2 048       41.5  / 40.3       1.74 / 0.71   (mesh4, a device)
+#   2^14 → 64,      128          2.11 / 1.14       1.01 / 0.45   (a panel)
+#   2^12 → 64,      512          1.17 / 0.36       1.00 / 0.43   (8 rows a run)
+#   2^11 → 64,      64           1.02 / 0.36       0.96 / 0.46   (a panel)
+#   2^10 → 64,      256          0.94 / 0.38       0.96 / 0.45   (4 rows a run)
+# count + max reads the same to ±3 %. A blocking call's floor is ~0.9 ms
+# and a queued call's 0.36, so under 2^13 rows the two paths answer
+# equally fast; what differs is device time: a scatter update costs ~77 ns
+# (68 i64 + 9 i32 count), the run path ~0.45 ms of small operations
+# whatever the shape, ~1.3 ns a row of scans and, a run, its update plus
+# log2(rows) search gathers at ~7.5 ns (~240 ns at 2^21 rows). The two
+# meet near 5 800 rows and, for long batches, near 3 rows a run. But the
+# device's time is not the request's: with the run path on from 2^13 rows,
+# devops-host-panels (four clients under one GIL; only its 2^14-row
+# launches engaged, ten a query) lost its device 72 % of its busy time
+# (1.404 → 0.373 s of a 10 s window) and still ran 3 % SLOWER end to end in
+# four pairs of four (query_p50_ms 93.6 → 96.3, 92.8 → 95.9): a launch
+# compiled with the run path pulls one more output, its flag, and a device
+# call more costs a crowded Python process more than 1.1 ms less of
+# waiting gives back. So the floor sits where the wait saved is several
+# milliseconds a launch (77 ns × 2^16 rows = 5 ms), not where the device
+# breaks even.
+RUN_PATH_MIN_ROWS = 1 << 16
+RUN_PATH_MIN_ROWS_PER_RUN = 8
+# The scans are Hillis–Steele: log2(N) shifted combines over the whole
+# vector (2^21 rows: 0.8 ms i64 prefix sum, 1.1 ms run-restarting max;
+# compiled for a v5e in 0.6–1.9 s). jnp.cumsum over the same axis runs in
+# 1.0 / 3.0 ms (i32 / i64) but compiles in 7.5 s / 40–92 s, a flagged
+# lax.associative_scan in 307 s; jnp.searchsorted's while loop takes 4.4 ms
+# for 8 192 run ends where the unrolled search below takes 1.9.
+
+
+class SegmentRuns(NamedTuple):
+    """The contiguous equal-segment runs of one segment-id vector."""
+    index: jax.Array     # [N] i32 — the run each row lies in (monotone)
+    ends: jax.Array      # [run_pad] i32 — each run's last row
+    seg: jax.Array       # [run_pad] i32 — each run's segment; unused run
+    #                      slots carry the dead slot `num_segments`
+    engaged: jax.Array   # bool scalar — the rows hold at most run_pad runs
+
+
+def run_pad_for(n_pad: int, max_runs: int) -> int:
+    """The static `run_pad` for a batch of n_pad rows holding at most
+    max_runs runs (the caller's bound, e.g. n_series · n_buckets + 1 for
+    the zero-padded tail), padded to a size class — or 0 (keep the row
+    scatter) where the batch is short or its runs are not several times
+    fewer than its rows: there the run path saves nothing end to end."""
+    run_pad = pad_segments(max(int(max_runs), 1))
+    pays = n_pad >= max(RUN_PATH_MIN_ROWS,
+                        RUN_PATH_MIN_ROWS_PER_RUN * run_pad)
+    return run_pad if pays else 0
+
+
+def _shift(x, k: int, head):
+    """x shifted k places to the right; `head` [k] fills the front."""
+    return jnp.concatenate([head, x[:-k]])
+
+
+def _prefix_sum(x):
+    """Inclusive prefix sum of x [N]. Integer adds wrap, so differences of
+    the prefix stay exact."""
+    k = 1
+    while k < x.shape[0]:
+        x = x + _shift(x, k, jnp.zeros((k,), x.dtype))
+        k <<= 1
+    return x
+
+
+def _run_scan(x, index, op):
+    """Inclusive scan of `op` over x [N] that restarts at every run start
+    (index = the monotone run index): a row combines with the row 2^k
+    back while both lie in one run."""
+    k = 1
+    while k < x.shape[0]:
+        same = _shift(index, k, jnp.full((k,), -1, index.dtype)) == index
+        x = jnp.where(same, op(x, _shift(x, k, x[:k])), x)
+        k <<= 1
+    return x
+
+
+def segment_runs(seg_ids, run_pad: int, num_segments: int) -> SegmentRuns:
+    """Find the runs of seg_ids [N] i32 (trace-time body). The program
+    counts its own runs: `engaged` is False when the rows hold more than
+    run_pad (rows not run-contiguous, or a caller's bound that was wrong)
+    and the reduction then takes the row scatter."""
+    n = seg_ids.shape[0]
+    start = jnp.concatenate(
+        [jnp.ones((1,), jnp.int32),
+         (seg_ids[1:] != seg_ids[:-1]).astype(jnp.int32)])
+    index = _prefix_sum(start) - 1
+    n_runs = index[n - 1] + 1
+    slot = jax.lax.iota(jnp.int32, run_pad)
+    # rows with index <= slot, by an unrolled binary search over the
+    # monotone index, one bit a step — not a compaction: nonzero(size=…)
+    # over N rows lowers to the same serial scatter
+    below = jnp.zeros((run_pad,), jnp.int32)
+    bit = 1 << (n.bit_length() - 1)
+    while bit:
+        cand = below + bit
+        take = (cand <= n) & (index[jnp.minimum(cand, n) - 1] <= slot)
+        below = jnp.where(take, cand, below)
+        bit >>= 1
+    ends = below - 1        # run r's last row; n - 1 for unused slots
+    seg = jnp.where(slot < n_runs, seg_ids[ends], num_segments)
+    return SegmentRuns(index, ends, seg, n_runs <= run_pad)
+
+
+_SEGMENT_OPS = {"count": jax.ops.segment_sum, "sum": jax.ops.segment_sum,
+                "min": jax.ops.segment_min, "max": jax.ops.segment_max}
+
+
+def _masked_inputs(values, valid, names) -> dict:
+    """name → the [N] vector its reduction runs over: masked and null rows
+    carry the reduction's identity."""
+    vmax, vmin = type_extrema(values.dtype)
+    fill = {"sum": jnp.zeros((), values.dtype), "min": vmax, "max": vmin}
+    # count is i32 on device (64-bit int ops are emulated on TPU); a batch
+    # is bounded well below 2^31 rows, host wrappers upcast to i64
+    return {n: valid.astype(jnp.int32) if n == "count"
+            else jnp.where(valid, values, fill[n]) for n in names}
+
+
+def _reduce_rows(inputs: dict, seg_ids, num_segments: int) -> dict:
+    """One scatter update per row (XLA's segment lowering)."""
+    return {n: _SEGMENT_OPS[n](x, seg_ids, num_segments)
+            for n, x in inputs.items()}
+
+
+def _reduce_runs(inputs: dict, runs: SegmentRuns, num_segments: int) -> dict:
+    """The same by runs: each run's partial from a scan read at its last
+    row (count and sum: the prefix sum's step from the run before), then
+    one scatter update per run."""
+    out = {}
+    for n, x in inputs.items():
+        if n in ("count", "sum"):
+            at_end = _prefix_sum(x)[runs.ends]
+            per_run = at_end - _shift(at_end, 1, jnp.zeros((1,), x.dtype))
+        else:
+            op = jnp.minimum if n == "min" else jnp.maximum
+            per_run = _run_scan(x, runs.index, op)[runs.ends]
+        # the dead slot absorbs unused run slots and is sliced off
+        out[n] = _SEGMENT_OPS[n](per_run, runs.seg, num_segments + 1)[:-1]
+    return out
+
+
 def local_segment_partials(values, valid, seg_ids, rank, *, num_segments: int,
+                           run_pad: int = 0,
                            want_count=True, want_sum=True, want_min=True,
                            want_max=True, want_first=False, want_last=False):
     """Masked segment reductions for one column (trace-time body, shared by
-    the local jit and the distributed shard_map program).
+    the local jit, the fused program and the distributed shard_map
+    programs).
 
     values [N], valid [N] bool, seg_ids [N] i32 (padded/filtered rows carry
-    seg 0 with valid=False), rank [N] i32 globally-unique time order.
+    valid=False), rank [N] i32 globally-unique time order.
     → dict of [num_segments] arrays (plus first_rank/last_rank carrying the
     selection keys for cross-shard combination).
+
+    `run_pad` (static; 0 = one scatter update per row) is the caller's
+    bound on the number of contiguous equal-segment runs in seg_ids, from
+    run_pad_for(). With it, count, integer sum, min and max reduce runs:
+    prefix sums and run-restarting scans read at each run's last row, then
+    a scatter of run_pad run partials. Masked and null rows carry the
+    identity and do not cut a run — so hand in the UNMASKED ids and put a
+    filter's mask into `valid` only. The bound is checked, not trusted:
+    with more runs than run_pad the same program takes the row scatter
+    (lax.cond), and the result says which under "by_runs" (bool scalar;
+    present whenever run_pad > 0). Answers are bit-identical either way:
+    integer sums are exact in any association, count / min / max
+    order-free. A FLOATING sum / min / max keeps the row scatter (its
+    association is pinned by the parity tests); first / last stay on the
+    rank scatters. A program that reduces several columns over one seg_ids
+    repeats segment_runs() per call; it is a pure function of seg_ids, so
+    XLA folds the copies into one (tests/test_chip_compile.py counts).
     """
-    out = {}
-    vmax, vmin = type_extrema(values.dtype)
+    names = [n for n, want in (("count", want_count), ("sum", want_sum),
+                               ("min", want_min), ("max", want_max)) if want]
+    integral = jnp.issubdtype(values.dtype, jnp.integer)
+    by_runs = [n for n in names if run_pad and (n == "count" or integral)]
+    out = _reduce_rows(
+        _masked_inputs(values, valid, [n for n in names if n not in by_runs]),
+        seg_ids, num_segments)
+    if run_pad:
+        runs = segment_runs(seg_ids, run_pad, num_segments)
+        out["by_runs"] = runs.engaged
+        out.update(jax.lax.cond(
+            runs.engaged,
+            lambda: _reduce_runs(_masked_inputs(values, valid, by_runs),
+                                 runs, num_segments),
+            lambda: _reduce_rows(_masked_inputs(values, valid, by_runs),
+                                 seg_ids, num_segments)))
     zero = jnp.zeros((), values.dtype)
-    if want_count:
-        # i32 on device (64-bit int ops are emulated on TPU); a batch is
-        # bounded well below 2^31 rows, host wrappers upcast to i64
-        out["count"] = jax.ops.segment_sum(
-            valid.astype(jnp.int32), seg_ids, num_segments)
-    if want_sum:
-        out["sum"] = jax.ops.segment_sum(
-            jnp.where(valid, values, zero), seg_ids, num_segments)
-    if want_min:
-        out["min"] = jax.ops.segment_min(
-            jnp.where(valid, values, vmax), seg_ids, num_segments)
-    if want_max:
-        out["max"] = jax.ops.segment_max(
-            jnp.where(valid, values, vmin), seg_ids, num_segments)
     if want_first:
         key = jnp.where(valid, rank, I32_MAX)
         rmin = jax.ops.segment_min(key, seg_ids, num_segments)
@@ -108,8 +295,8 @@ def local_segment_partials(values, valid, seg_ids, rank, *, num_segments: int,
 
 segment_aggregate = jax.jit(
     program("segment_aggregate")(local_segment_partials),
-    static_argnames=("num_segments", "want_count", "want_sum", "want_min",
-                     "want_max", "want_first", "want_last"))
+    static_argnames=("num_segments", "run_pad", "want_count", "want_sum",
+                     "want_min", "want_max", "want_first", "want_last"))
 
 
 def numpy_segment_partials(values: np.ndarray, valid: np.ndarray,
@@ -274,17 +461,25 @@ def run_segment_partials(values: np.ndarray, seg_ids: np.ndarray,
 
 def aggregate_column_host(values: np.ndarray, valid: np.ndarray,
                           seg_ids: np.ndarray, rank: np.ndarray,
-                          num_segments: int, wants: dict) -> dict:
+                          num_segments: int, wants: dict,
+                          max_runs: int | None = None) -> dict:
     """Host wrapper: pads rows to a size class, runs the jit kernel, pulls
     results back as numpy (sliced to num_segments by the caller).
+
+    `max_runs` is the caller's bound on the contiguous equal-segment runs
+    of seg_ids, known from the plan (series × buckets), not counted here:
+    under four clients every numpy call on the rows is one more release of
+    the GIL, and counting the runs cost the panels ~4 % of their
+    throughput on the chip (PR 30). None keeps the row scatter.
 
     When the pallas segment kernel is enabled (ops/pallas_kernels.enabled:
     CNOSDB_TPU_PALLAS=1 or a real TPU scan device) and this aggregation
     qualifies (pallas_kernels.decline_reason: no first/last, a narrow
     segment span per row tile, and on a TPU a 32-bit value dtype), the
-    storage-layout-aware windowed kernel replaces XLA's sort/scatter
-    segment lowering; everything else books the reason and takes the XLA
-    kernel below."""
+    storage-layout-aware windowed kernel replaces the XLA program;
+    everything else books the reason and takes the XLA kernel below —
+    which reduces runs where the bound leaves several times fewer runs
+    than rows (run_pad_for), one scatter update a row otherwise."""
     n = len(values)
     np_pad = pad_rows(max(n, 1))
     ns_pad = pad_segments(max(num_segments, 1))
@@ -310,18 +505,31 @@ def aggregate_column_host(values: np.ndarray, valid: np.ndarray,
             if "count" in host:
                 host["count"] = host["count"].astype(np.int64)
             return host
+    # +1: the zero-padded tail is a run of its own
+    run_pad = run_pad_for(np_pad, max_runs + 1) if max_runs else 0
     if np_pad != n:
         values = _pad(values, np_pad)
         valid = _pad(valid, np_pad, fill=False)
         seg_ids = _pad(seg_ids, np_pad, fill=0)
         rank = _pad(rank, np_pad, fill=0)
     out = segment_aggregate(values, valid, seg_ids, rank,
-                            num_segments=ns_pad, **wants)
+                            num_segments=ns_pad, run_pad=run_pad, **wants)
     with stages.stage("kernel.fetch_ms"):
-        host = {k: np.asarray(v)[:num_segments] for k, v in out.items()}  # lint: disable=host-sync (THE audited transfer point: one batched pull per aggregate call)
+        host = {k: np.asarray(v) for k, v in out.items()}  # lint: disable=host-sync (THE audited transfer point: one batched pull per aggregate call)
+    note_run_path(host.pop("by_runs", None))
+    host = {k: v[:num_segments] for k, v in host.items()}
     if "count" in host:
         host["count"] = host["count"].astype(np.int64)
     return host
+
+
+def note_run_path(by_runs) -> None:
+    """Book what a launched reduction said of its run path: the pulled
+    "by_runs" flag of local_segment_partials, None where the path was not
+    compiled in."""
+    if by_runs is not None:
+        stages.count("segment_runs.engaged" if by_runs
+                     else "segment_runs.fallback")
 
 
 def _pad(a: np.ndarray, n: int, fill=0):

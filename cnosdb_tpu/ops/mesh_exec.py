@@ -286,7 +286,7 @@ def _build_prep(live, query, m, n_dev):
     lead batch for unfiltered repeats). → prep dict, or None when the
     fold operand would blow the segment budget."""
     from .device_cache import put_sharded
-    from .kernels import pad_rows, pad_segments
+    from .kernels import pad_rows, pad_segments, run_pad_for
     from ..parallel.mesh import SHARD_AXIS
     from jax.sharding import PartitionSpec as P
 
@@ -424,6 +424,14 @@ def _build_prep(live, query, m, n_dev):
             shard_rows[i // slots] += b.n_rows
         row_pad = pad_rows(max(max(shard_rows), 1))
         total = n_dev * row_pad
+        # a bound on a shard's contiguous equal-segment runs, from the
+        # plan alone: every series of a series-major, time-ascending batch
+        # passes each bucket once (+1: the zero-padded tail). String-field
+        # group keys shred that structure: no bound, the row scatter.
+        row_runs = [1] * n_dev
+        for i, b in enumerate(live):
+            row_runs[i // slots] += max(b.n_series, 1) * n_t
+        row_run_pad = 0 if n_gf else run_pad_for(row_pad, max(row_runs))
         seg_arr = np.zeros(total, dtype=np.int32)
         base_valid = np.zeros(total, dtype=bool)
         rank_arr = np.zeros(total, dtype=np.int32)
@@ -542,6 +550,7 @@ def _build_prep(live, query, m, n_dev):
     prep = {
         "n_out": int((presence > 0).sum()), "presence": presence,
         "n_seg": n_seg, "seg_pad": seg_pad, "slots": slots,
+        "row_run_pad": row_run_pad,
         "n_t": n_t, "utimes": utimes, "lab_table": lab_table,
         "gdims": gdims, "gvals": gvals, "sorted_ts": sorted_ts,
         "wants": {c: tuple(sorted(w)) for c, w in wants.items()},
@@ -564,6 +573,7 @@ def _run_collectives(prep, m) -> dict:
     in a single host pull each. The two halves are stages: dispatch is
     host time, the fetch waits for the devices."""
     from ..parallel.distributed_agg import mesh_merge_kernel
+    from .kernels import note_run_path
 
     n_seg = prep["n_seg"]
     launched = {}
@@ -575,12 +585,16 @@ def _run_collectives(prep, m) -> dict:
                 vals_dev, valid_dev, prep["seg_dev"], prep["rank_dev"],
                 rids, rsegs, mesh=m, slots=prep["slots"],
                 num_segments=prep["seg_pad"], wants=prep["wants"][c],
-                run_pad=rpad)
+                run_pad=rpad, row_run_pad=prep["row_run_pad"])
     stages.count("mesh.columns", len(launched))
     with stages.stage("mesh.fetch_ms"):
         # the lane's one transfer point: a replicated pull per output
-        return {c: {k: np.asarray(v)[:n_seg] for k, v in out.items()}
-                for c, out in launched.items()}
+        fetched = {c: {k: np.asarray(v) for k, v in out.items()}
+                   for c, out in launched.items()}
+    for out in fetched.values():
+        note_run_path(out.pop("by_runs", None))
+    return {c: {k: v[:n_seg] for k, v in out.items()}
+            for c, out in fetched.items()}
 
 
 def _empty_result(query):

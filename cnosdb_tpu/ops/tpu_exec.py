@@ -16,6 +16,7 @@ ranges index directly, sparse ones remap through np.unique.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -719,8 +720,15 @@ def launch_scan_aggregate(batch: ScanBatch, query: TpuQuery):
                 rank = batch._zero_rank = np.zeros(n, dtype=np.int32)
 
         # -------------------------------------------- per-column kernels
+        # the device kernel reduces contiguous runs where the plan bounds
+        # them: a series-major, time-ascending batch passes each bucket
+        # once per series, also after a filter compressed its rows.
+        # String-field group keys shred that structure: no bound.
         seg_kernel = (kernels.numpy_segment_partials if cpu_mode
-                      else kernels.aggregate_column_host)
+                      else functools.partial(
+                          kernels.aggregate_column_host,
+                          max_runs=None if gf_dims
+                          else max(batch.n_series, 1) * n_buckets))
         sel_runs = None
         ts_sel = None
         if cpu_mode and sel_idx is not None and not prefer_flat:

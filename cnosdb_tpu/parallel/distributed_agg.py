@@ -71,11 +71,12 @@ def _dist_kernel(values, valid, seg_ids, rank, *, mesh: Mesh,
 
 @functools.partial(
     jax.jit, static_argnames=("mesh", "slots", "num_segments", "wants",
-                              "run_pad"))
+                              "run_pad", "row_run_pad"))
 @program("mesh_merge")
 def mesh_merge_kernel(values, valid, seg_ids, rank, run_sums, run_segs, *,
                       mesh: Mesh, slots: int, num_segments: int,
-                      wants: tuple[str, ...], run_pad: int = 0):
+                      wants: tuple[str, ...], run_pad: int = 0,
+                      row_run_pad: int = 0):
     """Deterministic-order collective merge for the mesh exec lane
     (ops/mesh_exec.py): each shard holds up to `slots` whole scan
     batches, rows carry slot-local segment ids (slot · num_segments +
@@ -103,6 +104,14 @@ def mesh_merge_kernel(values, valid, seg_ids, rank, run_sums, run_segs, *,
     bit-for-bit — and the cross-shard merge below stays collective.
     run_pad == 0 keeps the flat row-order sum (the legacy flat-scatter
     branches and integer columns).
+
+    `row_run_pad` > 0 is the other run structure, the rows' own: a bound
+    (kernels.run_pad_for) on the contiguous equal-segment runs of a
+    shard's seg_ids — whole series-major batches side by side — with which
+    local_segment_partials reduces runs, not rows (count, integer sum,
+    min, max). Each device checks the bound for its shard and takes the
+    row scatter otherwise; the output then carries "by_runs", true when
+    every device reduced by runs.
     """
     want_first = "first" in wants
     want_last = "last" in wants
@@ -111,6 +120,7 @@ def mesh_merge_kernel(values, valid, seg_ids, rank, run_sums, run_segs, *,
     def body(v, m, s, r, rsum, rseg):
         local = local_segment_partials(
             v, m, s, r, num_segments=slots * num_segments,
+            run_pad=row_run_pad,
             want_count=True, want_sum="sum" in wants and not two_level,
             want_min="min" in wants, want_max="max" in wants,
             want_first=want_first, want_last=want_last)
@@ -134,6 +144,9 @@ def mesh_merge_kernel(values, valid, seg_ids, rank, run_sums, run_segs, *,
             return acc
 
         out = {"count": folded("count", jnp.add, cast=jnp.int64)}
+        if "by_runs" in local:
+            out["by_runs"] = jax.lax.pmin(
+                local["by_runs"].astype(jnp.int32), SHARD_AXIS) > 0
         if "sum" in wants:
             out["sum"] = folded("sum", jnp.add)
         if "min" in wants:

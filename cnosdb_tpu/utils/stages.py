@@ -80,6 +80,14 @@ STAGE_CATALOG: dict[str, str] = {
     "upload_ms": "host→device column uploads",
     "upload_bytes": "bytes moved host→device by those uploads",
     "fused_launches": "fused filter/bucket/segment programs launched",
+    "segment_runs.engaged": "device segment reductions (fused launches, "
+                            "segment_aggregate calls, mesh merge programs) "
+                            "that reduced contiguous equal-segment runs",
+    "segment_runs.fallback": "device segment reductions compiled with the "
+                             "run path that counted more runs than their "
+                             "static bound and took the row scatter in "
+                             "the same program (rows not run-contiguous; "
+                             "should read 0)",
     "f64_kept_on_host": "aggregations / top-k selections over FLOAT "
                         "columns kept on the host kernels because the "
                         "scan device does not hold f64 exactly "

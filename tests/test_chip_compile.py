@@ -74,13 +74,35 @@ def test_xla_segment_aggregate(spec, dtype):
              num_segments=SEGMENTS, **ALL_SIX)
 
 
+# rows → segments, bound on runs (kernels.run_pad_for): the run path at the
+# benchmark's shapes — the fleet request, a panel over 40 hosts x 5 h, and
+# the shortest batch that takes it at the fewest rows a run
+RUN_SHAPES = {"fleet": (1 << 21, 8192, 8192), "panel-64k": (1 << 16, 64, 256),
+              "shortest": (1 << 16, 8192, 8192)}
+
+
+@pytest.mark.parametrize("shape", list(RUN_SHAPES))
+def test_xla_segment_aggregate_by_runs(spec, shape):
+    rows, segments, run_pad = RUN_SHAPES[shape]
+    assert kernels.run_pad_for(rows, run_pad) == run_pad
+    compiled = _compile(
+        kernels.segment_aggregate,
+        spec((rows,), jnp.int64), spec((rows,), jnp.bool_),
+        spec((rows,), jnp.int32), spec((rows,), jnp.int32),
+        num_segments=segments, run_pad=run_pad, want_count=True,
+        want_sum=True, want_min=False, want_max=True)
+    # one program holds both branches: the run path and the row scatter
+    assert " conditional(" in compiled.as_text()
+
+
 # ------------------------------------------------------------ fused program
-def _fused_args(spec, n_cols, use_bucket, need_rank, n_series=1000):
+def _fused_args(spec, n_cols, use_bucket, need_rank, n_series=1000,
+                rows=ROWS):
     # launch_fused's argument order for an irregular, second-aligned batch:
     # [ts_sec], sid_ordinal, [rank], packed params, the value columns
-    args = [spec((ROWS,), jnp.int32)] * (1 + use_bucket + need_rank)
+    args = [spec((rows,), jnp.int32)] * (1 + use_bucket + need_rank)
     args.append(spec((4 + n_series,), jnp.int32))    # packed params
-    args += [spec((ROWS,), jnp.int64)] * n_cols
+    args += [spec((rows,), jnp.int64)] * n_cols
     return args
 
 
@@ -96,16 +118,41 @@ FUSED_SHAPES = {
 }
 
 
+@pytest.mark.parametrize("run_pad", [0, 8192], ids=["by-rows", "by-runs"])
 @pytest.mark.parametrize("shape", list(FUSED_SHAPES))
-def test_fused_program(spec, shape):
+def test_fused_program(spec, shape, run_pad):
     flt, col_wants, n_buckets, use_bucket = FUSED_SHAPES[shape]
     present = tuple(sorted(col_wants))
     need_rank = any(w.get("want_last") for w in col_wants.values())
     fn, manifest = fused._build_kernel(
         flt, col_wants, present, SEGMENTS, n_buckets, use_bucket, 3600,
-        need_rank, (False,) * len(present), False, False, ROWS)
+        need_rank, (False,) * len(present), False, False, ROWS, run_pad)
     _compile(fn, *_fused_args(spec, len(present), use_bucket, need_rank))
     assert len(manifest) > len(present)
+
+
+def _fleet_program(spec, n_cols):
+    """devops-fleet-groupby's program (avg of n_cols fields by hour x host
+    over 2^21 rows, 1 000 series, a bound of 8 192 runs), compiled."""
+    rows = 1 << 21
+    run_pad = kernels.run_pad_for(rows, 1000 * 6 + 1)
+    assert run_pad == 8192
+    col_wants = {f: {"want_sum": True} for f in FIELDS[:n_cols]}
+    fn, manifest = fused._build_kernel(
+        None, col_wants, tuple(sorted(col_wants)), SEGMENTS, 6, True, 3600,
+        False, (False,) * n_cols, False, False, rows, run_pad)
+    assert manifest[-1] == ("__runs__", "engaged")
+    return _compile(fn, *_fused_args(spec, n_cols, True, False, rows=rows))
+
+
+def test_fused_program_searches_its_runs_once(spec):
+    """Every column's reduction asks for the run structure of the same
+    segment ids; the compiler folds the copies, so ten columns cost ten
+    prefix sums and one 22-step search for the run ends, not ten."""
+    one = _fleet_program(spec, 1).as_text().count(" gather(")
+    ten = _fleet_program(spec, 10).as_text().count(" gather(")
+    assert one >= 22                 # the search is there at all
+    assert ten - one < 22, (one, ten)
 
 
 # ----------------------------------------------------------- decode kernels
@@ -178,10 +225,13 @@ def test_64bit_columns_are_routed_off_the_pallas_kernel_on_a_tpu(
 
 
 # ------------------------------------------------- the mesh lane, four chips
+@pytest.mark.parametrize("row_run_pad", [0, 2048], ids=["by-rows", "by-runs"])
 @pytest.mark.parametrize("wants", [("count", "sum"), ("count", "last")])
-def test_mesh_merge_kernel_on_four_chips(topo, wants):
-    """chip_smoke.py --chips 4: eight shards over a 2x2 host, two batches
-    per device, one shard_map program per column with all_gather folds."""
+def test_mesh_merge_kernel_on_four_chips(topo, wants, row_run_pad):
+    """chip_smoke.py --chips 4 and the mesh4 cell: eight shards over a 2x2
+    host, two batches per device ([2^19] rows → 2 x 8 192 slots; 2 x ~125
+    series x 6 buckets + 1 = a bound of 2 048 runs), one shard_map program
+    per column with all_gather folds."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from cnosdb_tpu.parallel.distributed_agg import mesh_merge_kernel
@@ -198,7 +248,8 @@ def test_mesh_merge_kernel_on_four_chips(topo, wants):
         mesh_merge_kernel, arr(total, jnp.int64), arr(total, jnp.bool_),
         arr(total, jnp.int32), arr(total, jnp.int32),
         arr(len(topo.devices), jnp.int32), arr(len(topo.devices), jnp.int32),
-        mesh=mesh, slots=2, num_segments=SEGMENTS, wants=wants, run_pad=0)
+        mesh=mesh, slots=2, num_segments=SEGMENTS, wants=wants, run_pad=0,
+        row_run_pad=row_run_pad)
     assert "all-gather" in compiled.as_text()
 
 

@@ -593,6 +593,33 @@ def test_mesh_request_splits_the_collective_into_launch_and_fetch(
     assert st["mesh.rows"] == rows and st["mesh.shards"] == 4
 
 
+@pytest.mark.parametrize("lane", ["fused", "mesh"])
+def test_sorted_scans_reduce_runs_and_say_so(http, monkeypatch, lane):
+    """A flushed scan is series-major and time-ascending: every fused
+    launch (resp. every mesh merge program) over a batch long enough for
+    the run path (kernels.run_pad_for) reduces runs, and the profile
+    counts it from the program's own flag — `segment_runs.engaged` equals
+    the launches, `segment_runs.fallback` never shows."""
+    if lane == "mesh":
+        _seed_sharded_ints(http, monkeypatch, hosts=64, steps=2100)
+        db, launches = "mesh4", "mesh.columns"
+    else:
+        monkeypatch.setenv("CNOSDB_TPU_FORCE_DEVICE_PATH", "1")
+        monkeypatch.setenv("CNOSDB_MESH", "0")
+        _seed_flushed_ints(http, hosts=40, steps=1700)
+        db, launches = "public", "fused_launches"
+    status, body, hdrs = http.request(
+        "POST", f"/api/v1/sql?db={db}", _BUCKETED,
+        headers={"X-CnosDB-Profile": "1"})
+    assert status == 200, body
+    st = json.loads(hdrs["X-CnosDB-Profile-Summary"])["stages"]
+    for k in ("segment_runs.engaged", "segment_runs.fallback"):
+        assert k in stages.STAGE_CATALOG, k
+    assert st.get(launches, 0) >= 1, sorted(st)
+    assert st.get("segment_runs.engaged") == st[launches], sorted(st.items())
+    assert "segment_runs.fallback" not in st
+
+
 def test_traced_mesh_request_holds_the_lane_under_http_sql(http, monkeypatch):
     _seed_sharded_ints(http, monkeypatch)
     tid = "feedc0de0029"
