@@ -355,17 +355,3 @@ def run(paths=None, rules=None, baseline_path: str = BASELINE_PATH,
                   counts=counts, baseline=baseline,
                   rule_totals=rule_totals,
                   wall_ms=round((_time.perf_counter() - t0) * 1000.0, 1))
-
-
-def finding_counts() -> dict:
-    """Whole-tree summary for bench metadata: totals, per-rule finding
-    counts, and the analyzer's wall time, so the cost of the static
-    plane rides in the perf trajectory next to the numbers it guards."""
-    rep = run()
-    return {"findings": len(rep.findings),
-            "baselined": len(rep.findings) - len(rep.violations),
-            "violations": len(rep.violations),
-            "stale_baseline_cells": len(rep.stale),
-            "analyzer_wall_ms": rep.wall_ms,
-            "per_rule": {r: n for r, n in sorted(rep.rule_totals.items())
-                         if n}}

@@ -21,7 +21,7 @@ edges away. This module builds what they are missing:
   longest call chain;
 * a per-function **taint environment** mapping local names to
   host/device, seeded by ``jnp.*``/``jax.*``/``lax.*`` calls,
-  ``device_put``/``pallas_call``, jit aliases, and device-returning
+  ``device_put``, jit aliases, and device-returning
   callees; ``np.asarray``/``float()``/``int()``/``bool()``/``len()``/
   ``.item()``/``.tolist()`` are the *crossings* — their results are
   host (and, in a hot path, the crossing itself is a finding).
@@ -44,8 +44,8 @@ from . import ProjectRule
 _UNRESOLVED = object()                   # memo-table "no entry" marker
 
 # modules whose attribute calls produce device values / dispatch work
-_DEVICE_MODULES = {"jnp", "lax", "pl", "pltpu"}
-_DEVICE_ENTRY_NAMES = {"device_put", "pallas_call"}
+_DEVICE_MODULES = {"jnp", "lax"}
+_DEVICE_ENTRY_NAMES = {"device_put"}
 # under the bare `jax` namespace only these attrs touch arrays —
 # jax.devices() / jax.local_device_count() return host metadata handles
 _JAX_ARRAY_ATTRS = {"numpy", "lax", "ops", "device_put", "jit", "pmap",

@@ -296,9 +296,8 @@ class SwallowedException(Rule):
 # --------------------------------------------------------------------------
 _JAX_PURITY_FILES = ("cnosdb_tpu/ops/kernels.py",
                      "cnosdb_tpu/ops/group_agg.py",
-                     "cnosdb_tpu/ops/pallas_kernels.py",
                      "cnosdb_tpu/ops/device_decode.py")
-_ARRAY_MODULES = {"jnp", "lax", "pl"}
+_ARRAY_MODULES = {"jnp", "lax"}
 
 
 def _contains_jit(expr: ast.AST) -> bool:
@@ -350,9 +349,7 @@ class JaxPurity(Rule):
         for node in ast.walk(ctx.tree):
             if not isinstance(node, ast.Call):
                 continue
-            is_jit = _contains_jit(node.func) or (
-                _call_name(node) == "pallas_call")
-            if not is_jit:
+            if not _contains_jit(node.func):
                 continue
             statics = _static_argnames(node)
             for n in ast.walk(node):
@@ -479,8 +476,7 @@ _METRIC_METHODS = {"incr", "set_gauge", "set_counter", "observe"}
 
 class MetricsNaming(Rule):
     name = "metrics-naming"
-    motivation = ("dashboards and the bench-trajectory tooling key on "
-                  "cnosdb_* naming; unprefixed or mis-suffixed series "
+    motivation = ("dashboards key on cnosdb_* naming; unprefixed or mis-suffixed series "
                   "silently fall out of every query")
     node_types = (ast.Call,)
 
@@ -521,7 +517,8 @@ _STAGE_RECEIVERS = {"stages", "_stages"}
 class StageCatalog(Rule):
     name = "stage-catalog"
     motivation = ("PR 7 profiling plane: EXPLAIN ANALYZE, the slow-query "
-                  "log and bench trend tooling all key on stage names; a "
+                  "log and the benchmark's layer metrics all key on "
+                  "stage names; a "
                   "typo'd or undocumented name silently drifts out of "
                   "every report instead of failing")
     node_types = (ast.Call,)
@@ -662,7 +659,7 @@ _SFA_FUNCS = {
                                      "topk_order_indices"),
     "cnosdb_tpu/sql/expr.py": ("_per_unique_cmp",),
 }
-_SFA_ACCOUNTING = {"note_path", "count", "note_engaged", "count_outcome"}
+_SFA_ACCOUNTING = {"note_path", "count", "count_outcome"}
 
 
 def _sfa_has_accounting(node: ast.AST) -> bool:

@@ -33,7 +33,6 @@ from dataclasses import dataclass
 
 from . import note_verdict
 from .history import History
-from ..utils import stages
 
 
 @dataclass
@@ -192,9 +191,8 @@ def run_client_checks(history: History, observed: set,
 
 
 def book(results: list[CheckResult]) -> list[CheckResult]:
-    """Fold verdicts into the chaos counters (→ /metrics) and the stage
-    counter; returns `results` unchanged for chaining."""
+    """Fold verdicts into the chaos counters (→ /metrics); returns
+    `results` unchanged for chaining."""
     for r in results:
         note_verdict(r.name, r.ok)
-        stages.count("chaos.checks")
     return results

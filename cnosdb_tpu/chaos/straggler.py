@@ -8,9 +8,7 @@ remote` lane, the health scorer and the cancel fan-out all run for
 real; only the *placement* is synthetic (every "replica" serves the
 same local vnode, which is exactly the raft-converged-replicas
 assumption hedging relies on). Used by tests/test_health.py for the
-bit-identical parity + cancellation proofs and by bench_suites.
-run_straggler for the p50/p99 tail numbers, so the benchmark measures
-the very plane the tests pin down.
+bit-identical parity + cancellation proofs.
 """
 from __future__ import annotations
 
@@ -131,23 +129,6 @@ class StragglerBed:
                            node_id=first.node_id,
                            alternates=[(self.vnode_id, r.node_id)
                                        for r in rest])
-
-    def warm_replicas(self, per_replica: int = 8):
-        """Scan each replica directly (round-robin, bypassing the health
-        ranker) so every replica's latency sketch holds honest warm
-        samples — the steady state of a real cluster, where all replicas
-        carry traffic. Without this, a lone cold-path first sample can
-        anchor an otherwise-idle replica's score."""
-        from ..parallel.net import rpc_call
-        payload = {"owner": OWNER, "vnode_id": self.vnode_id,
-                   "table": TABLE, "trs": TimeRanges.all().to_wire(),
-                   "doms": ColumnDomains.all().to_wire(),
-                   "field_names": None}
-        for i in range(per_replica):
-            for r in self.replicas:
-                with deadline_mod.scope(
-                        deadline_mod.Deadline(5.0, qid=f"warm-{r.node_id}-{i}")):
-                    rpc_call(r.addr, "scan_vnode", payload, timeout=5.0)
 
     def scan_once(self, qid: str = "bed", timeout_s: float | None = 5.0,
                   field_names=None):

@@ -28,7 +28,6 @@ import subprocess
 import sys
 
 from .. import faults
-from ..utils import stages
 from . import workload
 
 CRASH_RC = 137          # faults.fire's os._exit code
@@ -108,7 +107,6 @@ def run_one(base: str, point: str, nth: int, seed: int = 7) -> dict:
     spec = f"seed={seed};{point}:crash:nth={nth}"
     root = os.path.join(base, f"{point.replace('.', '_')}_{nth}")
     p = _run_workload(root, spec)
-    stages.count("chaos.crash_sites")
     out = {"point": point, "nth": nth, "spec": spec, "root": root,
            "rc": p.returncode, "crashed": p.returncode == CRASH_RC,
            "repro": repro_command(spec, root)}
@@ -143,74 +141,3 @@ def run_sweep(base: str, points: list[str] | None = None,
                                         if hits.get(p, 0)),
                          "hits": hits, "uncovered": uncovered},
             "runs": runs, "failed": failed}
-
-
-def restore_bench(base: str, rows: int = 2000) -> dict:
-    """Disaster-recovery MTTR for bench.py: seed a small database with
-    WAL archiving on, BACKUP it, destroy the data directory (total node
-    loss), RESTORE from the archive, and report the restore wall time.
-    This is the recovery-time half of the DR story; the data-loss half
-    is bounded by the archive_lag_seconds gauge."""
-    import shutil
-    import time
-
-    from ..parallel.coordinator import Coordinator
-    from ..parallel.meta import MetaStore
-    from ..sql.executor import QueryExecutor
-    from ..storage import backup
-    from ..storage.engine import TsKv
-
-    root = os.path.join(base, "restore_bench")
-    data = os.path.join(root, "data")
-    backup.configure_archive(os.path.join(root, "archive"))
-    try:
-        meta = MetaStore(os.path.join(root, "meta.json"))
-        engine = TsKv(data)
-        ex = QueryExecutor(meta, Coordinator(meta, engine))
-        ex.execute_one("CREATE TABLE r (v DOUBLE, TAGS(h))")
-        step = 500
-        for lo in range(0, rows, step):
-            vals = ",".join(f"({t},'h',{float(t)})"
-                            for t in range(lo, min(lo + step, rows)))
-            ex.execute_one(f"INSERT INTO r (time, h, v) VALUES {vals}")
-        ex.execute_one("BACKUP DATABASE public")
-        for a in backup.archivers():
-            a.wal.seal_active()
-            a.catch_up()
-        engine.close()
-        shutil.rmtree(data)
-        t0 = time.monotonic()
-        engine2 = TsKv(data)
-        ex2 = QueryExecutor(meta, Coordinator(meta, engine2))
-        ex2.coord.restore_database("cnosdb", "public")
-        restore_s = time.monotonic() - t0
-        rs = ex2.execute_one("SELECT COUNT(v) FROM r")
-        n = int(rs.columns[0][0])
-        engine2.close()
-        return {"rows": rows, "restored_rows": n,
-                "restore_mttr_s": round(restore_s, 3), "ok": n == rows}
-    finally:
-        backup.configure_archive(None)
-
-
-def bench_block(base: str, seed: int = 7) -> dict:
-    """Compact summary for bench.py's final JSON: the fast subset's MTTR
-    and checker verdicts, plus the total-loss restore MTTR."""
-    runs = [run_one(base, p, 1, seed=seed) for p in FAST_POINTS]
-    verdicts: dict[str, str] = {}
-    for r in runs:
-        for name, ok, _detail in r.get("results", ()):
-            if verdicts.get(name) != "fail":
-                verdicts[name] = "pass" if ok else "fail"
-    mttrs = [r["mttr_s"] for r in runs if "mttr_s" in r]
-    try:
-        restore = restore_bench(base)
-    except Exception as e:   # DR bench failure must not sink the block
-        stages.count_error("swallow.sweep.restore_bench")
-        restore = {"error": repr(e)[:200]}
-    return {"seed": seed, "crash_sites": len(runs),
-            "all_crashed": all(r["crashed"] for r in runs),
-            "mttr_s_max": max(mttrs) if mttrs else None,
-            "verdicts": verdicts, "restore": restore,
-            "failed": [r["repro"] for r in runs
-                       if not r.get("ok") or not r.get("crashed")]}

@@ -30,7 +30,6 @@ from .. import faults
 from ..errors import CnosError
 from .checker import book, check_matview_parity, run_client_checks
 from .history import History, HistoryRecorder
-from ..utils import stages
 
 SEC = 10**9
 OWNER = "cnosdb.public"
@@ -213,14 +212,13 @@ def verify(root: str) -> dict:
     t0 = time.monotonic()
     engine, coord, ex = _open_db(root)
     try:
-        with stages.stage("chaos.mttr_ms"):
-            try:
-                rows = ex.execute_one("SELECT h, time FROM w").rows()
-            except CnosError:
-                # first read may trip over torn cold state; the
-                # coordinator's recover-and-retry already ran once — a
-                # second attempt proves recovery converged (or fails loud)
-                rows = ex.execute_one("SELECT h, time FROM w").rows()
+        try:
+            rows = ex.execute_one("SELECT h, time FROM w").rows()
+        except CnosError:
+            # first read may trip over torn cold state; the
+            # coordinator's recover-and-retry already ran once — a
+            # second attempt proves recovery converged (or fails loud)
+            rows = ex.execute_one("SELECT h, time FROM w").rows()
         mttr = time.monotonic() - t0
         chaos.note_recovery("crash_restart", mttr)
         observed = {f"{h}:{int(ts)}" for h, ts in rows}

@@ -1,8 +1,7 @@
 """Device-side decode plane: the TSM codecs as batched accelerator kernels.
 
 Cold scans were host-bound: every page decoded on the CPU (native or
-numpy) and only the finished arrays crossed the PCIe pipe (a host-CPU
-bench: decode_ms 71 s cold vs 0.8 ms warm kernel time). Following "GPU
+numpy) and only the finished arrays crossed the PCIe pipe. Following "GPU
 Acceleration of SQL Analytics on Compressed Data" (arxiv 2506.10092),
 this module inverts that: host work stops at the byte-container stage
 (zstd et al — storage/codecs.split_for_device), the still-narrow
@@ -12,9 +11,8 @@ transforms run there as batched jitted kernels:
   delta / delta_ts   widen -> unzigzag -> cumsum   (i64, u64 bit-rides)
   delta const-stride first + stride * iota          (18-byte pages)
   gorilla f64        byte-plane assembly -> log-step prefix-XOR scan
-                     (native/bytetrans.h as lane-parallel u32 planes;
-                     a Pallas kernel when CNOSDB_TPU_PALLAS allows,
-                     else lax.associative_scan)
+                     (native/bytetrans.h as lane-parallel u32 planes,
+                     lax.associative_scan)
   bitpack bool       bit-expansion from packed u8
   string dict pages  narrow code widening (codes on device; the Python
                      dictionary itself stays host-side)
@@ -31,9 +29,9 @@ suite in tests/test_device_decode.py) because every transform is
 integer/bitwise: XOR scans, two's-complement cumsum and bitcasts have no
 rounding.
 
-Gating mirrors pallas_kernels: CNOSDB_DEVICE_DECODE=1 forces the lane on
-(interpret/XLA-on-CPU backends included — how tests engage it), =0 off,
-auto enables it only when the scan device is a real TPU. The scan layer
+Gating: CNOSDB_DEVICE_DECODE=1 forces the lane on (the XLA kernels on a
+CPU backend included — how tests engage it), =0 off, auto enables it only
+when the scan device is a real TPU. The scan layer
 (storage/scan) receives a DeviceDecodeLane via `decode_hook` so storage
 itself stays jax-free; every page the lane examines but does not decode
 books a (lane, reason) outcome — surfaced as
@@ -54,9 +52,7 @@ import jax.numpy as jnp
 from ..models.codec import Encoding
 from ..models.schema import ValueType
 from ..utils import stages
-from jax.experimental import pallas as pl
-
-from . import pallas_kernels, program
+from . import program
 from .placement import exact_on_device
 
 # TPU lane width: value buckets are pow2 multiples of this, so the last
@@ -67,14 +63,14 @@ _WIDTH_DTYPE = {1: np.uint8, 2: np.uint16, 4: np.uint32, 8: np.uint64}
 
 def enabled() -> bool:
     """Should scans route decodes through this plane?
-    CNOSDB_DEVICE_DECODE=1 forces on (XLA/interpret on CPU backends —
-    the test/bench mode), =0 off; default: only on a real TPU."""
+    CNOSDB_DEVICE_DECODE=1 forces on (XLA on CPU backends — the test
+    mode), =0 off; default: only on a real TPU."""
     return disabled_reason() is None
 
 
 def disabled_reason() -> str | None:
     """None when the lane is usable, else WHY not — stamped into query
-    profiles next to pallas_disabled_reason."""
+    profiles as device_decode_disabled_reason."""
     mode = os.environ.get("CNOSDB_DEVICE_DECODE", "auto").lower()
     if mode in ("1", "on", "true"):
         return None
@@ -133,7 +129,7 @@ def _pow2(n: int, minimum: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# kernels (pure XLA; gorilla optionally via Pallas)
+# kernels (pure XLA)
 # ---------------------------------------------------------------------------
 @jax.jit
 @program("decode_delta")
@@ -160,81 +156,19 @@ def _delta_const_kernel(firsts, strides, length):
     return firsts[:, None] + strides[:, None] * idx[None, :]
 
 
-def _assemble_planes(planes):
-    """[B, 8, L] u8 byte planes (plane k = byte k of each u64, little
-    endian) -> (lo, hi) u32 halves of the XOR'd u64 stream."""
-    p = planes.astype(jnp.uint32)
-    lo = p[:, 0] | (p[:, 1] << 8) | (p[:, 2] << 16) | (p[:, 3] << 24)
-    hi = p[:, 4] | (p[:, 5] << 8) | (p[:, 6] << 16) | (p[:, 7] << 24)
-    return lo, hi
-
-
-def _combine_f64(lo, hi):
-    u = lo.astype(jnp.uint64) | (hi.astype(jnp.uint64) << jnp.uint64(32))
-    return jax.lax.bitcast_convert_type(u, jnp.float64)
-
-
 @jax.jit
 @program("decode_gorilla")
 def _gorilla_xla_kernel(planes):
-    """Gorilla f64: untranspose + prefix-XOR scan, XOR running as two
-    independent u32 planes (XOR is bytewise, so the split is exact)."""
-    lo, hi = _assemble_planes(planes)
+    """Gorilla f64: [B, 8, L] u8 byte planes (plane k = byte k of each
+    u64, little endian) -> untranspose + prefix-XOR scan, XOR running as
+    two independent u32 halves (XOR is bytewise, so the split is exact)."""
+    p = planes.astype(jnp.uint32)
+    lo = p[:, 0] | (p[:, 1] << 8) | (p[:, 2] << 16) | (p[:, 3] << 24)
+    hi = p[:, 4] | (p[:, 5] << 8) | (p[:, 6] << 16) | (p[:, 7] << 24)
     lo = jax.lax.associative_scan(jnp.bitwise_xor, lo, axis=1)
     hi = jax.lax.associative_scan(jnp.bitwise_xor, hi, axis=1)
-    return _combine_f64(lo, hi)
-
-
-@jax.jit
-@program("decode_gorilla_pre")
-def _gorilla_pre_kernel(planes):
-    return _assemble_planes(planes)
-
-
-@jax.jit
-@program("decode_gorilla_post")
-def _gorilla_post_kernel(lo, hi):
-    return _combine_f64(lo, hi)
-
-
-def _make_xor_scan_body(steps: int):
-    """Pallas kernel body: log-step (Hillis-Steele) inclusive XOR scan
-    over the lane axis — `steps` = log2(bucket length) unrolled at trace
-    time, each row tile VMEM-resident."""
-    def body(x_ref, o_ref):
-        x = x_ref[...]
-        for k in range(steps):
-            s = 1 << k
-            x = x ^ jnp.concatenate(
-                [jnp.zeros_like(x[:, :s]), x[:, :-s]], axis=1)
-        o_ref[...] = x
-    return body
-
-
-# the scan kernel's block is [_XOR_ROWS, width] u32: one sublane tile of
-# pages, the whole lane axis resident. Past _XOR_MAX_WIDTH the blocks and
-# their shifted temporaries no longer fit the kernel's 16 MiB of VMEM on
-# a v5e (a 2^18 bucket — the largest page — asks for 31.78M); those
-# buckets take the XLA scan.
-_XOR_ROWS = 8
-_XOR_MAX_WIDTH = 1 << 16
-
-
-def _pallas_xor_scan(x, interpret: bool):
-    """x: [b, width] u32, b a multiple of _XOR_ROWS, width a pow2 bucket."""
-    b, width = x.shape
-    steps = max(width.bit_length() - 1, 0)
-    # index maps return i32 explicitly: under x64 a literal 0 is an i64,
-    # which Mosaic cannot legalize beside the i32 grid index
-    spec = pl.BlockSpec((_XOR_ROWS, width), lambda i: (i, jnp.int32(0)))
-    return pl.pallas_call(
-        _make_xor_scan_body(steps),
-        grid=(b // _XOR_ROWS,),
-        in_specs=[spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((b, width), jnp.uint32),
-        interpret=interpret,
-    )(x)
+    u = lo.astype(jnp.uint64) | (hi.astype(jnp.uint64) << jnp.uint64(32))
+    return jax.lax.bitcast_convert_type(u, jnp.float64)
 
 
 @jax.jit
@@ -285,12 +219,7 @@ class DeviceDecodeLane:
                                  int(Encoding.NULL)},
     }
 
-    def __init__(self, interpret: bool | None = None):
-        # interpret= is a test's explicit request; left alone, a TPU
-        # always compiles its kernels for the chip
-        self._interpret = pallas_kernels.interpret_mode() \
-            if interpret is None else bool(interpret)
-        self._use_pallas = pallas_kernels.enabled()
+    def __init__(self):
         self._jobs: list[_Job] = []
 
     def accepts(self, value_type: int, encoding: int) -> bool:
@@ -415,7 +344,6 @@ class DeviceDecodeLane:
                 firsts[bi] = j.plan["first"]
             return [zz, firsts]
         if kind == "gorilla":
-            b_pad = max(b_pad, _XOR_ROWS)
             planes = np.zeros((b_pad, 8, lane_len), dtype=np.uint8)
             for bi, j in enumerate(jobs):
                 n = j.plan["n"]
@@ -438,14 +366,6 @@ class DeviceDecodeLane:
 
     def _launch_group(self, kind, lane_len, operands):
         """→ the [B, L] decoded batch, on device."""
-        if kind == "gorilla" and self._use_pallas \
-                and lane_len <= _XOR_MAX_WIDTH:
-            _called(4)
-            lo, hi = _gorilla_pre_kernel(*operands)
-            lo = _pallas_xor_scan(lo, self._interpret)
-            hi = _pallas_xor_scan(hi, self._interpret)
-            pallas_kernels.note_engaged()
-            return _gorilla_post_kernel(lo, hi)
         _called()
         if kind == "delta_const":
             firsts, strides = operands

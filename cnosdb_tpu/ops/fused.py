@@ -27,11 +27,7 @@ is series-major and time-ascending, so they lie in at most
 n_series × n_buckets + 1 contiguous runs — the static bound launch_fused
 hands the program, which then reduces runs, not rows (count, integer sum,
 min, max; the program counts its runs and takes the row scatter itself if
-the bound does not hold, and says so in its last packed row). The pallas
-windowed kernel's host-side applicability check (pallas_kernels.applicable
-— per-tile span < W_WIN over a host seg array) cannot run here; that route
-lives in kernels.aggregate_column_host, where the host-prep device path
-has the seg array in host memory.
+the bound does not hold, and says so in its last packed row).
 """
 from __future__ import annotations
 
@@ -50,7 +46,7 @@ from .kernels import (local_segment_partials, note_run_path, pad_segments,
 _kernel_cache: dict = {}
 
 # observability: how many fused device programs launched this process
-# (tests assert the device path actually engaged; bench records it)
+# (tests assert the device path actually engaged)
 launch_count = 0
 
 NS_PER_SEC = 1_000_000_000

@@ -17,8 +17,8 @@ win at). This module is the shared engine:
                  multi-chip partials is unchanged
 
 Counters are always on (cheap dict bumps) and surface on /metrics as
-cnosdb_group_agg_total{kind=...}; bench stage timings (factorize_ms,
-group_count, distinct_path.*) ride utils.stages when enabled.
+cnosdb_group_agg_total{kind=...}; the stages factorize_ms and
+group_count ride the query's profile (utils.stages).
 """
 from __future__ import annotations
 
@@ -160,19 +160,16 @@ def distinct_count(gid: np.ndarray, values: np.ndarray,
     f = factorize(values)
     if f is None:
         _count("distinct_fallback")
-        stages.count("distinct_path.fallback")
         return None
     if device_enabled() and len(gid) >= 65536:
         out = _device_distinct_count(gid, f.codes, n_groups, f.n_values)
         if out is not None:
             _count("distinct_device")
-            stages.count("distinct_path.device")
             return out
     pairs = distinct_pairs(gid, f.codes, f.n_values)
     out = np.bincount((pairs // max(f.n_values, 1)).astype(np.int64),
                       minlength=n_groups).astype(np.int64)
     _count("distinct_sort")
-    stages.count("distinct_path.sort")
     return out[:n_groups]
 
 

@@ -388,8 +388,8 @@ def run_segment_partials(values: np.ndarray, seg_ids: np.ndarray,
     """Segment reductions over contiguous equal-segment runs.
 
     The storage-layout-aware twin of numpy_segment_partials: sequential
-    ufunc.reduceat over runs replaces scatter bincount/ufunc.at (5-8×
-    faster on one core at bench scale), then tiny per-run combines fold
+    ufunc.reduceat over runs replaces scatter bincount/ufunc.at, then
+    tiny per-run combines fold
     runs into segments. ALL rows are assumed valid — callers compress
     invalid rows out first (compression preserves run structure).
 
@@ -470,41 +470,12 @@ def aggregate_column_host(values: np.ndarray, valid: np.ndarray,
     of seg_ids, known from the plan (series × buckets), not counted here:
     under four clients every numpy call on the rows is one more release of
     the GIL, and counting the runs cost the panels ~4 % of their
-    throughput on the chip (PR 30). None keeps the row scatter.
-
-    When the pallas segment kernel is enabled (ops/pallas_kernels.enabled:
-    CNOSDB_TPU_PALLAS=1 or a real TPU scan device) and this aggregation
-    qualifies (pallas_kernels.decline_reason: no first/last, a narrow
-    segment span per row tile, and on a TPU a 32-bit value dtype), the
-    storage-layout-aware windowed kernel replaces the XLA program;
-    everything else books the reason and takes the XLA kernel below —
-    which reduces runs where the bound leaves several times fewer runs
-    than rows (run_pad_for), one scatter update a row otherwise."""
+    throughput on the chip (PR 30). None keeps the row scatter; a bound
+    reduces runs where it leaves several times fewer runs than rows
+    (run_pad_for), one scatter update a row otherwise."""
     n = len(values)
     np_pad = pad_rows(max(n, 1))
     ns_pad = pad_segments(max(num_segments, 1))
-    from . import pallas_kernels as pk
-
-    if pk.enabled() and n:
-        # routing BEFORE any padding copy or launch (the layout check is
-        # O(n/R_TILE))
-        reason = pk.decline_reason(values.dtype, wants, seg_ids)
-        if reason is not None:
-            pk.note_declined(reason)
-        else:
-            # pad seg with the edge value (not 0) so trailing tiles keep
-            # their narrow window; padded rows are valid=False either way
-            v2 = _pad(values, np_pad)
-            ok2 = _pad(valid, np_pad, fill=False)
-            sg2 = _pad(seg_ids, np_pad, fill=seg_ids[n - 1])
-            out = pk.segment_partials_pallas(
-                v2, ok2, sg2.astype(np.int32, copy=False), ns_pad,
-                wants=wants, interpret=pk.interpret_mode())
-            pk.note_engaged()
-            host = {k: v[:num_segments] for k, v in out.items()}
-            if "count" in host:
-                host["count"] = host["count"].astype(np.int64)
-            return host
     # +1: the zero-padded tail is a run of its own
     run_pad = run_pad_for(np_pad, max_runs + 1) if max_runs else 0
     if np_pad != n:

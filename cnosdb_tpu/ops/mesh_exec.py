@@ -92,7 +92,7 @@ def _failed(reason: str, counter: str, exc: Exception):
 
 
 # ------------------------------------------------------------ prep cache
-# Warm repeat queries (dashboards, the bench sweep) re-aggregate the same
+# Warm repeat queries (dashboards) re-aggregate the same
 # scan snapshot: the sharded device operands are pure functions of
 # (batch set, group shape) for unfiltered queries, so they cache on the
 # lead batch. Accounted to the memory broker as its own pool — reclaim
@@ -167,7 +167,7 @@ def try_mesh_aggregate(batches, query):
             os.environ.get("CNOSDB_MESH_FIELDS", "0") != "1":
         # string/numeric field group axes merge through the dict path in
         # the legacy engine, whose row order this lane cannot reproduce;
-        # opt in (parity tests and the bench do) when ORDER BY pins it
+        # opt in (the parity tests do) when ORDER BY pins it
         return _declined("group_fields")
     if any(not getattr(b, "_mesh_local", False) for b in batches):
         # off-mesh replica partials arrive over RPC msgpack — the
@@ -301,9 +301,7 @@ def _build_prep(live, query, m, n_dev):
     if cache_ok:
         hit = getattr(live[0], "_mesh_prep", None)
         if hit is not None and hit[0] == key:
-            stages.count("mesh.plan_cache_hit")
             return hit[1]
-    stages.count("mesh.plan_cache_miss")
 
     with stages.stage("mesh.plan_ms"):
         masks = [host_row_mask(b, query.filter) for b in live]

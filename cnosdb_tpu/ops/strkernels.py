@@ -54,31 +54,17 @@ from ..utils import stages
 from ..utils.bloom import BloomFilter
 
 # ---------------------------------------------------------------------------
-# engagement + outcome accounting (mirrors ops.device_decode)
+# outcome accounting (mirrors ops.device_decode)
 # ---------------------------------------------------------------------------
 _LOCK = threading.Lock()
-_engagements = 0
 _outcomes: dict[tuple[str, str], int] = {}
 
 
 def enabled() -> bool:
     """CNOSDB_STR_LANE=0 routes LIKE back to the per-unique regex path
-    (the pre-plane behavior) — the bench A/B and parity-oracle knob."""
+    (the pre-plane behavior) — the parity-oracle knob."""
     return os.environ.get("CNOSDB_STR_LANE", "1").lower() \
         not in ("0", "off", "false")
-
-
-def note_engaged(n: int = 1) -> None:
-    global _engagements
-    with _LOCK:
-        _engagements += n
-
-
-def engagements() -> int:
-    """Predicates answered by the per-unique/ngram lanes this process
-    (bench.py reports this as string_filter_engagements)."""
-    with _LOCK:
-        return _engagements
 
 
 def note_path(path: str, reason: str, n: int = 1) -> None:
@@ -87,8 +73,6 @@ def note_path(path: str, reason: str, n: int = 1) -> None:
     with _LOCK:
         _outcomes[(path, reason)] = _outcomes.get((path, reason), 0) + n
     stages.count(f"string_path.{path}", n)
-    if path in ("per_unique", "ngram_skip"):
-        note_engaged(n)
 
 
 def outcomes_snapshot() -> dict[tuple[str, str], int]:

@@ -295,8 +295,6 @@ def _seg_layout(batch: ScanBatch, group_tags, group_fields, group_of_series,
     batch under the same key the kernel path uses — one derivation serves
     both the segment kernels and the host distinct/collect merges.
     → (seg_ids, bucket_starts, n_buckets, seg_cache, seg_key)."""
-    from ..utils import stages as _stages
-
     n = batch.n_rows
     seg_key = (tuple(group_tags), tuple(group_fields),
                origin, interval, bmin, dense_span)
@@ -306,10 +304,8 @@ def _seg_layout(batch: ScanBatch, group_tags, group_fields, group_of_series,
             seg_cache = batch._seg_cache = {}
         cached = seg_cache.get(seg_key)
     if cached is not None:
-        _stages.count("kernel_cache.hit")
         seg_ids, bucket_starts, n_buckets = cached[:3]
         return seg_ids, bucket_starts, n_buckets, seg_cache, seg_key
-    _stages.count("kernel_cache.miss")
     group_of_row = group_of_series[batch.sid_ordinal]
     if gf_dims:
         group_of_row = group_of_row.astype(np.int64)

@@ -495,7 +495,7 @@ def counters_snapshot() -> tuple[dict, dict]:
 
 
 def reset_counters() -> None:
-    """Test / bench isolation."""
+    """Test isolation."""
     with _ctr_lock:
         _counters.clear()
 

@@ -80,9 +80,8 @@ def make_mesh(n_devices: int | None = None) -> Mesh:
 
 def get_mesh() -> Mesh | None:
     """The process-wide execution mesh, built once from the placement
-    plane's device pool. CNOSDB_MESH_DEVICES caps the width (the bench
-    sweep uses it to scale 1→2→4→8 on a fixed virtual-device pool);
-    None when the pool is empty."""
+    plane's device pool. CNOSDB_MESH_DEVICES caps the width; None when
+    the pool is empty."""
     global _cached_mesh, _cached_key
     want = os.environ.get("CNOSDB_MESH_DEVICES")
     with _lock:

@@ -85,7 +85,7 @@ def width_histogram() -> dict[int, int]:
 
 
 def reset_counters() -> None:
-    """Test/bench isolation for the process-global serving counters."""
+    """Test isolation for the process-global serving counters."""
     with _counters_lock:
         _COUNTERS.clear()
         _WIDTHS.clear()

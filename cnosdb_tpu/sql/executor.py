@@ -199,8 +199,8 @@ class QueryExecutor:
             self._tls = _th.local()
         prev_qid = getattr(self._tls, "qid", None)
         self._tls.qid = qid
-        # always-on per-query profile: adopt an ambient one (bench /
-        # EXPLAIN ANALYZE / a caller-installed scope) or own a fresh one
+        # always-on per-query profile: adopt an ambient one (EXPLAIN
+        # ANALYZE / a caller-installed scope) or own a fresh one
         prof = stages.current_profile()
         own_prof = prof is None
         if own_prof:
@@ -4918,7 +4918,6 @@ def _merge_distinct_vec(acc: dict, batch, plan: AggregatePlan,
         codes, dic, nv = f.codes, f.values, f.n_values
     pairs = _ga.distinct_pairs(inv, codes, nv)
     _ga._count("distinct_sort")
-    stages.count("distinct_path.sort")
     nvm = max(nv, 1)
     pseg = pairs // nvm
     pval = pairs % nvm

@@ -418,7 +418,6 @@ class MatviewEngine:
                                     launch_scan_aggregate)
         from ..storage.scan import scan_vnode
 
-        t0 = time.perf_counter()
         batch = scan_vnode(
             v, vdef.table,
             time_ranges=TimeRanges([TimeRange(hwm, end - 1)]),
@@ -447,12 +446,6 @@ class MatviewEngine:
         # run ahead of the partials it describes
         self.tracker.set(f"{vdef.name}@{vdef.owner}:{vnode_id}", end)
         _count("refresh")
-        stages.count("matview.delta_rows",
-                     int(batch.n_rows) if batch is not None else 0)
-        prof = stages.current_profile()
-        if prof is not None:
-            prof.add_ms("matview.refresh_ms",
-                        (time.perf_counter() - t0) * 1e3)
         return True
 
     # ------------------------------------------------------- state storage

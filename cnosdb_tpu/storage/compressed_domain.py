@@ -455,7 +455,6 @@ class ScanLane:
         self.pages_answered = 0
         self.pages_skipped = 0
         self.pages_masked = 0
-        self.bytes_avoided = 0
         self.bytes_materialized = 0   # job page bytes the lane DID read
 
     # -- plan filtering ---------------------------------------------------
@@ -488,12 +487,6 @@ class ScanLane:
                 out.append(("n", sid, new_chunks, n2, trim, pruned))
         return out
 
-    def _page_bytes(self, cols, tp, i) -> int:
-        total = tp.size
-        for col in cols.values():
-            total += col.pages[i].size
-        return total
-
     def _classify(self, sid, r, cm, cols, i, keep_idx) -> int:
         """Classify page i; append to keep_idx when it must materialize.
         → rows removed from the series plan (0 when kept)."""
@@ -522,7 +515,6 @@ class ScanLane:
                 # conjunct on it fails ⇒ no row of the page survives
                 count_outcome("skip", "null_column")
                 self.pages_skipped += 1
-                self.bytes_avoided += self._page_bytes(cols, tp, i)
                 return tp.n_rows
             pm = colmeta.pages[i]
             evt = spec.col_types[colname]
@@ -532,7 +524,6 @@ class ScanLane:
             if v == _FALSE:
                 count_outcome("skip", "pred_false")
                 self.pages_skipped += 1
-                self.bytes_avoided += self._page_bytes(cols, tp, i)
                 return tp.n_rows
             if v == _MIXED:
                 verdict = _MIXED
@@ -711,12 +702,6 @@ class ScanLane:
         if job_aggs or count_aliases:
             self.jobs.append((sid, r, tp,
                               tuple(job_aggs), tuple(count_aliases), bts))
-        avoided = self._page_bytes(cols, tp, i)
-        for _f, _c, _a, pm, _t in job_aggs:
-            avoided -= pm.size
-        if job_aggs or count_aliases:
-            avoided -= tp.size
-        self.bytes_avoided += max(0, avoided)
         return tp.n_rows
 
     # -- deferred jobs ----------------------------------------------------
@@ -913,9 +898,3 @@ class ScanLane:
         batch._compressed_engaged = self.engaged
         if self.pages_answered:
             stages.count("compressed.pages_answered", self.pages_answered)
-        if self.pages_skipped:
-            stages.count("compressed.pages_skipped", self.pages_skipped)
-        if self.pages_masked:
-            stages.count("compressed.pages_masked", self.pages_masked)
-        if self.bytes_avoided:
-            stages.count("compressed.bytes_avoided", self.bytes_avoided)

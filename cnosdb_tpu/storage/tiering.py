@@ -27,8 +27,8 @@ Physical layout per tiered file ``_{id:06d}.tsm``:
 
 Every exit out of the cold lane books a (lane, reason) into
 ``cnosdb_cold_tier_total`` — enforced by the ``cold-tier-accounting``
-lint rule — so download-vs-decode time and silent fallbacks stay visible
-on /metrics and in EXPLAIN ANALYZE (``cold.*`` stages).
+lint rule — so downloads and silent fallbacks stay visible on /metrics
+(EXPLAIN ANALYZE shows ``cold.pages_pruned``).
 """
 from __future__ import annotations
 
@@ -419,17 +419,13 @@ class ColdTsmReader(TsmReader):
             else:
                 ranges.append([off, size])
         downloaded = 0
-        with stages.stage("cold.fetch_ms"):
-            for start, length in ranges:
-                raw = self._store.get_range(self.key, start, length)
-                downloaded += len(raw)
-                for off, size in want:
-                    if start <= off and off + size <= start + len(raw):
-                        _cache_put(self.key, off,
-                                   raw[off - start:off - start + size])
-        stages.count("cold.range_gets", len(ranges))
-        stages.count("cold.pages_fetched", len(want))
-        stages.count("cold.bytes_downloaded", downloaded)
+        for start, length in ranges:
+            raw = self._store.get_range(self.key, start, length)
+            downloaded += len(raw)
+            for off, size in want:
+                if start <= off and off + size <= start + len(raw):
+                    _cache_put(self.key, off,
+                               raw[off - start:off - start + size])
         _count_cold("fetch", "range_gets", len(ranges))
         _count_cold("fetch", "pages_fetched", len(want))
         _count_cold("fetch", "bytes_downloaded", downloaded)

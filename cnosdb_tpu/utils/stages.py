@@ -2,7 +2,7 @@
 
 Every `stage()` / `count()` call lands in the *active query's*
 :class:`QueryProfile` (a contextvar installed at ingress by the SQL
-executor, by `EXPLAIN ANALYZE`, or by bench.py). With no profile in
+executor or by `EXPLAIN ANALYZE`). With no profile in
 scope both are a single contextvar read — cheap enough to leave on in
 production. Profiles propagate:
 
@@ -47,7 +47,7 @@ from contextlib import contextmanager
 from . import lockwatch, spans
 
 # The documented profile schema. A name missing here is invisible to
-# every dashboard/bench consumer, so the lint plane refuses it.
+# every consumer of a profile, so the lint plane refuses it.
 STAGE_CATALOG: dict[str, str] = {
     "scan_hit": "coordinator scan-snapshot cache hits",
     "scan_miss": "coordinator scan-snapshot cache misses (full decode)",
@@ -102,22 +102,6 @@ STAGE_CATALOG: dict[str, str] = {
     "group_count": "output group cardinality per query",
     "group_spill": "group-by accumulator epochs spilled to disk by the "
                    "memory broker's GroupSpiller (sql/executor.py)",
-    "distinct_path.sort": "count(DISTINCT) via host sorted pair codes",
-    "distinct_path.device": "count(DISTINCT) via the jax segment kernels",
-    "distinct_path.fallback": "count(DISTINCT) via the scalar set fold",
-    "pallas_engagements": "aggregations that ran through a Pallas kernel",
-    "pallas_declined": "aggregations the enabled Pallas segment kernel "
-                       "routed to the XLA kernel (reason in the profile's "
-                       "device telemetry)",
-    "kernel_cache.hit": "segment-geometry/program cache hits on the "
-                        "device batch (compile/derive skipped)",
-    "kernel_cache.miss": "segment-geometry/program cache misses "
-                         "(derived data rebuilt, jit may recompile)",
-    "matview.refresh_ms": "materialized-rollup delta refresh (scan the "
-                          "[hwm, watermark) slice + fold + persist)",
-    "matview.delta_rows": "raw rows folded into rollup partials by delta "
-                          "refreshes (full-history's worth means the "
-                          "watermark is not advancing)",
     "matview.hit": "aggregate queries rewritten to read sealed buckets "
                    "from a materialized rollup",
     "matview.miss": "rewrite-eligible aggregate queries no registered "
@@ -131,12 +115,6 @@ STAGE_CATALOG: dict[str, str] = {
     "compressed.pages_answered": "pages whose aggregate contribution "
                                  "came from stats/closed forms — never "
                                  "decoded into rows",
-    "compressed.pages_skipped": "pages proven predicate-false from "
-                                "encoded form — zero bytes touched",
-    "compressed.pages_masked": "pages filtered in code space (dict/"
-                               "bitpack masks) — only survivors gather",
-    "compressed.bytes_avoided": "page bytes the compressed-domain lane "
-                                "kept out of every decode lane",
     "compressed.bytes_materialized": "page bytes that DID enter a decode "
                                      "lane (the ≥5× drop the lane exists "
                                      "to produce on selective scans)",
@@ -145,22 +123,8 @@ STAGE_CATALOG: dict[str, str] = {
     "topk.device": "ORDER BY+LIMIT thresholds computed by jax.lax.top_k",
     "topk.declined": "ORDER BY+LIMIT shapes outside the top-k fast path "
                      "(nulls/NaN/object keys, k≥n) — full sort",
-    "cold.fetch_ms": "ranged object-store GETs for cold-tier pages "
-                     "(storage/tiering.py fetch_pages)",
-    "cold.range_gets": "coalesced byte-range requests issued to the "
-                       "object store by cold scans",
-    "cold.pages_fetched": "cold pages whose bytes were downloaded "
-                          "(cache misses after pruning)",
-    "cold.bytes_downloaded": "bytes fetched from the object store by "
-                             "cold scans (vs. bytes the pages span)",
     "cold.pages_pruned": "cold pages eliminated locally by sidecar zone "
                          "maps/constraints — zero bytes downloaded",
-    "chaos.checks": "consistency-checker verdicts evaluated by the "
-                    "nemesis plane (chaos/checker.py)",
-    "chaos.crash_sites": "crash-point sweep runs executed — one per "
-                         "(fault point, nth crossing) pair",
-    "chaos.mttr_ms": "crash→first-successful-read recovery time measured "
-                     "by chaos workload verify",
     "serving.plan_hit": "SELECTs answered from a cached analyzed plan "
                         "(parse+analyze+plan all skipped)",
     "serving.plan_rebind": "template fingerprint hits re-bound with new "
@@ -205,11 +169,6 @@ STAGE_CATALOG: dict[str, str] = {
                     "aggregated column)",
     "mesh.assemble_ms": "mesh exec lane: merged partials → the legacy "
                         "vec-merge AggResult shape",
-    "mesh.plan_cache_hit": "mesh prep cache hits — sharded operands "
-                           "reused from the lead batch (warm repeats "
-                           "skip layout + upload)",
-    "mesh.plan_cache_miss": "mesh prep cache misses (layout + sharded "
-                            "upload rebuilt)",
     "mesh.rows": "rows aggregated through the mesh lane per query",
     "mesh.shards": "mesh devices participating in the collective merge",
     "hedge.fired": "hedged scan attempts launched at a next-ranked "
@@ -322,9 +281,8 @@ class QueryProfile:
 
     # ---------------------------------------------------------- rendering
     def snapshot(self) -> dict:
-        """Local stage map, bench wire shape: rounded `*_ms` floats
-        merged with integer counters, sorted by key (bench.py's
-        `stages_warm`/`stages_cold` fields)."""
+        """Local stage map: rounded `*_ms` floats merged with integer
+        counters, sorted by key."""
         with self._lock:
             out = {k: round(v, 2) for k, v in sorted(self.ms.items())}
             out.update(sorted(self.counts.items()))
@@ -397,20 +355,6 @@ class QueryProfile:
                         wall_ms, ivs, hi - wall_ms / 1e3, hi)
         if error is not None:
             self.error = error
-        pk = sys.modules.get("cnosdb_tpu.ops.pallas_kernels")
-        if pk is None and "cnosdb_tpu.ops.kernels" in sys.modules:
-            # the jax kernel stack is already resident (this query ran
-            # aggregates), so the pallas module itself is a cheap import
-            try:
-                from ..ops import pallas_kernels as pk
-            except Exception:  # telemetry stamp must never fail the query
-                pk = None
-        if pk is not None:
-            try:
-                self.device["pallas_enabled"] = pk.enabled()
-                self.device["pallas_disabled_reason"] = pk.disabled_reason()
-            except Exception:  # telemetry stamp must never fail the query
-                pass
         dd = sys.modules.get("cnosdb_tpu.ops.device_decode")
         if dd is not None:
             try:

@@ -4,9 +4,9 @@ gives them — 1000 hosts x 6 h of TSBS devops cpu (2.16 M rows, a 4 Mi row
 size class, 8192 segments, 4096-value pages).
 
 The chip's compiler is installed in the sandbox and refuses here what it
-would refuse on the chip: block shapes off the (8, 128) rule, 64-bit
-operands of a pallas_call, kernels past their fast-memory limit. Nothing
-runs, so nothing here says anything about results or times.
+would refuse on the chip: a program that does not fit the device's
+memory, an operand it cannot tile or partition. Nothing runs, so nothing
+here says anything about results or times.
 
 One file, and the topology is described inside a module-scoped fixture:
 only one process may load the TPU's library, the suite runs under several
@@ -21,7 +21,6 @@ from jax.sharding import SingleDeviceSharding
 
 from cnosdb_tpu.ops import device_decode as dd
 from cnosdb_tpu.ops import fused, kernels
-from cnosdb_tpu.ops import pallas_kernels as pk
 from cnosdb_tpu.sql.expr import BinOp, Column, Literal
 
 ROWS = 1 << 22           # pad_rows(2_160_000)
@@ -166,8 +165,12 @@ def test_delta_const_kernel(spec):
              spec((1024,), jnp.int64), length=PAGE_LEN)
 
 
-def test_gorilla_xla_kernel(spec):
-    _compile(dd._gorilla_xla_kernel, spec((1024, 8, PAGE_LEN), jnp.uint8))
+@pytest.mark.parametrize("pages,lane_len", [(8, dd._MIN_LANE),
+                                            (1024, PAGE_LEN), (8, 1 << 16)])
+def test_gorilla_xla_kernel(spec, pages, lane_len):
+    """The Gorilla lane's only scan, from the smallest bucket to a 2^16
+    one."""
+    _compile(dd._gorilla_xla_kernel, spec((pages, 8, lane_len), jnp.uint8))
 
 
 def test_bitpack_kernel(spec):
@@ -176,52 +179,6 @@ def test_bitpack_kernel(spec):
 
 def test_codes_kernel(spec):
     _compile(dd._codes_kernel, spec((1024, PAGE_LEN), jnp.uint16))
-
-
-# ------------------------------------------------------------ Pallas kernels
-@pytest.mark.parametrize("shape", [(dd._XOR_ROWS, 128), (1024, PAGE_LEN),
-                                   (dd._XOR_ROWS, dd._XOR_MAX_WIDTH)])
-def test_pallas_xor_scan(spec, shape):
-    """The Gorilla lane's scan kernel, from the smallest bucket to the
-    widest the lane routes to it."""
-    _compile(jax.jit(lambda x: dd._pallas_xor_scan(x, False)),
-             spec(shape, jnp.uint32))
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
-def test_pallas_segment_kernel_32bit(spec, dtype):
-    n = 1 << 16
-    _compile(pk._windowed_partials, spec((n // pk.R_TILE,), jnp.int32),
-             spec((n,), dtype), spec((n,), jnp.bool_), spec((n,), jnp.int32),
-             num_segments=4096, interpret=False)
-
-
-@pytest.mark.parametrize("dtype,words", [
-    (jnp.float64, "not contain X64 element types"),
-    (jnp.int64, "int64 not implemented")])
-def test_pallas_segment_kernel_64bit_is_refused(spec, dtype, words):
-    """What the chip's compiler says to the engine's own column types —
-    the reason decline_reason() keeps them off the kernel on a TPU."""
-    n = 1 << 16
-    with pytest.raises(Exception, match=words):
-        _compile(pk._windowed_partials, spec((n // pk.R_TILE,), jnp.int32),
-                 spec((n,), dtype), spec((n,), jnp.bool_),
-                 spec((n,), jnp.int32), num_segments=4096, interpret=False)
-
-
-@pytest.mark.parametrize("dtype", [np.float64, np.int64, np.uint64])
-def test_64bit_columns_are_routed_off_the_pallas_kernel_on_a_tpu(
-        monkeypatch, dtype):
-    """No Pallas kernel the compiler refuses is reachable on the chip: with
-    interpret mode off (a TPU), every 64-bit aggregation declines before
-    any launch; in interpret mode (a CPU backend) it does not."""
-    seg = np.zeros(512, dtype=np.int32)
-    wants = {"want_sum": True}
-    monkeypatch.setattr(pk, "interpret_mode", lambda: False)
-    assert "64-bit" in pk.decline_reason(dtype, wants, seg)
-    assert pk.decline_reason(np.float32, wants, seg) is None
-    monkeypatch.setattr(pk, "interpret_mode", lambda: True)
-    assert pk.decline_reason(dtype, wants, seg) is None
 
 
 # ------------------------------------------------- the mesh lane, four chips

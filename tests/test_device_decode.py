@@ -1,7 +1,7 @@
 """Device-decode plane (ops/device_decode + codecs.split_for_device):
 bit-identical parity against the host decoder across every codec, dtype
-and null pattern (interpret mode on CPU), reason accounting for rejected
-pages, and the end-to-end scan lane — engagements > 0 and batch
+and null pattern (the XLA kernels on the CPU backend), reason accounting
+for rejected pages, and the end-to-end scan lane — engagements > 0 and batch
 equivalence vs the legacy Python scan, the page group as the lane's unit
 of device work, and the decoded columns' staging through the
 EagerUploader."""
@@ -25,12 +25,12 @@ from cnosdb_tpu.storage.vnode import VnodeStorage
 # kernel parity: device lane output must be BIT-identical to codecs.decode
 # ---------------------------------------------------------------------------
 def _device_decode_block(block: bytes, vt: ValueType) -> np.ndarray:
-    """Round one encoded block through the device lane (interpret=True)
-    and return the decoded values, shaped like codecs.decode's output."""
+    """Round one encoded block through the device lane and return the
+    decoded values, shaped like codecs.decode's output."""
     plan, reason = codecs.split_for_device(block, vt)
     assert plan is not None, f"split rejected: {reason}"
     n = plan["n"]
-    lane = device_decode.DeviceDecodeLane(interpret=True)
+    lane = device_decode.DeviceDecodeLane()
     if vt in (ValueType.STRING, ValueType.GEOMETRY):
         got = {}
 
@@ -135,22 +135,6 @@ def test_dict_string_parity(rng, n):
     np.testing.assert_array_equal(dev, np.asarray(host, dtype=object))
 
 
-def test_pallas_gorilla_path_parity(rng, monkeypatch):
-    """CNOSDB_TPU_PALLAS=1 routes the gorilla XOR scan through the
-    Pallas kernel (interpret on CPU) — still bit-identical, and it books
-    a pallas engagement."""
-    from cnosdb_tpu.ops import pallas_kernels
-
-    monkeypatch.setenv("CNOSDB_TPU_PALLAS", "1")
-    vals = rng.normal(0.0, 100.0, 777)
-    block = codecs.encode(vals, ValueType.FLOAT, Encoding.GORILLA)
-    host = codecs.decode(block, ValueType.FLOAT)
-    before = pallas_kernels.engagements()
-    _assert_bit_identical(_device_decode_block(block, ValueType.FLOAT),
-                          host)
-    assert pallas_kernels.engagements() > before
-
-
 # ---------------------------------------------------------------------------
 # rejection accounting: split_for_device + the lane's outcome counters
 # ---------------------------------------------------------------------------
@@ -174,7 +158,7 @@ def test_split_rejects_with_reasons(rng):
 
 def test_declined_pages_book_host_outcomes():
     before = device_decode.outcomes_snapshot().get(("host", "encoding"), 0)
-    lane = device_decode.DeviceDecodeLane(interpret=True)
+    lane = device_decode.DeviceDecodeLane()
     assert not lane.accepts(int(ValueType.INTEGER), int(Encoding.QUANTILE))
     lane.declined("encoding", 3)
     snap = device_decode.outcomes_snapshot()
@@ -256,8 +240,7 @@ def _assert_batches_equal(a, b):
 
 def _device_scan(v, **kw):
     got = scan_vnode(v, "m",
-                     decode_hook=lambda: device_decode.DeviceDecodeLane(
-                         interpret=True), **kw)
+                     decode_hook=device_decode.DeviceDecodeLane, **kw)
     os.environ["CNOSDB_NO_NATIVE_SCAN"] = "1"
     try:
         want = scan_vnode(v, "m", **kw)
@@ -383,7 +366,7 @@ def _profiled_device_scan(v):
     lanes = []
 
     def hook():
-        lanes.append(device_decode.DeviceDecodeLane(interpret=True))
+        lanes.append(device_decode.DeviceDecodeLane())
         return lanes[-1]
 
     prof = stages.QueryProfile()

@@ -179,7 +179,7 @@ def test_gorilla_pages_decline_the_device_decode_lane(rounding_device):
 
     before = device_decode.outcomes_snapshot().get(
         ("host", "f64_inexact_on_device"), 0)
-    lane = device_decode.DeviceDecodeLane(interpret=True)
+    lane = device_decode.DeviceDecodeLane()
     assert not lane.accepts(int(ValueType.FLOAT), int(Encoding.GORILLA))
     assert lane.accepts(int(ValueType.INTEGER), int(Encoding.DELTA))
     assert device_decode.outcomes_snapshot()[

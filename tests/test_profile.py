@@ -187,11 +187,11 @@ def test_explain_analyze_renders_stage_and_device_rows(db):
         assert name in stages.STAGE_CATALOG \
             or name.startswith(stages.DYNAMIC_STAGE_PREFIXES)
         assert value >= 0
-    assert "device pallas_enabled=" in text
+    assert "device platform=" in text
 
 
 def test_explain_analyze_reconciles_with_scoped_profile(db):
-    """The rendered breakdown and the ambient (bench-style) profile must
+    """The rendered breakdown and the ambient (caller-installed) profile must
     agree: the inner profile folds into the outer, so per-stage sums
     reconcile within 10%."""
     _seed(db)
@@ -219,7 +219,7 @@ def test_profile_sealed_by_executor_and_ring_recorded(db):
     assert prof.qid is not None
     assert prof.wall_ms is not None and prof.wall_ms > 0
     assert prof.sql == "SELECT count(*) FROM m"
-    assert "pallas_enabled" in prof.device
+    assert "platform" in prof.device
     d = stages.PROFILES.get(prof.qid)
     assert d is not None and d["wall_ms"] == prof.wall_ms
 
@@ -336,7 +336,7 @@ def test_http_profile_header_and_debug_profile(http):
     assert status == 200
     full = json.loads(body)
     assert full["qid"] == qid and full["counts"]["group_count"] == 4
-    assert "pallas_enabled" in full["device"]
+    assert "platform" in full["device"]
     status, body, _ = http.request("GET", "/debug/profile")
     recents = json.loads(body)
     assert any(d["qid"] == qid for d in recents)

@@ -17,6 +17,8 @@ from cnosdb_tpu.sql.expr import BinOp, Column, Literal
 from cnosdb_tpu.utils import stages
 
 ALL_FOUR = dict(want_count=True, want_sum=True, want_min=True, want_max=True)
+# aggregate_column_host hands segment_aggregate every flag
+HOST_WANTS = dict(ALL_FOUR, want_first=False, want_last=False)
 
 
 def _series_major(rng, n_series, rows_per, n_buckets, n_groups, n_pad, dtype,
@@ -280,7 +282,7 @@ def test_host_wrapper_takes_the_callers_bound(rng):
     bound comes or the bound leaves too few rows a run."""
     vals, valid, seg = _series_major(rng, 8, 5000, 8, 8, 40000, np.int64)
     rank = np.zeros(40000, np.int32)
-    wants = dict(ALL_FOUR, want_first=False, want_last=False)
+    wants = HOST_WANTS
     ref = kernels.numpy_segment_partials(vals, valid, seg, rank, 64, wants)
     for max_runs, booked in ((8 * 8, "segment_runs.engaged"),
                              (None, None), (20000, None),
@@ -294,6 +296,104 @@ def test_host_wrapper_takes_the_callers_bound(rng):
         assert runs == ({booked: 1} if booked else {}), (max_runs, runs)
         for k in ref:
             assert np.array_equal(got[k], ref[k]), (max_runs, k)
+
+
+# ------------------------------------------- the host wrapper's edge inputs
+I64 = np.iinfo(np.int64)
+
+
+def _basic(rng):
+    # a FLOAT column: only its count goes by runs
+    vals, valid, seg = _series_major(rng, 6, 7000, 24, 6, 42000, np.float64)
+    return vals, valid, seg, 6 * 24, HOST_WANTS
+
+
+def _nulls_and_empty_segments(rng):
+    vals, valid, seg = _series_major(rng, 4, 9000, 100, 4, 36000, np.int64,
+                                     null_share=0.4)
+    valid &= (seg % 7) != 3          # whole segments null; 100 ids unused
+    return vals, valid, seg, 500, HOST_WANTS
+
+
+def _all_rows_invalid(rng):
+    n = 40000
+    return (np.ones(n, np.int64), np.zeros(n, bool), np.zeros(n, np.int32),
+            8, HOST_WANTS)
+
+
+def _integer_extrema(rng):
+    _, valid, seg = _series_major(rng, 3, 12000, 16, 3, 36000, np.int64,
+                                  null_share=0.2)
+    vals = rng.choice(np.array([I64.min, I64.max, -1, 0, 1], np.int64), 36000)
+    return vals, valid, seg, 64, HOST_WANTS
+
+
+def _run_crossing_a_row_block(rng):
+    # two series meet off any power of two; the second is ONE run of
+    # segment 0 that crosses row 2^15 and ends where the zero-padded tail
+    # (segment 0 too) begins
+    a = (16 + (np.arange(20000) * 16) // 20000).astype(np.int32)
+    seg = np.concatenate([a, np.zeros(20000, np.int32)])
+    vals = rng.integers(-2**62, 2**62, 40000, dtype=np.int64)
+    return vals, rng.random(40000) > 0.1, seg, 32, HOST_WANTS
+
+
+def _wants_subsetting(rng):
+    vals, valid, seg = _series_major(rng, 2, 20000, 8, 2, 40000, np.int64)
+    return vals, valid, seg, 16, dict(HOST_WANTS, want_sum=False, want_min=False)
+
+
+def _first_last(rng):
+    vals, valid, seg = _series_major(rng, 5, 8000, 6, 5, 40000, np.int64)
+    return vals, valid, seg, 30, dict(HOST_WANTS, want_sum=False, want_min=False,
+                                      want_max=False, want_first=True,
+                                      want_last=True)
+
+
+HOST_CASES = {f.__name__[1:]: f for f in (
+    _basic, _nulls_and_empty_segments, _all_rows_invalid, _integer_extrema,
+    _run_crossing_a_row_block, _wants_subsetting, _first_last)}
+
+
+@pytest.mark.parametrize("path", ["row_scatter", "by_runs"])
+@pytest.mark.parametrize("case", list(HOST_CASES))
+def test_host_wrapper_matches_the_numpy_oracle(rng, case, path):
+    """kernels.aggregate_column_host — pad, launch, pull, slice — against
+    the numpy twin on the inputs a scan can hand it, with no bound (one
+    scatter update a row) and with the rows' true run count as the bound
+    (every case is long enough to engage the run path)."""
+    vals, valid, seg, num_segments, wants = HOST_CASES[case](rng)
+    n = len(vals)
+    rank = rng.permutation(n).astype(np.int32)
+    runs = int(np.count_nonzero(seg[1:] != seg[:-1])) + 1
+    assert kernels.pad_rows(n) >= kernels.RUN_PATH_MIN_ROWS
+    ref = kernels.numpy_segment_partials(vals, valid, seg, rank,
+                                         num_segments, wants)
+    prof = stages.QueryProfile()
+    with stages.profile_scope(prof):
+        got = kernels.aggregate_column_host(
+            vals, valid, seg, rank, num_segments, wants,
+            max_runs=runs if path == "by_runs" else None)
+    booked = {k: v for k, v in prof.counts.items()
+              if k.startswith("segment_runs")}
+    assert booked == ({"segment_runs.engaged": 1} if path == "by_runs"
+                      else {})
+    assert sorted(got) == sorted(ref)
+    for k in ref:
+        assert got[k].shape == (num_segments,), k
+        if k == "sum" and vals.dtype.kind == "f":
+            np.testing.assert_allclose(got[k], ref[k], rtol=1e-12)
+        else:
+            assert got[k].dtype == ref[k].dtype, k
+            assert np.array_equal(got[k], ref[k]), k
+    if case in ("nulls_and_empty_segments", "all_rows_invalid"):
+        # the convention callers mask by: an empty segment counts 0, sums
+        # 0 and holds the type's extrema
+        empty = got["count"] == 0
+        assert empty.any()
+        assert (got["sum"][empty] == 0).all()
+        assert (got["min"][empty] == I64.max).all()
+        assert (got["max"][empty] == I64.min).all()
 
 
 # ------------------------------------------------------- the mesh lane's body

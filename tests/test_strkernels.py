@@ -276,8 +276,7 @@ def test_ngram_scan_never_drops_matching_pages(tmp_path, rng):
                 b = scan_vnode(
                     v, "m",
                     page_constraints=_page_constraints(flt, ["s"]),
-                    decode_hook=lambda: device_decode.DeviceDecodeLane(
-                        interpret=True))
+                    decode_hook=device_decode.DeviceDecodeLane)
         finally:
             del os.environ["CNOSDB_NGRAM_SKIP"]
         return b, prof.snapshot().get("ngram_pages_skipped", 0)

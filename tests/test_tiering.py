@@ -146,7 +146,7 @@ def test_boundary_respects_file_age(tmp_engine_dir, store_dir):
 # ----------------------------------------------------- near-data pruning
 def _device_hook():
     from cnosdb_tpu.ops import device_decode
-    return lambda: device_decode.DeviceDecodeLane(interpret=True)
+    return lambda: device_decode.DeviceDecodeLane()
 
 
 def test_constraint_prune_downloads_nothing(tmp_engine_dir, store_dir):
