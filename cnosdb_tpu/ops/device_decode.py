@@ -30,13 +30,25 @@ integer/bitwise: XOR scans, two's-complement cumsum and bitcasts have no
 rounding.
 
 Gating: CNOSDB_DEVICE_DECODE=1 forces the lane on (the XLA kernels on a
-CPU backend included — how tests engage it), =0 off, auto enables it only
-when the scan device is a real TPU. The scan layer
+CPU backend included — how tests engage it), =0 off, auto hands scans a
+lane only when the scan device is a real TPU. The scan layer
 (storage/scan) receives a DeviceDecodeLane via `decode_hook` so storage
 itself stays jax-free; every page the lane examines but does not decode
 books a (lane, reason) outcome — surfaced as
 cnosdb_device_decode_total{lane,reason} and required by the
 device-decode-accounting lint rule.
+
+The route of a page (PR 32): a page is decoded where its values land.
+Every consumer of a scan reads the scan's HOST arrays, and the pipe down
+from the chip runs at 0.29 GB/s, so a forced lane (=1) and a lane handed
+to scan_vnode directly are device-first as before, but the lane the
+coordinator's hook builds in auto mode is `behind_native()`: a numeric or
+time page the native decoder can take (native/pagedec.cpp: a local
+reader, a type and encoding it knows, the column's own type) goes to it
+and books {lane="host", reason="native_first"}. In auto mode the device
+kernels see what that decoder cannot take: cold readers' pages,
+encodings outside its set, and STRING / GEOMETRY pages (it has no
+dictionary lane).
 """
 from __future__ import annotations
 
@@ -62,18 +74,29 @@ _WIDTH_DTYPE = {1: np.uint8, 2: np.uint16, 4: np.uint32, 8: np.uint64}
 
 
 def enabled() -> bool:
-    """Should scans route decodes through this plane?
+    """Should scans be handed a lane of this plane?
     CNOSDB_DEVICE_DECODE=1 forces on (XLA on CPU backends — the test
     mode), =0 off; default: only on a real TPU."""
     return disabled_reason() is None
 
 
+def forced() -> bool:
+    """CNOSDB_DEVICE_DECODE=1: the lane is device-first, as it is when
+    handed to scan_vnode directly; in auto mode it stands behind the
+    native decoder (DeviceDecodeLane.behind_native)."""
+    return _mode() in ("1", "on", "true")
+
+
+def _mode() -> str:
+    return os.environ.get("CNOSDB_DEVICE_DECODE", "auto").lower()
+
+
 def disabled_reason() -> str | None:
     """None when the lane is usable, else WHY not — stamped into query
     profiles as device_decode_disabled_reason."""
-    mode = os.environ.get("CNOSDB_DEVICE_DECODE", "auto").lower()
-    if mode in ("1", "on", "true"):
+    if forced():
         return None
+    mode = _mode()
     if mode in ("0", "off", "false"):
         return f"disabled by env CNOSDB_DEVICE_DECODE={mode}"
     from .placement import scan_device
@@ -219,8 +242,20 @@ class DeviceDecodeLane:
                                  int(Encoding.NULL)},
     }
 
+    # True: the scan sends every page its native decoder can take there
+    # (booked host / native_first) and this lane sees the rest
+    native_first = False
+
     def __init__(self):
         self._jobs: list[_Job] = []
+
+    @classmethod
+    def behind_native(cls) -> "DeviceDecodeLane":
+        """The lane of auto mode: decoded values land in host arrays, so
+        the native decoder goes first (module docstring)."""
+        lane = cls()
+        lane.native_first = True
+        return lane
 
     def accepts(self, value_type: int, encoding: int) -> bool:
         """Cheap pre-check: does (value_type, encoding) have a device

@@ -1137,14 +1137,20 @@ class Coordinator:
 
     def _decode_hook(self):
         """Device-decode lane factory for the scan pipeline: a fresh
-        DeviceDecodeLane per scan when the plane is enabled (real TPU, or
-        forced via CNOSDB_DEVICE_DECODE=1), else None — scans then use
-        the native/Python host lanes exactly as before."""
+        DeviceDecodeLane per scan when the plane is enabled, else None —
+        scans then use the native/Python host lanes alone. Forced
+        (CNOSDB_DEVICE_DECODE=1) the lane is device-first; in auto mode
+        on a real TPU it stands behind the native decoder: a scan's
+        values land in host arrays, so every page that decoder can take
+        is decoded there (booked host / native_first, one booking a
+        page as ever) and the device sees only the rest."""
         from ..ops import device_decode
 
         if self._scan_platform() is None or not device_decode.enabled():
             return None
-        return device_decode.DeviceDecodeLane
+        if device_decode.forced():
+            return device_decode.DeviceDecodeLane
+        return device_decode.DeviceDecodeLane.behind_native
 
     def _scan_remote(self, split: PlacedSplit, field_names,
                      fingerprint: str | None = None) -> ScanBatch | None:

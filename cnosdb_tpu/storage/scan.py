@@ -461,7 +461,10 @@ def scan_vnode(vnode: VnodeStorage, table: str,
     (ops/device_decode): pages whose codec has a device kernel stop host
     work at the byte container and decode as batched kernels on the
     accelerator — the third lane beside native pagedec and per-page
-    Python.
+    Python. The lane says which goes first (`native_first`): the
+    coordinator's auto-mode lane leaves the native decoder every page it
+    can take, because the values land in this scan's host arrays; a
+    forced lane, or the class handed in directly, is asked first.
     `compressed_spec` (storage/compressed_domain.CompressedSpec), when
     given, engages the compressed-domain lane AHEAD of the decode lanes:
     merge-free pages provably skippable/answerable from their encoded
@@ -604,6 +607,34 @@ def _count_fallback(reason: str, n: int = 1) -> None:
 def decode_fallback_snapshot() -> dict[str, int]:
     with _FALLBACK_LOCK:
         return dict(sorted(_FALLBACK.items()))
+
+
+def _native_miss(native_ok: bool, cold: bool, pm, vt) -> str | None:
+    """Why the native decoder cannot take field page `pm` of a column
+    typed `vt` (`cold`: the page's reader is) — the page's
+    cnosdb_decode_fallback_total reason should it end on the Python
+    lane — or None when it can."""
+    if vt in (ValueType.STRING, ValueType.GEOMETRY):
+        return "string"
+    if not native_ok:
+        return "native_unavailable"
+    if cold:
+        # the native writer reads pages out of a local mmap
+        # (buffer_array) — cold pages have no local bytes, so they decode
+        # via the Python lane over the block cache
+        return "cold_tier"
+    kind = _NATIVE_NUMERIC.get(pm.value_type)
+    if kind is None:
+        return "value_type"
+    if pm.encoding not in _NATIVE_ENC[kind]:
+        return "encoding"
+    if pm.value_type != int(vt):
+        # schema evolution changed the column's type between chunks — the
+        # output array is typed by ftypes, so a differently-typed page
+        # must go through the casting Python path, never the width-blind
+        # native writer
+        return "schema_change"
+    return None
 
 
 def _count_cold_pruned(n: int) -> None:
@@ -966,6 +997,12 @@ def _scan_vnode_native(vnode: VnodeStorage, table: str,
                        pm.n_values))
         lst[1].append((pm, out_off))
 
+    # the route of a page: a lane that stands behind the native decoder
+    # (auto mode — decoded values land in the host arrays allocated
+    # above) leaves it every page it can take; a device-first lane
+    # (forced, or handed in directly) is asked first, as before
+    native_first = dev_lane is not None and dev_lane.native_first
+    n_native_first = 0
     kept_sids: list[int] = []
     keys = []
     counts: list[int] = []
@@ -987,16 +1024,23 @@ def _scan_vnode_native(vnode: VnodeStorage, table: str,
         keys.append(vnode.index.get_series_key(sid))
         counts.append(n_rows)
         for r, cm, cols, idx in chunks:
+            cold = getattr(r, "is_cold", False)
+            time_native = native_ok and not cold
             for i in idx:
                 tp = cm.time_pages[i]
-                if not (dev_lane is not None
+                if native_first and time_native:
+                    n_native_first += 1
+                    queued = False
+                else:
+                    queued = dev_lane is not None \
                         and dev_lane.accepts(int(ValueType.INTEGER),
-                                             tp.encoding)
+                                             tp.encoding) \
                         and _submit_device_page(
                             dev_lane, r, tp, None, off, ValueType.INTEGER,
                             numeric_cols, string_parts, string_valid,
-                            ts_all)):
-                    if native_ok and not getattr(r, "is_cold", False):
+                            ts_all)
+                if not queued:
+                    if time_native:
                         _add_page(r, tp, None, off, 0)
                     else:
                         py_jobs.append((r, tp, None, off, None))
@@ -1006,7 +1050,10 @@ def _scan_vnode_native(vnode: VnodeStorage, table: str,
                         continue   # absent column: stays zero/invalid
                     pm = col.pages[i]
                     vt = ftypes.get(name)
-                    if dev_lane is not None and pm.value_type == int(vt) \
+                    miss = _native_miss(native_ok, cold, pm, vt)
+                    if native_first and miss is None:
+                        n_native_first += 1
+                    elif dev_lane is not None and pm.value_type == int(vt) \
                             and (vt in (ValueType.STRING,
                                         ValueType.GEOMETRY)
                                  or dev_lane.accepts(pm.value_type,
@@ -1016,45 +1063,23 @@ def _scan_vnode_native(vnode: VnodeStorage, table: str,
                                 numeric_cols, string_parts, string_valid,
                                 ts_all):
                         continue
-                    if vt in (ValueType.STRING, ValueType.GEOMETRY):
-                        _count_fallback("string")
+                    if miss is None:
+                        _add_page(r, pm, name, off,
+                                  _NATIVE_NUMERIC[pm.value_type])
+                    else:
+                        # neither the device lane nor the native decoder
+                        # takes it: per-page Python path
+                        _count_fallback(miss)
                         py_jobs.append((r, pm, name, off, vt))
-                        continue
-                    if not native_ok:
-                        # device lane declined and there is no native
-                        # decoder in this build: per-page Python path
-                        _count_fallback("native_unavailable")
-                        py_jobs.append((r, pm, name, off, vt))
-                        continue
-                    if getattr(r, "is_cold", False):
-                        # the native writer reads pages out of a local
-                        # mmap (buffer_array) — cold pages have no local
-                        # bytes, so they decode via the Python lane over
-                        # the block cache
-                        _count_fallback("cold_tier")
-                        py_jobs.append((r, pm, name, off, vt))
-                        continue
-                    kind = _NATIVE_NUMERIC.get(pm.value_type)
-                    if kind is None or pm.encoding not in _NATIVE_ENC[kind] \
-                            or pm.value_type != int(vt):
-                        # the last case: schema evolution changed the
-                        # column's type between chunks — the output array
-                        # is typed by ftypes, so a differently-typed page
-                        # must go through the casting Python path, never
-                        # the width-blind native writer
-                        _count_fallback(
-                            "value_type" if kind is None else
-                            "encoding" if pm.encoding not in _NATIVE_ENC[kind]
-                            else "schema_change")
-                        py_jobs.append((r, pm, name, off, vt))
-                        continue
-                    _add_page(r, pm, name, off, kind)
                 bytes_materialized += tp.size + sum(
                     cols[name].pages[i].size for name in field_names
                     if name in cols)
                 if lane is not None:
                     lane.apply_page_masks(cm, i, off, total)
                 off += tp.n_rows
+
+    if n_native_first:
+        dev_lane.declined("native_first", n_native_first)
 
     # ------------------------------------------------------ device decode
     # the third lane runs BEFORE the native tasks: device writebacks land

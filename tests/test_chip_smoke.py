@@ -1,6 +1,7 @@
 """chip_smoke.py rehearsed off the chip: the same phases at toy size on the
 CPU backend must pass, and the script must still refuse to call that a
 chip run."""
+import importlib.util
 import json
 import os
 import subprocess
@@ -9,6 +10,17 @@ import sys
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def smoke():
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(ROOT, "chip_smoke.py"))
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod   # dataclasses resolves types through it
+    spec.loader.exec_module(mod)
+    yield mod
+    del sys.modules[spec.name]
 
 
 @pytest.fixture(scope="module")
@@ -40,3 +52,39 @@ def test_parent_never_imports_jax(rehearsal):
     _p, lines = rehearsal
     seen = [ln["jax_in_parent"] for ln in lines if "jax_in_parent" in ln]
     assert seen and not any(seen)
+
+
+_TPU = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+_CPU = {"platform": "cpu", "kind": "cpu", "count": 1}
+
+
+def _decode_table(**booked):
+    return {("cnosdb_device_decode_total",
+             (("lane", key.partition("_")[0]),
+              ("reason", key.partition("_")[2]))): float(n)
+            for key, n in booked.items()}
+
+
+@pytest.mark.parametrize("device, force_dec, booked, fault", [
+    # auto mode on a TPU: the lane stands behind the native decoder, so no
+    # page need be decoded on the device, but every page is booked
+    (_TPU, False, {"host_native_first": 44}, None),
+    (_TPU, False, {"host_native_first": 40, "device_ok": 4}, None),
+    (_TPU, False, {"host_native_first": 43}, "books fewer"),
+    (_TPU, False, {}, "books fewer"),
+    # forced: device-first, on any backend
+    (_TPU, True, {"device_ok": 44}, None),
+    (_CPU, True, {"device_ok": 44}, None),
+    (_TPU, True, {"host_native_first": 44}, "no page was decoded"),
+    (_CPU, True, {}, "no page was decoded"),
+    # a CPU in auto mode has no lane and books nothing
+    (_CPU, False, {}, None),
+])
+def test_decode_lane_check_follows_the_mode(smoke, device, force_dec,
+                                            booked, fault):
+    m = _decode_table(**booked)
+    if fault is None:
+        smoke.check_decode_lanes(m, device, force_dec, pages_floor=44)
+    else:
+        with pytest.raises(smoke.Fail, match=fault):
+            smoke.check_decode_lanes(m, device, force_dec, pages_floor=44)

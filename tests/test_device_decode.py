@@ -343,10 +343,12 @@ def _mixed_schema():
                 ("n", ValueType.INTEGER)])}
 
 
-def _write_mixed(v, n_series, rng):
+def _write_mixed(v, n_series, rng, flush_each=False):
     """n_series series of 6 pages each (time, i, u, b, s, n), rows
     alternating 90 / 300 so every column lies in two length buckets."""
     for k in range(n_series):
+        if flush_each and k:
+            v.flush()
         rows = 300 if k % 2 else 90
         _write(v, f"h{k:03d}", range(rows),
                i=rng.integers(-10**6, 10**6, rows),
@@ -469,3 +471,112 @@ def test_failure_at_the_pull_routes_the_group_to_the_python_lane(
     # a column with a page still to decode is not staged yet
     assert set(got._preuploaded[1]) == {"b"}
     v.close()
+
+
+# ---------------------------------------------------------------------------
+# the route of a page: a page is decoded where its values land
+# ---------------------------------------------------------------------------
+def _coordinator_hook_on_a_tpu(monkeypatch):
+    """The coordinator's decode hook as it decides where the scan device
+    is a TPU; the lane's kernels then run on the CPU backend."""
+    import types
+
+    from cnosdb_tpu.ops import placement
+    from cnosdb_tpu.parallel.coordinator import Coordinator
+
+    with monkeypatch.context() as m:
+        m.setattr(placement, "scan_device",
+                  lambda: types.SimpleNamespace(platform="tpu"))
+        return Coordinator.__new__(Coordinator)._decode_hook()
+
+
+# id → (fields scanned, how the scan gets its lane, cold reader?, the
+# columns whose pages still reach the device lane; None = the time column)
+_EVERY = ("i", "u", "b", "n", "s")
+_ROUTES = {
+    "integer": (("i",), "auto", False, ()),
+    "unsigned_above_2_63": (("u",), "auto", False, ()),
+    "boolean": (("b",), "auto", False, ()),
+    "null_masked": (("n",), "auto", False, ()),
+    "const_stride_time": ((), "auto", False, ()),
+    "string_has_no_native_lane": (("i", "s"), "auto", False, ("s",)),
+    "cold_reader": (("i", "n"), "auto", True, (None, "i", "n")),
+    "forced": (_EVERY, "forced", False, (None,) + _EVERY),
+    "lane_handed_in": (_EVERY, "direct", False, (None,) + _EVERY),
+}
+
+
+@pytest.mark.parametrize("case", list(_ROUTES))
+def test_route_of_a_page(tmp_engine_dir, tmp_path, rng, monkeypatch, case):
+    """Through the coordinator's hook in auto mode on a TPU, a numeric or
+    time page the native decoder can take is decoded by it — booked
+    host / native_first, no device call — and what it cannot take (a cold
+    reader's pages, strings) still reaches the device lane; forced, or
+    handed to the scan directly, the lane is device-first as ever. Every
+    page is booked exactly once, and the batch is bit for bit the forced
+    device lane's and the Python lane's."""
+    from cnosdb_tpu.storage import tiering
+    from cnosdb_tpu.utils import stages
+
+    fields, mode, cold, on_device = _ROUTES[case]
+    n_series = 5
+    v = VnodeStorage(1, tmp_engine_dir, schemas=_mixed_schema())
+    _write_mixed(v, n_series, rng, flush_each=cold)
+    if cold:
+        # five flushes compact into the one sealed file that may tier
+        tiering.configure(str(tmp_path / "bucket"))
+        v.compact_full()
+        assert tiering.tier_vnode(v, boundary_ns=10 ** 18) == 1
+    if mode == "forced":
+        monkeypatch.setenv("CNOSDB_DEVICE_DECODE", "1")
+    else:
+        monkeypatch.delenv("CNOSDB_DEVICE_DECODE", raising=False)
+    hook = device_decode.DeviceDecodeLane if mode == "direct" \
+        else _coordinator_hook_on_a_tpu(monkeypatch)
+    assert (hook == device_decode.DeviceDecodeLane) == (mode != "auto")
+
+    try:
+        before = device_decode.outcomes_snapshot()
+        prof = stages.QueryProfile()
+        with stages.profile_scope(prof):
+            got = scan_vnode(v, "m", field_names=list(fields),
+                             decode_hook=hook)
+        after = device_decode.outcomes_snapshot()
+        rise = {k: after[k] - before.get(k, 0) for k in after
+                if after[k] != before.get(k, 0)}
+        pages = n_series * (1 + len(fields))
+        device_pages = n_series * len(on_device)
+        want = {("host", "native_first"): pages - device_pages,
+                ("device", "ok"): device_pages}
+        assert rise == {k: n for k, n in want.items() if n}
+        assert sum(rise.values()) == pages
+        assert prof.counts.get("device_decode_engagements", 0) \
+            == device_pages
+        assert (prof.counts.get("device_decode.device_calls", 0) > 0) \
+            == bool(on_device)
+        if not on_device:
+            assert not [k for k in list(prof.ms) + list(prof.counts)
+                        if k.startswith("device_decode")]
+
+        forced = scan_vnode(v, "m", field_names=list(fields),
+                            decode_hook=device_decode.DeviceDecodeLane)
+        os.environ["CNOSDB_NO_NATIVE_SCAN"] = "1"
+        try:
+            python = scan_vnode(v, "m", field_names=list(fields))
+        finally:
+            del os.environ["CNOSDB_NO_NATIVE_SCAN"]
+        for other in (forced, python):
+            _assert_batches_equal(got, other)
+            for name in fields:
+                assert got.fields[name][1].dtype \
+                    == other.fields[name][1].dtype or name == "s"
+        if "u" in fields:
+            assert got.fields["u"][1].min() >= 2**63
+        if "n" in fields:
+            assert not got.fields["n"][2].all()
+    finally:
+        v.close()
+        if cold:
+            tiering.configure(None)
+            tiering.counters_reset()
+            tiering.block_cache_clear()

@@ -553,6 +553,34 @@ def test_decode_stage_terms_sum_to_decode_ms(http, monkeypatch):
         < st["device_decode_engagements"]
 
 
+def test_auto_mode_profile_carries_no_device_decode_stage(http, monkeypatch):
+    """Auto mode with the lane on, as on a TPU: the scan's values land in
+    host arrays, so the native decoder takes every page — booked
+    host / native_first — and the profile shows decode_ms alone, no
+    device_decode.* stage, span or count."""
+    from cnosdb_tpu.ops import device_decode
+
+    monkeypatch.delenv("CNOSDB_DEVICE_DECODE", raising=False)
+    monkeypatch.setattr(device_decode, "disabled_reason", lambda: None)
+    _seed_flushed_ints(http, hosts=12)
+    key = ("host", "native_first")
+    before = device_decode.outcomes_snapshot()
+    tid = "feedc0de0032"
+    status, _body, hdrs = http.request(
+        "POST", "/api/v1/sql?db=public", _BUCKETED,
+        headers={"X-CnosDB-Profile": "1", "cnos-trace-id": tid})
+    assert status == 200
+    after = device_decode.outcomes_snapshot()
+    assert after[key] - before.get(key, 0) == 24      # 12 time, 12 usage
+    assert {k: n for k, n in after.items() if k != key} \
+        == {k: n for k, n in before.items() if k != key}
+    st = json.loads(hdrs["X-CnosDB-Profile-Summary"])["stages"]
+    assert st["decode_ms"] > 0
+    assert not [k for k in st if k.startswith("device_decode")], st
+    assert not [s["name"] for s in _trace_spans(http, tid)
+                if s["name"].startswith("device_decode")]
+
+
 def _seed_sharded_ints(h, monkeypatch, hosts=16, steps=200):
     """A `WITH SHARD 4` database of two INTEGER fields, flushed, and the
     mesh lane opened to a table this small on four of the virtual devices."""
