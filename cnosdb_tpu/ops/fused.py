@@ -19,7 +19,10 @@ epoch E and query origin O:
     bucket = qA + floor((sec + rA_s + carry) / I_s)            (all i32)
 
 The final index subtracts bmin host-side (folded into `offset`), so no
-per-query recompilation: I_s, rA_s, rA_ns, offset are traced scalars.
+per-query recompilation: rA_s, rA_ns, offset and the bucket count are
+traced scalars (a window that happens to start on a bucket boundary has
+one bucket fewer and must not be a new program: eight vnodes compiling
+one each inside a request is seconds).
 
 Segment reductions are kernels.local_segment_partials: the seg ids are
 derived ON DEVICE (group_of_series[sid] × n_buckets + bucket), and a batch
@@ -37,7 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from ..sql.expr import Expr
-from ..utils import stages
+from ..utils import lockwatch, stages
 from . import program
 from .device_cache import DeviceBatch
 from .kernels import (local_segment_partials, note_run_path, pad_segments,
@@ -45,11 +48,24 @@ from .kernels import (local_segment_partials, note_run_path, pad_segments,
 
 _kernel_cache: dict = {}
 
+# One thread at a time calls a fused program. The call dispatches and
+# returns (a few hundred µs) unless the argument shapes are new, and then
+# it compiles: the per-vnode fan-out (sql/executor.py) reaches here from
+# eight pool threads at once, each vnode with its own series count and
+# so its own shapes, and eight of these compiles side by side brought the
+# TPU compiler down with the server (SIGSEGV under
+# xla::jellyfish::TpuBroadcastRewriter, a v5e, PR 33: three of five
+# first requests of a `WITH SHARD 8` database on one chip).
+_DISPATCH_LOCK = lockwatch.Lock("fused.dispatch")
+
 # observability: how many fused device programs launched this process
 # (tests assert the device path actually engaged)
 launch_count = 0
 
 NS_PER_SEC = 1_000_000_000
+# the head of a launch's packed i32 params: ra_s, ra_ns, offset, n_rows,
+# n_buckets; the group vector and the regular mode's run params follow
+_SCALARS = 5
 
 
 def bucket_arith_params(epoch_ns: int, origin: int, interval: int,
@@ -155,16 +171,17 @@ def launch_fused(dbatch: DeviceBatch, filter_expr: Expr | None,
     # constant divisors to multiplies. Intervals are few (1m/5m/1h/...), so
     # keying the kernel cache on i_s costs a handful of compiles. The
     # add/compare params (ra_s/ra_ns/offset) stay traced — they change per
-    # batch/origin without recompilation. Optional inputs (ts_ns, rank,
-    # per-column validity) are kernel variants: an absent buffer is one
-    # never uploaded.
-    key = (filter_key, cols_key, dtypes_key, ns_pad, n_buckets,
+    # batch/origin without recompilation, and so does n_buckets (the
+    # size classes ns_pad and run_pad carry what shape it needs).
+    # Optional inputs (ts_ns, rank, per-column validity) are kernel
+    # variants: an absent buffer is one never uploaded.
+    key = (filter_key, cols_key, dtypes_key, ns_pad,
            use_bucket, i_s, dbatch.n_pad, need_rank, valid_flags, has_ts_ns,
            regular, run_pad)
     entry = _kernel_cache.get(key)
     if entry is None:
         entry = _build_kernel(filter_expr, col_wants, tuple(present), ns_pad,
-                              n_buckets, use_bucket, i_s, need_rank,
+                              use_bucket, i_s, need_rank,
                               valid_flags, has_ts_ns, regular, dbatch.n_pad,
                               run_pad)
         _kernel_cache[key] = entry
@@ -188,14 +205,11 @@ def launch_fused(dbatch: DeviceBatch, filter_expr: Expr | None,
     # i32 buffer
     sp = dbatch.series_params if regular else None
     sp_len = sp.size if sp is not None else 0
-    params = np.empty(4 + ns + sp_len, dtype=np.int32)
-    params[0] = ra_s
-    params[1] = ra_ns
-    params[2] = offset
-    params[3] = dbatch.n_rows
-    params[4:4 + ns] = gos
+    params = np.empty(_SCALARS + ns + sp_len, dtype=np.int32)
+    params[:_SCALARS] = ra_s, ra_ns, offset, dbatch.n_rows, n_buckets
+    params[_SCALARS:_SCALARS + ns] = gos
     if sp is not None:
-        params[4 + ns:] = sp.ravel()
+        params[_SCALARS + ns:] = sp.ravel()
     from .placement import scan_device
 
     args.append(jax.device_put(params, scan_device()))
@@ -204,7 +218,8 @@ def launch_fused(dbatch: DeviceBatch, filter_expr: Expr | None,
         args.append(vals)
         if has_valid:
             args.append(valid)
-    dev_out = fn(*args)
+    with _DISPATCH_LOCK:
+        dev_out = fn(*args)
     int_cols = {name for name in present
                 if jnp.issubdtype(dbatch.fields[name][1].dtype, jnp.integer)}
     agg_cols = tuple(n for n in present if n in col_wants)
@@ -220,7 +235,7 @@ def run_fused(dbatch: DeviceBatch, filter_expr: Expr | None,
 
 
 def _build_kernel(filter_expr: Expr | None, col_wants: dict,
-                  present: tuple, ns_pad: int, n_buckets: int,
+                  present: tuple, ns_pad: int,
                   use_bucket: bool, i_s: int, need_rank: bool,
                   valid_flags: tuple, has_ts_ns: bool, regular: bool,
                   n_pad: int = 0, run_pad: int = 0):
@@ -263,7 +278,8 @@ def _build_kernel(filter_expr: Expr | None, col_wants: dict,
         else:
             rank = None
         params = args[i]; i += 1
-        ra_s, ra_ns, offset, n_rows = params[0], params[1], params[2], params[3]
+        ra_s, ra_ns, offset, n_rows, n_buckets = (
+            params[k] for k in range(_SCALARS))
         fields = {}
         for name, has_valid in zip(present, valid_flags):
             vals = args[i]; i += 1
@@ -275,9 +291,9 @@ def _build_kernel(filter_expr: Expr | None, col_wants: dict,
         row = jax.lax.iota(jnp.int32, n_pad)
         if regular:
             # reconstruct sid + ts_sec from [n_series,3] run params
-            n_series = (params.shape[0] - 4) // 4
-            group_of_series = params[4:4 + n_series]
-            sp = params[4 + n_series:].reshape(n_series, 3)
+            n_series = (params.shape[0] - _SCALARS) // 4
+            group_of_series = params[_SCALARS:_SCALARS + n_series]
+            sp = params[_SCALARS + n_series:].reshape(n_series, 3)
             row_start, sec0, stride = sp[:, 0], sp[:, 1], sp[:, 2]
             sid_ord = (jnp.searchsorted(row_start, row, side="right") - 1
                        ).astype(jnp.int32)
@@ -286,8 +302,8 @@ def _build_kernel(filter_expr: Expr | None, col_wants: dict,
                 k = row - row_start[sid_ord]
                 ts_sec = sec0[sid_ord] + k * stride[sid_ord]
         else:
-            n_series = params.shape[0] - 4
-            group_of_series = params[4:]
+            n_series = params.shape[0] - _SCALARS
+            group_of_series = params[_SCALARS:]
         mask = row < n_rows
         if filter_expr is not None:
             env = {}

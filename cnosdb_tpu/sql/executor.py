@@ -3307,9 +3307,15 @@ class QueryExecutor:
                     from concurrent.futures import ThreadPoolExecutor
 
                     def one(b):
-                        return finish_scan_aggregate(
-                            launch_scan_aggregate(b, q))
+                        # prep, put and dispatch, then the blocking pull:
+                        # summed over the pool's threads, both inside
+                        # this kernel_ms
+                        with stages.stage("fanout.launch_ms"):
+                            job = launch_scan_aggregate(b, q)
+                        with stages.stage("fanout.fetch_ms"):
+                            return finish_scan_aggregate(job)
 
+                    stages.count("fanout.vnodes", len(batches))
                     with ThreadPoolExecutor(
                             max_workers=min(8, len(batches))) as tp:
                         results = [f.result() for f in [
@@ -3321,6 +3327,7 @@ class QueryExecutor:
             with stages.stage("merge_ms"):
                 merged = _merge_results_vec(results, plan, phys_aggs)
             if merged is not None:
+                stages.count("merge.groups", merged.n_rows)
                 with stages.stage("finalize_ms"):
                     return self._finalize_single(plan, merged, phys_aggs,
                                                  finalize)

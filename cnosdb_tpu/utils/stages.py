@@ -171,6 +171,18 @@ STAGE_CATALOG: dict[str, str] = {
                         "vec-merge AggResult shape",
     "mesh.rows": "rows aggregated through the mesh lane per query",
     "mesh.shards": "mesh devices participating in the collective merge",
+    "fanout.launch_ms": "per-vnode aggregate fan-out, inside kernel_ms: "
+                        "launch_scan_aggregate of every batch (group "
+                        "layout, upload, dispatch), summed over the "
+                        "pool's threads",
+    "fanout.fetch_ms": "per-vnode aggregate fan-out, inside kernel_ms: "
+                       "finish_scan_aggregate of every batch (the "
+                       "blocking pull of its partials + assembly), "
+                       "summed over the pool's threads",
+    "fanout.vnodes": "scan batches fanned out to per-vnode aggregate "
+                     "launches (the mesh lane declined or was not asked)",
+    "merge.groups": "groups out of the vectorized host merge of "
+                    "per-vnode partials (_merge_results_vec)",
     "hedge.fired": "hedged scan attempts launched at a next-ranked "
                    "replica after the adaptive p95 trigger elapsed",
     "hedge.won": "scans answered by a hedge attempt instead of the "

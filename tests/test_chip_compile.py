@@ -100,7 +100,7 @@ def _fused_args(spec, n_cols, use_bucket, need_rank, n_series=1000,
     # launch_fused's argument order for an irregular, second-aligned batch:
     # [ts_sec], sid_ordinal, [rank], packed params, the value columns
     args = [spec((rows,), jnp.int32)] * (1 + use_bucket + need_rank)
-    args.append(spec((4 + n_series,), jnp.int32))    # packed params
+    args.append(spec((fused._SCALARS + n_series,), jnp.int32))  # packed params
     args += [spec((rows,), jnp.int64)] * n_cols
     return args
 
@@ -120,11 +120,11 @@ FUSED_SHAPES = {
 @pytest.mark.parametrize("run_pad", [0, 8192], ids=["by-rows", "by-runs"])
 @pytest.mark.parametrize("shape", list(FUSED_SHAPES))
 def test_fused_program(spec, shape, run_pad):
-    flt, col_wants, n_buckets, use_bucket = FUSED_SHAPES[shape]
+    flt, col_wants, _n_buckets, use_bucket = FUSED_SHAPES[shape]
     present = tuple(sorted(col_wants))
     need_rank = any(w.get("want_last") for w in col_wants.values())
     fn, manifest = fused._build_kernel(
-        flt, col_wants, present, SEGMENTS, n_buckets, use_bucket, 3600,
+        flt, col_wants, present, SEGMENTS, use_bucket, 3600,
         need_rank, (False,) * len(present), False, False, ROWS, run_pad)
     _compile(fn, *_fused_args(spec, len(present), use_bucket, need_rank))
     assert len(manifest) > len(present)
@@ -138,7 +138,7 @@ def _fleet_program(spec, n_cols):
     assert run_pad == 8192
     col_wants = {f: {"want_sum": True} for f in FIELDS[:n_cols]}
     fn, manifest = fused._build_kernel(
-        None, col_wants, tuple(sorted(col_wants)), SEGMENTS, 6, True, 3600,
+        None, col_wants, tuple(sorted(col_wants)), SEGMENTS, True, 3600,
         False, (False,) * n_cols, False, False, rows, run_pad)
     assert manifest[-1] == ("__runs__", "engaged")
     return _compile(fn, *_fused_args(spec, n_cols, True, False, rows=rows))

@@ -30,6 +30,11 @@ HOSTS, STEPS = 300, 360    # 1 h of data; a request scans 50 min = 90 000 rows
 DB = "bench"
 with open(os.path.join(ROOT, "benchmarks", "traffic", "fleet-groupby.json")) as f:
     FLEET = json.load(f)["classes"][0]            # TSBS double-groupby-1
+with open(os.path.join(ROOT, "benchmarks", "traffic",
+                       "fleet-groupby-all.json")) as f:
+    FLEET_ALL = json.load(f)["classes"][0]        # TSBS double-groupby-all
+FANOUT_KEYS = ("fanout.launch_ms", "fanout.fetch_ms", "fanout.vnodes",
+               "merge.groups")
 
 
 def _class(aggregate: str) -> dict:
@@ -47,9 +52,9 @@ class _Deployment:
         self.h, self.ds = harness, ds
         self.rng = np.random.default_rng(SEED)
 
-    def sql(self, sql: str, headers=None):
+    def sql(self, sql: str, headers=None, db: str = DB):
         status, body, hdrs = self.h.request(
-            "POST", f"/api/v1/sql?db={DB}", sql,
+            "POST", f"/api/v1/sql?db={db}", sql,
             headers={"Accept": "application/csv", **(headers or {})})
         assert status == 200, body[:500]
         return body, hdrs
@@ -59,19 +64,23 @@ class _Deployment:
         assert status == 200
         return parse_metrics(body)
 
-    def ask(self, cls: dict) -> devops.Request:
+    def ask(self, cls: dict, db: str = DB, ds=None) -> dict:
         """One request of the class with a window nothing has had, answered
-        and compared with the reference."""
-        req = devops.ClassGenerator(cls, self.ds, self.rng).draw()
-        text, _ = self.sql(req.sql)
+        and compared with the reference. → the stages of its profile, with
+        `answer_rows`: the groups of the answer, which are the reference's
+        once the comparison has passed."""
+        ds = ds or self.ds
+        req = devops.ClassGenerator(cls, ds, self.rng).draw()
+        text, hdrs = self.sql(req.sql, {"X-CnosDB-Profile": "1"}, db=db)
         # devops.check_answer: the same set of (bucket, hostname) rows;
         # count / min / max / sum compared as integers — the columns are
         # BIGINT and the lane's sums are i64, so nothing may round; avg to
         # 1e-9 relative — the reference divides the exact integer sum by
         # the exact count in f64 and so does the program, the tolerance
         # is room for the CSV's shortest-repr digits, not for the merge
-        devops.check_answer(self.ds, req, text)
-        return req
+        devops.check_answer(ds, req, text)
+        st = json.loads(hdrs["X-CnosDB-Profile-Summary"])["stages"]
+        return {**st, "answer_rows": len(text.splitlines()) - 1}
 
 
 def _mesh_outcomes(m: dict) -> dict:
@@ -161,6 +170,101 @@ def test_every_width_gives_the_references_answer(deployment, width, n_dev,
     assert _mesh_errors(after) == _mesh_errors(before)
 
 
+@pytest.mark.parametrize("lane", ["fused", "native"])
+@pytest.mark.parametrize("cls", [FLEET, FLEET_ALL],
+                         ids=lambda c: c["name"])
+def test_one_device_fans_out_a_launch_a_vnode_and_merges_on_the_host(
+        deployment, width, monkeypatch, cls, lane):
+    """The deployment of `tsbs-devops-cpu-1k-shard8-1chip`: on one device
+    the mesh lane declines once a query (`few_devices`) and the per-vnode
+    fan-out + `_merge_results_vec` give the reference's answer, for one
+    field and for all ten. The profile says so: a launch and a fetch a
+    vnode with rows, both inside the `kernel_ms` section (thread-summed,
+    so at most one `kernel_ms` a vnode), a merge that took time, and as
+    many merged groups as the reference has. `fused` drives the device
+    program on this backend; `native` is what a CPU node runs."""
+    if lane == "fused":
+        monkeypatch.setenv("CNOSDB_TPU_FORCE_DEVICE_PATH", "1")
+    width(1)
+    before = deployment.metrics()
+    st = deployment.ask(cls)
+    after = deployment.metrics()
+    assert _rise(_mesh_outcomes(before), _mesh_outcomes(after)) \
+        == {("exec", "few_devices"): 1}
+    assert _mesh_errors(after) == _mesh_errors(before)
+    assert st["fanout.vnodes"] == 8          # 300 hosts: no vnode is empty
+    if lane == "fused":
+        assert st["fused_launches"] == st["fanout.vnodes"]
+        assert st["kernel.fetch_ms"] <= st["fanout.fetch_ms"] + 0.01
+    else:
+        assert "fused_launches" not in st
+    assert st["fanout.launch_ms"] > 0 and st["fanout.fetch_ms"] >= 0
+    assert st["fanout.launch_ms"] + st["fanout.fetch_ms"] \
+        <= st["fanout.vnodes"] * st["kernel_ms"] + 0.01
+    assert st["merge_ms"] > 0
+    assert st["merge.groups"] == st["answer_rows"] == st["group_count"]
+    assert not [k for k in st if k.startswith("mesh.")], sorted(st)
+
+
+def test_the_fan_outs_launches_dispatch_one_at_a_time(deployment, width,
+                                                      monkeypatch):
+    """Eight pool threads reach the fused program at once, each vnode
+    with shapes of its own; a call with new shapes compiles, and eight
+    compiles side by side crashed the TPU compiler on a v5e (PR 33). So
+    the call itself is serial: never two threads inside it."""
+    import threading
+    import time
+
+    from cnosdb_tpu.ops import fused
+
+    guard, inside, peak = threading.Lock(), [0], [0]
+    build = fused._build_kernel
+
+    def build_slow(*a, **k):
+        fn, manifest = build(*a, **k)
+
+        def slow(*args):
+            with guard:
+                inside[0] += 1
+                peak[0] = max(peak[0], inside[0])
+            try:
+                time.sleep(0.02)
+                return fn(*args)
+            finally:
+                with guard:
+                    inside[0] -= 1
+        return slow, manifest
+
+    monkeypatch.setenv("CNOSDB_TPU_FORCE_DEVICE_PATH", "1")
+    monkeypatch.setattr(fused, "_build_kernel", build_slow)
+    monkeypatch.setattr(fused, "_kernel_cache", {})
+    width(1)
+    st = deployment.ask(FLEET)
+    assert st["fused_launches"] == 8 and peak[0] == 1, (st, peak)
+
+
+@pytest.mark.parametrize("where", ["one_shard", "mesh4"])
+def test_no_fanout_key_off_the_fan_out_branch(deployment, width, where):
+    """One shard finalizes straight from its one batch; a mesh of four
+    merges by collective: neither books a `fanout.*` key or `merge.groups`,
+    and both give the reference's answer."""
+    if where == "mesh4":
+        width(4)
+        st = deployment.ask(FLEET)
+        assert st["mesh.shards"] == 4
+    else:
+        small = devops.Dataset(SEED + 1, 40, 120)
+        deployment.sql("CREATE DATABASE one", db="public")
+        status, out, _ = deployment.h.request(
+            "POST", "/api/v1/write?db=one", small.lines(0, 120).decode())
+        assert status == 200, out
+        deployment.sql("FLUSH", db="one")
+        width(1)
+        st = deployment.ask(FLEET, db="one", ds=small)
+        assert st["answer_rows"] > 0 and "kernel_ms" in st
+    assert not [k for k in FANOUT_KEYS if k in st], sorted(st)
+
+
 @pytest.fixture(scope="module")
 def compiles():
     """[n]: every backend compile of this process from here on, whatever
@@ -197,6 +301,42 @@ def test_shifted_windows_compile_nothing_after_the_warm_up(deployment, width,
     rise = _rise(_mesh_outcomes(before), _mesh_outcomes(deployment.metrics()))
     assert rise == {("exec", "engaged"): 10, ("merge", "collective"): 10}
     assert mesh_merge_kernel._cache_size() == cached0
+    assert compiles[0] == n0
+
+
+class _Starts:
+    """A stand-in for the generator's rng that hands out chosen window
+    starts (seconds after the first point)."""
+
+    def __init__(self, *starts):
+        self.starts = list(starts)
+
+    def integers(self, _lo, _hi):
+        return self.starts.pop(0)
+
+
+def test_a_window_on_a_bucket_boundary_is_no_new_program(
+        deployment, width, monkeypatch, compiles):
+    """A window that starts on a bucket boundary has one bucket fewer
+    than its neighbours (TSBS's 12 h window cut to 5/6 of the loaded span
+    is ten seconds short of whole buckets, so one window in 360 does).
+    The bucket count rides in the fused program's params: after two
+    warm-up requests such a window compiles nothing — on the chip it
+    was one compile a vnode, seconds inside a request (PR 33)."""
+    from cnosdb_tpu.ops import fused
+
+    # 5-minute buckets over the 2 991 s window: 11 of them, 10 from a
+    # boundary; 34-43 series a vnode keep both in one segment size class
+    cls = {**FLEET, "name": "double-groupby-1-5min", "bucket_s": 300}
+    monkeypatch.setenv("CNOSDB_TPU_FORCE_DEVICE_PATH", "1")
+    monkeypatch.setattr(deployment, "rng", _Starts(150, 151, 0, 152))
+    width(1)
+    for _ in range(2):
+        assert deployment.ask(cls)["answer_rows"] == 11 * HOSTS
+    n0, cached0 = compiles[0], len(fused._kernel_cache)
+    assert deployment.ask(cls)["answer_rows"] == 10 * HOSTS
+    assert deployment.ask(cls)["answer_rows"] == 11 * HOSTS
+    assert len(fused._kernel_cache) == cached0
     assert compiles[0] == n0
 
 

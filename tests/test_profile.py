@@ -596,6 +596,8 @@ def _seed_sharded_ints(h, monkeypatch, hosts=16, steps=200):
 _MESH_KEYS = ("mesh.plan_ms", "mesh.upload_ms", "mesh.collective_ms",
               "mesh.launch_ms", "mesh.fetch_ms", "mesh.assemble_ms",
               "mesh.columns", "mesh.rows", "mesh.shards")
+_FANOUT_KEYS = ("fanout.launch_ms", "fanout.fetch_ms", "fanout.vnodes",
+                "merge.groups")
 
 
 def test_mesh_request_splits_the_collective_into_launch_and_fetch(
@@ -619,6 +621,7 @@ def test_mesh_request_splits_the_collective_into_launch_and_fetch(
         <= st["mesh.collective_ms"] + 0.01
     assert st["mesh.columns"] == 2          # usage and idle: one program each
     assert st["mesh.rows"] == rows and st["mesh.shards"] == 4
+    assert not [k for k in _FANOUT_KEYS if k in st], sorted(st)
 
 
 @pytest.mark.parametrize("lane", ["fused", "mesh"])
@@ -669,6 +672,45 @@ def test_traced_mesh_request_holds_the_lane_under_http_sql(http, monkeypatch):
             assert s["start_ns"] >= coll["start_ns"]
             assert s["start_ns"] + s["duration_ns"] \
                 <= coll["start_ns"] + coll["duration_ns"] + 10**6
+
+
+def test_traced_fanout_request_holds_launch_and_fetch_inside_kernel(
+        http, monkeypatch):
+    """The same sharded table on a mesh of one: the lane declines, every
+    vnode's batch gets its own launch and fetch on the pool, and the host
+    merge answers. The four keys are booked here — a launch and a fetch
+    span a vnode, each inside `kernel_ms` under `http:sql` — and on the
+    mesh lane (the split test above) not at all."""
+    _seed_sharded_ints(http, monkeypatch)
+    monkeypatch.setenv("CNOSDB_MESH_DEVICES", "1")
+    tid = "feedc0de0033"
+    status, body, hdrs = http.request(
+        "POST", "/api/v1/sql?db=mesh4", _BUCKETED,
+        headers={"X-CnosDB-Profile": "1", "cnos-trace-id": tid})
+    assert status == 200, body
+    st = json.loads(hdrs["X-CnosDB-Profile-Summary"])["stages"]
+    for k in _FANOUT_KEYS:
+        assert k in stages.STAGE_CATALOG, k
+        assert k in st, (k, sorted(st))
+    assert not [k for k in st if k.startswith("mesh.")], sorted(st)
+    assert st["fanout.vnodes"] == 4
+    assert st["merge.groups"] == len(body.splitlines()) - 1
+    assert st["fanout.launch_ms"] > 0 and st["fanout.fetch_ms"] >= 0
+    # thread-summed inside a wall section: at most a kernel_ms a vnode
+    assert st["fanout.launch_ms"] + st["fanout.fetch_ms"] \
+        <= st["fanout.vnodes"] * st["kernel_ms"] + 0.01
+    spans = _trace_spans(http, tid)
+    by_id = {s["span_id"]: s for s in spans}
+    kernel = next(s for s in spans if s["name"] == "kernel_ms")
+    for leaf in ("fanout.launch_ms", "fanout.fetch_ms"):
+        found = [s for s in spans if s["name"] == leaf]
+        assert len(found) == 4, (leaf, len(found))
+        for s in found:
+            chain = _ancestors(s, by_id)
+            assert chain[0] == "kernel_ms" and chain[-1] == "http:sql", chain
+            assert s["start_ns"] >= kernel["start_ns"]
+            assert s["start_ns"] + s["duration_ns"] \
+                <= kernel["start_ns"] + kernel["duration_ns"] + 10**6
 
 
 def test_unprofiled_request_leaves_one_span_and_no_intervals(http):
@@ -935,8 +977,12 @@ def test_multi_batch_kernel_pool_keeps_the_query_context(tmp_path,
         by_id = {s["span_id"]: s for s in mine}
         fetches = [s for s in mine if s["name"] == "kernel.fetch_ms"]
         assert len(fetches) == 2
-        assert all(by_id[s["parent_id"]]["name"] == "kernel_ms"
+        # each on its pool thread: the fan-out's fetch, inside kernel_ms
+        assert all(_ancestors(s, by_id)[:2] == ["fanout.fetch_ms",
+                                                "kernel_ms"]
                    for s in fetches)
+        assert prof.counts.get("fanout.vnodes") == 2, prof.counts
+        assert prof.counts.get("merge.groups") == 16, prof.counts
     finally:
         coord.close()
 

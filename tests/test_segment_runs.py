@@ -209,7 +209,7 @@ def test_run_pad_is_a_size_class_or_nothing(n_pad, max_runs, expect):
 def _fused_matrix(run_pad, flt, col_wants, args, n_pad, valid_flags):
     present = tuple(sorted(col_wants))
     fn, manifest = fused._build_kernel(
-        flt, col_wants, present, 64, 4, True, 60, False, valid_flags, False,
+        flt, col_wants, present, 64, True, 60, False, valid_flags, False,
         False, n_pad, run_pad)
     return np.asarray(fn(*args)), manifest
 
@@ -238,9 +238,9 @@ def test_fused_program_by_runs_equals_by_rows(rng, case):
     flt = BinOp(">", Column("v"), Literal(0)) \
         if case == "filter_cuts_runs" else None
     col_wants = {"v": {"want_sum": True, "want_min": True, "want_max": True}}
-    params = np.zeros(4 + n_series, np.int32)
-    params[3] = n
-    params[4:] = rng.permutation(n_series)        # group_of_series
+    params = np.zeros(fused._SCALARS + n_series, np.int32)
+    params[3:5] = n, 4                            # rows, buckets
+    params[fused._SCALARS:] = rng.permutation(n_series)   # group_of_series
     args = [ts_sec, sid, params, vals] + ([valid] if nullable else [])
     run_pad = 64                 # n_series * 4 buckets + the tail = 49
     by_rows, manifest0 = _fused_matrix(0, flt, col_wants, args, n_pad,
