@@ -24,6 +24,7 @@ from ..parallel.coordinator import Coordinator
 from ..parallel.meta import MetaStore, DEFAULT_TENANT
 from ..protocol.line_protocol import parse_lines
 from ..sql.executor import QueryExecutor, ResultSet, Session
+from ..sql.tsfuncs import IntervalNs, format_interval_ns, render_composite
 from ..storage.engine import TsKv
 from ..utils import deadline as deadline_mod
 from ..utils import stages
@@ -1244,9 +1245,6 @@ def profile_summary_header(qid, wall_ms, stages_: dict,
 def _cell(v):
     if v is None:
         return ""
-    from ..sql.tsfuncs import IntervalNs, format_interval_ns, \
-        render_composite
-
     if isinstance(v, IntervalNs):
         return format_interval_ns(int(v))
     if isinstance(v, dict):
@@ -1272,10 +1270,55 @@ def _cell(v):
     return str(v)
 
 
+def _patch_nan_zero(cells: list, col: np.ndarray) -> list:
+    # the two floats _cell special-cases: NaN is a VALUE, -0.0 renders 0.0
+    for i in np.flatnonzero(np.isnan(col)).tolist():
+        cells[i] = "NaN"
+    for i in np.flatnonzero(col == 0.0).tolist():
+        cells[i] = "0.0"
+    return cells
+
+
+def _csv_column(col) -> list | None:
+    """One result column as escaped CSV cells by a rule its dtype picks,
+    byte for byte `_csv_escape(_cell(v))` of every value; None where no
+    rule applies and the caller renders the column a cell at a time."""
+    if not isinstance(col, np.ndarray) or col.ndim != 1:
+        return None
+    dt = col.dtype
+    if dt.kind in "iu":
+        return list(map(str, col.tolist()))
+    if dt == np.float64:
+        return _patch_nan_zero(list(map(repr, col.tolist())), col)
+    if dt == np.float32:
+        # shortest f32 text: iterating keeps the numpy scalars
+        return _patch_nan_zero(list(map(str, col)), col)
+    if dt == np.bool_:
+        return np.where(col, "true", "false").tolist()
+    if dt == object:
+        cells = col.tolist()
+        # exactly str: IntervalNs, np.str_, None, dicts keep _cell's rules
+        if not set(map(type, cells)) <= {str}:
+            return None
+        blob = "".join(cells)
+        if "," in blob or '"' in blob or "\n" in blob:
+            return list(map(_csv_escape, cells))
+        return cells
+    return None
+
+
 def format_csv(rs: ResultSet) -> str:
+    cols, percell = [], 0
+    for col in rs.columns:
+        cells = _csv_column(col)
+        if cells is None:
+            percell += 1
+            cells = [_csv_escape(_cell(v))
+                     for v in ResultSet.column_values(col)]
+        cols.append(cells)
+    stages.count("render.percell_columns", percell)
     lines = [",".join(rs.names)]
-    for row in rs.rows():
-        lines.append(",".join(_csv_escape(_cell(v)) for v in row))
+    lines.extend(map(",".join, zip(*cols)))
     return "\n".join(lines) + "\n"
 
 

@@ -67,14 +67,17 @@ class ResultSet:
     def n_rows(self) -> int:
         return len(self.columns[0]) if self.columns else 0
 
+    @staticmethod
+    def column_values(c) -> list:
+        # float32 stays a numpy scalar so renderers can keep f32
+        # precision (tolist() would widen to python float = f64)
+        return list(c) if getattr(c, "dtype", None) == np.float32 \
+            else c.tolist()
+
     def rows(self) -> list[tuple]:
         if not self.columns:
             return []
-        # float32 stays a numpy scalar so renderers can keep f32
-        # precision (tolist() would widen to python float = f64)
-        cols = [list(c) if getattr(c, "dtype", None) == np.float32
-                else c.tolist() for c in self.columns]
-        return list(zip(*cols))
+        return list(zip(*map(self.column_values, self.columns)))
 
     def to_dict(self) -> dict:
         return {n: c for n, c in zip(self.names, self.columns)}

@@ -75,6 +75,10 @@ STAGE_CATALOG: dict[str, str] = {
     "ingress_wait_ms": "HTTP handler entry → worker thread past the "
                        "admission gate (executor hand-off + queue wait)",
     "render_ms": "result set → CSV / JSON / table text",
+    "render.percell_columns": "CSV columns of the answer rendered a cell "
+                              "at a time: no by-column rule took their "
+                              "dtype (0 on int / float / bool / str "
+                              "answers)",
     "untraced_ms": "wall_ms minus the union of the request's stage "
                    "intervals: time no span covers (traced requests only)",
     "upload_ms": "host→device column uploads",

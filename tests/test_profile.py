@@ -845,6 +845,29 @@ def test_render_error_is_not_an_sql_error(http, monkeypatch):
     assert "cannot render" in by_name["render_ms"]["tags"]["error"]
 
 
+@pytest.mark.parametrize("sql, percell", [
+    # str, float64 and int64 columns: every one has a by-column rule
+    ("SELECT host, max(usage) FROM cpu GROUP BY host", 0),
+    ("SELECT time, host, usage FROM cpu LIMIT 5", 0),
+    # SHOW / DESCRIBE answers are columns of plain str: a rule takes them
+    ("DESCRIBE TABLE cpu", 0),
+    # a gauge / window composite is a dict in an object column: per cell
+    ("SELECT gauge_agg(time, usage) FROM cpu", 1),
+    ("SELECT time_window(time, interval '10 seconds') FROM cpu LIMIT 3", 1),
+])
+def test_served_request_books_render_and_its_percell_columns(http, sql,
+                                                             percell):
+    _seed_http(http)
+    status, body, hdrs = http.request(
+        "POST", "/api/v1/sql?db=public", sql,
+        headers={"X-CnosDB-Profile": "1"})
+    assert status == 200, body
+    booked = json.loads(hdrs["X-CnosDB-Profile-Summary"])["stages"]
+    assert booked["render_ms"] >= 0
+    assert booked["render.percell_columns"] == percell
+    assert body.endswith("\n") and body.count("\n") >= 2
+
+
 def test_disconnect_ends_the_root_span_where_the_worker_ends(http,
                                                              monkeypatch):
     """The handler is cancelled while the worker thread still records
