@@ -360,7 +360,9 @@ class Coordinator:
             wb.add_series(table, SeriesRows(
                 sk, [time.time_ns()],
                 {"value": (int(ValueType.UNSIGNED), [int(value)])}))
-            self.write_points("cnosdb", "usage_schema", wb)
+            # an internal row: not a stage of the request that caused it
+            with stages.profile_scope(None):
+                self.write_points("cnosdb", "usage_schema", wb)
         except Exception:
             stages.count_error("swallow.coord.report_usage")  # metrics must never fail or recurse into the caller
 
@@ -859,7 +861,7 @@ class Coordinator:
                     page_constraints: dict | None = None,
                     filter_key: str | None = None,
                     n_threads: int = 1,
-                    compressed_spec=None) -> ScanBatch | None:
+                    compressed_spec=None, recut: int = 0) -> ScanBatch | None:
         table, trs, doms = split.table, split.time_ranges, split.tag_domains
         v = self.engine.vnode(split.owner, split.vnode_id)
         if v is None:
@@ -901,10 +903,11 @@ class Coordinator:
                     if compressed_spec is not None else None)
         from ..utils import stages
 
-        # token BEFORE probe/decode: a write racing the decode makes the
-        # stored token conservative (its rows re-decode next delta and
-        # dedup away), never stale
-        token = v.scan_token()
+        # ONE cut for probe and decode: the token names exactly what the
+        # scan below reads (file set, memcache rows up to its seq) — a
+        # write, a switch or an inline flush beside it changes neither
+        cut = v.cut()
+        token = cut.token
         stale = None
         probes = (key, key0) if filter_key else (key0,)
         if spec_key is not None:
@@ -914,7 +917,7 @@ class Coordinator:
                 hit = self._scan_cache.get(k)
                 if hit is None:
                     continue
-                if hit[0].data_version == v.data_version:
+                if hit[0].data_version == token.data_version:
                     self._scan_cache[k] = self._scan_cache.pop(k)  # LRU
                     stages.count("scan_hit")
                     return hit[1]
@@ -922,20 +925,30 @@ class Coordinator:
                     stale = (k, hit)
         try:
             if stale is not None:
-                b = self._scan_delta(v, stale, token, table, trs, sids,
+                b = self._scan_delta(cut, stale, token, table, trs, sids,
                                      field_names, page_constraints,
                                      key, key0, n_threads)
                 if b is not None:
                     return b
             stages.count("scan_miss")
             with stages.stage("decode_ms"):
-                b = scan_vnode(v, table, series_ids=sids, time_ranges=trs,
+                b = scan_vnode(cut, table, series_ids=sids, time_ranges=trs,
                                field_names=field_names,
                                page_constraints=page_constraints,
                                n_threads=n_threads,
                                upload_hook=self._upload_hook(),
                                decode_hook=self._decode_hook(),
                                compressed_spec=compressed_spec)
+        except FileNotFoundError:
+            # a compaction replaced files of this cut and unlinked them
+            # before the scan had opened them: cut again, token and all
+            from ..storage.scan import RECUTS
+
+            if recut >= RECUTS:
+                raise
+            return self._scan_local(split, field_names, page_constraints,
+                                    filter_key, n_threads, compressed_spec,
+                                    recut + 1)
         except ChecksumMismatch as e:
             # quarantine-on-read: drop the corrupt file from the live
             # Version (manifest-durable, excluded from every future scan),

@@ -13,6 +13,7 @@ import base64
 import json
 import sys
 import time
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 from aiohttp import web
@@ -39,6 +40,15 @@ DEADLINE_HEADER = "X-CnosDB-Deadline-Ms"
 # summary header, and the full profile is at /debug/profile?qid=
 PROFILE_HEADER = "X-CnosDB-Profile"
 PROFILE_SUMMARY_HEADER = "X-CnosDB-Profile-Summary"
+# cnosdb_write_stage_ms{stage}: the `write.<stage>_ms` keys of a batch
+WRITE_STAGES = tuple(k[len("write."):-len("_ms")]
+                     for k in stages.STAGE_CATALOG if k.startswith("write."))
+# threads that run write requests (parse → vnode lock → WAL → memcache):
+# one batch is applied while the next is parsed. One vnode applies one
+# batch at a time and the rest is Python under one GIL, so more threads buy
+# no ingest, and four of them starved the queries beside them for seconds
+# at a stretch (PERF.md §6, PR 35)
+WRITE_WORKERS = 2
 
 
 class HttpServer:
@@ -70,6 +80,16 @@ class HttpServer:
         # observed once per admitted request (handle_sql); declared so
         # _sum/_count are on /metrics at 0 before the first query
         self.metrics.declare_histogram("cnosdb_requests_queue_wait_ms")
+        # observed once per acknowledged write batch (handle_write), from
+        # the `write.<stage>_ms` sums of the request's profile
+        for st in WRITE_STAGES:
+            self.metrics.declare_histogram("cnosdb_write_stage_ms", stage=st)
+        # writes run on threads of their own: a writer spends its time
+        # parsing or waiting for its vnode's lock, and on the loop's
+        # default pool two dozen of them would hold every thread a query
+        # is waiting for
+        self._write_pool = ThreadPoolExecutor(
+            WRITE_WORKERS, thread_name_prefix="write")
         # memory-governance plane: push [query] memory_* knobs into the
         # broker and hand it the gate so ladder step 2 can shed QUEUED
         # queries (server/memory.py)
@@ -196,17 +216,26 @@ class HttpServer:
             return _err_response(400, ParserError(f"bad precision {precision!r}"))
         body = await request.text()
         dl = self._request_deadline(request, self.write_timeout_ms)
+        # the batch's stages (utils/stages.py `write.*_ms`): sums only,
+        # one histogram observation each once the batch is acknowledged
+        prof = stages.QueryProfile()
+        prof.annotate = True
 
         def run():
-            with deadline_mod.scope(dl):
+            # on a worker thread, the parse too: a 1.3 MB body parsed on
+            # the event-loop thread stands in front of every other
+            # request's ingress and every answer's way out
+            with deadline_mod.scope(dl), stages.profile_scope(prof):
+                with stages.stage("write.parse_ms"):
+                    batch = parse_lines(body, prec)
+                self.limiters.check_write(session.tenant, batch.n_rows())
                 self.coord.write_points(session.tenant, session.database,
                                         batch)
+                return batch.n_rows()
 
         try:
-            batch = parse_lines(body, prec)
-            self.limiters.check_write(session.tenant, batch.n_rows())
             loop = asyncio.get_running_loop()
-            await loop.run_in_executor(None, run)
+            n_rows = await loop.run_in_executor(self._write_pool, run)
         except asyncio.CancelledError:
             dl.cancel("client disconnected")
             raise
@@ -225,7 +254,11 @@ class HttpServer:
                 self.metrics.incr("cnosdb_requests_memory_exceeded_total")
             return _err_response(_status_for(e), e)
         self.metrics.incr("cnosdb_http_writes_total")
-        self.metrics.incr("cnosdb_http_points_written_total", batch.n_rows())
+        self.metrics.incr("cnosdb_http_points_written_total", n_rows)
+        for st in WRITE_STAGES:
+            ms = prof.ms.get(f"write.{st}_ms")
+            if ms is not None:    # flush: only the batch that ran one
+                self.metrics.observe("cnosdb_write_stage_ms", ms, stage=st)
         self._record_http_usage(request, session, "http_data_in",
                                 len(body))
         self._record_http_usage(request, session, "http_writes", 1)
@@ -1018,6 +1051,12 @@ class HttpServer:
         for name, n in _scan.decode_fallback_snapshot().items():
             self.metrics.set_counter("cnosdb_decode_fallback_total", n,
                                      reason=name)
+        # write path: WAL payload bytes appended, memcache flushes and the
+        # rows they persisted (storage/vnode.py process totals)
+        from ..storage import vnode as _vnode
+
+        for name, n in _vnode.ingest_counters_snapshot().items():
+            self.metrics.set_counter(f"cnosdb_{name}_total", n)
         # aggregation plane: factorize/distinct path totals
         from ..ops import group_agg as _group_agg
 

@@ -9,6 +9,9 @@ dedup and null-mask construction happen once, vectorized, at
 """
 from __future__ import annotations
 
+import bisect
+from operator import itemgetter
+
 import numpy as np
 
 from ..models.points import SeriesRows
@@ -45,56 +48,77 @@ def _series_rows_bytes(sr: SeriesRows) -> int:
     return total
 
 
+_CHUNK_SEQ = itemgetter(0)     # a chunk is (wal_seq, timestamps, fields)
+
+
 class SeriesData:
-    """Accumulated rows of one series inside a memcache."""
+    """Accumulated rows of one series inside a memcache.
 
-    __slots__ = ("sid", "table", "ts_chunks", "field_chunks", "n_rows",
-                 "seq_chunks")
+    A writer appends while scans read with no lock between them, so the
+    one mutable thing here is `_chunks`, and a batch enters it WHOLE —
+    timestamps and every field in one tuple, one `list.append`. A reader
+    copies the list once (`chunks()`) and works on the copy: it sees a
+    prefix of whole appended batches, every field of a row or none of the
+    row. In-place edits (rename / drop of a field) swap in a rebuilt list.
+    """
 
-    def __init__(self, sid: int, table: str):
+    __slots__ = ("sid", "table", "_chunks")
+
+    def __init__(self, sid: int, table: str, chunks: list | None = None):
         self.sid = sid
         self.table = table
-        self.ts_chunks: list[list[int]] = []
-        # field → list[(row_offset, value_type, values)]; offset aligns the
-        # chunk with its rows in the concatenated timestamp stream
-        self.field_chunks: dict[str, list[tuple[int, int, list]]] = {}
-        self.n_rows = 0
-        # WAL seq per ts chunk (non-decreasing — appends follow log order);
-        # lets a delta scan take only the chunk suffix newer than a token
-        self.seq_chunks: list[int] = []
+        # [(wal_seq, timestamps, {field: (value_type, values)})], seq
+        # non-decreasing (appends follow log order): a reader cuts the
+        # list at a seq, a delta scan takes the suffix newer than a token
+        self._chunks: list[tuple[int, list, dict]] = \
+            chunks if chunks is not None else []
 
     def append(self, sr: SeriesRows, seq: int = 0):
-        off = self.n_rows
-        self.ts_chunks.append(sr.timestamps)
-        self.seq_chunks.append(seq)
-        self.n_rows += len(sr.timestamps)
-        for name, (vt, vals) in sr.fields.items():
-            self.field_chunks.setdefault(name, []).append((off, vt, vals))
+        self._chunks.append((seq, sr.timestamps, dict(sr.fields)))
 
-    def suffix(self, after_seq: int) -> "SeriesData | None":
-        """→ a SeriesData holding only the chunks with seq > after_seq
-        (None when there are none). Shares the chunk lists' objects —
-        callers must treat the result as read-only."""
-        import bisect
+    def chunks(self, upto_seq: int | None = None,
+               after_seq: int | None = None) -> list:
+        """→ a private copy of the whole batches appended so far, cut to
+        after_seq < seq <= upto_seq where given."""
+        chunks = self._chunks[:]
+        if upto_seq is not None and chunks and chunks[-1][0] > upto_seq:
+            chunks = chunks[:bisect.bisect_right(
+                chunks, upto_seq, key=_CHUNK_SEQ)]
+        if after_seq is not None and chunks and chunks[0][0] <= after_seq:
+            chunks = chunks[bisect.bisect_right(
+                chunks, after_seq, key=_CHUNK_SEQ):]
+        return chunks
 
-        i = bisect.bisect_right(self.seq_chunks, after_seq)
-        if i >= len(self.ts_chunks):
+    @property
+    def n_rows(self) -> int:
+        return sum(len(ts) for _seq, ts, _f in self._chunks[:])
+
+    def field_names(self) -> set[str]:
+        return {name for _seq, _ts, f in self._chunks[:] for name in f}
+
+    def rename_field(self, old: str, new: str):
+        self._chunks = [
+            (seq, ts, {new if n == old else n: v for n, v in f.items()})
+            for seq, ts, f in self._chunks]
+
+    def drop_field(self, name: str):
+        self._chunks = [
+            (seq, ts, {n: v for n, v in f.items() if n != name})
+            for seq, ts, f in self._chunks]
+
+    def suffix(self, after_seq: int,
+               upto_seq: int | None = None) -> "SeriesData | None":
+        """→ a SeriesData holding only the chunks with after_seq < seq
+        (<= upto_seq), None when there are none. Shares the chunks'
+        objects — callers must treat the result as read-only."""
+        chunks = self.chunks(upto_seq, after_seq)
+        if not any(len(ts) for _seq, ts, _f in chunks):
             return None
-        nd = SeriesData(self.sid, self.table)
-        nd.ts_chunks = self.ts_chunks[i:]
-        nd.seq_chunks = self.seq_chunks[i:]
-        nd.n_rows = sum(len(c) for c in nd.ts_chunks)
-        if nd.n_rows == 0:
-            return None
-        base = sum(len(c) for c in self.ts_chunks[:i])
-        for name, chunks in self.field_chunks.items():
-            kept = [(off - base, vt, vals) for (off, vt, vals) in chunks
-                    if off >= base]
-            if kept:
-                nd.field_chunks[name] = kept
-        return nd
+        return SeriesData(self.sid, self.table, chunks)
 
-    def materialize(self) -> tuple[np.ndarray, dict[str, tuple[ValueType, np.ndarray, np.ndarray]], np.ndarray]:
+    def materialize(self, upto_seq: int | None = None) -> tuple[
+            np.ndarray, dict[str, tuple[ValueType, np.ndarray, np.ndarray]],
+            np.ndarray]:
         """→ (sorted unique ts, {field: (vt, values, valid_mask)}, order)
 
         Sorts by time. Duplicate timestamps merge PER FIELD: each field
@@ -104,27 +128,36 @@ class SeriesData:
         materialize fully vectorized; only chunks actually carrying Nones
         pay a per-element pass.
         """
-        if len(self.ts_chunks) == 1:
-            ts = np.asarray(self.ts_chunks[0], dtype=np.int64)
+        chunks = self.chunks(upto_seq)
+        if len(chunks) == 1:
+            ts = np.asarray(chunks[0][1], dtype=np.int64)
         else:
             ts = np.concatenate(
-                [np.asarray(c, dtype=np.int64) for c in self.ts_chunks]) \
-                if self.ts_chunks else np.empty(0, dtype=np.int64)
+                [np.asarray(c[1], dtype=np.int64) for c in chunks]) \
+                if chunks else np.empty(0, dtype=np.int64)
         n = len(ts)
+        # field → [(row_offset, value_type, values)]; the offset aligns a
+        # chunk's values with its rows in the concatenated timestamps
+        field_chunks: dict[str, list[tuple[int, int, list]]] = {}
+        off = 0
+        for _seq, cts, fields in chunks:
+            for name, (vt, vals) in fields.items():
+                field_chunks.setdefault(name, []).append((off, vt, vals))
+            off += len(cts)
         order = np.argsort(ts, kind="stable")  # stable: append order within ties
         ts_sorted = ts[order]
         group_starts = _group_starts(ts_sorted)
         uts = ts_sorted[group_starts]
         out_fields: dict[str, tuple[ValueType, np.ndarray, np.ndarray]] = {}
         idx = np.arange(n, dtype=np.int64)
-        for name, chunks in self.field_chunks.items():
-            vt = ValueType(chunks[0][1])
+        for name, fchunks in field_chunks.items():
+            vt = ValueType(fchunks[0][1])
             np_dtype = vt.numpy_dtype()
             typed = np_dtype is not object
             vals_full = (np.zeros(n, dtype=np_dtype) if typed
                          else np.empty(n, dtype=object))
             valid_full = np.zeros(n, dtype=bool)
-            for off, _vt, vals in chunks:
+            for off, _vt, vals in fchunks:
                 m = len(vals)
                 if typed and isinstance(vals, np.ndarray):
                     vals_full[off:off + m] = vals
@@ -152,7 +185,7 @@ class SeriesData:
 
     def time_range(self) -> tuple[int, int]:
         lo, hi = 2**63 - 1, -(2**63)
-        for c in self.ts_chunks:
+        for _seq, c, _f in self._chunks[:]:
             a = np.asarray(c, dtype=np.int64)
             if len(a):
                 lo = min(lo, int(a.min()))
@@ -207,8 +240,12 @@ class MemCache:
         key = (table, sid)
         sd = self.series.get(key)
         if sd is None:
-            sd = self.series[key] = SeriesData(sid, table)
-        sd.append(sr, seq)
+            # a series enters the dict with its first batch in it
+            sd = SeriesData(sid, table)
+            sd.append(sr, seq)
+            self.series[key] = sd
+        else:
+            sd.append(sr, seq)
         nb = len(sr.timestamps)
         self.approx_bytes += _series_rows_bytes(sr)
         self.rowcols += nb * (1 + len(sr.fields))
@@ -284,21 +321,28 @@ class MemCache:
             else:
                 del self.series[(tbl, sid)]
 
-    def suffix_view(self, after_seq: int) -> "MemCache | None":
+    def series_keys(self) -> list[tuple[str, int]]:
+        """The (table, sid) keys as one copy: scans run without the vnode
+        lock, and a write may grow the dict while a reader walks it."""
+        return list(self.series)
+
+    def suffix_view(self, after_seq: int,
+                    upto_seq: int | None = None) -> "MemCache | None":
         """→ a read-only MemCache exposing only rows appended with WAL
-        seq > after_seq, or None when this cache has nothing newer. Used
-        by the delta scan (storage/scan.DeltaVnodeView) so an incremental
-        rescan decodes only post-token memcache chunks."""
+        seq > after_seq (and <= upto_seq, a scan's cut), or None when this
+        cache has nothing newer. Used by the delta scan
+        (storage/scan.DeltaVnodeView) so an incremental rescan decodes
+        only post-token memcache chunks."""
         if self.max_seq <= after_seq:
             return None
         out = MemCache(self.vnode_id, self.max_bytes)
         out.immutable = True
         out.min_seq = self.min_seq
         out.max_seq = self.max_seq
-        # list(): scans run without the vnode lock, so a concurrent write
-        # may grow the dict mid-iteration (same discipline as _series_parts)
+        # the whole cache's bounds: a superset of the suffix's
+        out.min_ts, out.max_ts = self.min_ts, self.max_ts
         for key, sd in list(self.series.items()):
-            suf = sd.suffix(after_seq)
+            suf = sd.suffix(after_seq, upto_seq)
             if suf is not None:
                 out.series[key] = suf
         return out if out.series else None

@@ -85,6 +85,11 @@ class RecordWriter:
         self._f.write(rec)
         return off
 
+    def flush(self):
+        """Hand every appended byte to the OS: what a SIGKILL of this
+        process cannot take back (no fsync: a power loss still can)."""
+        self._f.flush()
+
     def sync(self):
         if faults.ENABLED:
             faults.fire("record.sync", path=self.path)

@@ -15,6 +15,7 @@ memcache.materialize / compaction merge).
 """
 from __future__ import annotations
 
+from contextlib import nullcontext
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -25,7 +26,7 @@ from ..utils import deadline as deadline_mod
 from ..models.strcol import DictArray, as_dict_part as _as_dict_part, \
     unify_dictionaries
 from .memcache import MemCache, _group_starts
-from .vnode import VnodeStorage
+from .vnode import VnodeCut, VnodeStorage, _CutSummary
 from ..server import memory as memgov
 from ..utils import lockwatch
 from ..utils import stages
@@ -74,10 +75,12 @@ def _time_mask(ts: np.ndarray, trs: TimeRanges) -> np.ndarray | None:
     return m
 
 
-def _series_parts(vnode: VnodeStorage, table: str, sid: int,
+def _series_parts(vnode: VnodeCut, table: str, sid: int,
                   field_names: list[str], trs: TimeRanges):
-    """Collect (ts, {field: (vt, vals, valid)}) parts in priority order."""
+    """Collect (ts, {field: (vt, vals, valid)}) parts in priority order
+    → (parts, how many of them came from memcaches, TSM pages read)."""
     parts = []
+    n_pages = 0
     targets = _field_targets(vnode, table, field_names)
     version = vnode.summary.version
     # files: L4..L1 then L0, ascending file_id within level ⇒ ascending priority
@@ -105,40 +108,62 @@ def _series_parts(vnode: VnodeStorage, table: str, sid: int,
                     continue
             fields = {}
             maps = _chunk_maps(cm)
+            n_pages += len(cm.time_pages)
             for name in field_names:
                 cid, cands = targets[name]
                 col = _resolve_chunk_col(maps, cid, cands)
                 if col is None:
                     continue
+                n_pages += len(col.pages)
                 vt = ValueType(col.pages[0].value_type)
                 vals, valid = r.read_series_column(table, sid, col.name)
                 if sel is not None:
                     vals, valid = vals[sel], valid[sel]
                 fields[name] = (vt, vals, valid)
             parts.append(((ts[sel] if sel is not None else ts), fields))
-    # memcaches: immutables old→new, then active
-    for cache in [*vnode.immutables, vnode.active]:
-        sd = cache.series.get((table, sid))
-        if sd is None:
-            continue
-        ts, mfields, _ = sd.materialize()
-        tmask = _time_mask(ts, trs)
-        if tmask is not None:
-            if not tmask.any():
-                continue
-            ts = ts[tmask]
-        fields = {}
-        for name in field_names:
-            src = next((c for c in targets[name][1]
-                        if c in mfields), None)
-            if src is None:
-                continue
-            vt, vals, valid = mfields[src]
+    # memcaches: immutables old→new, then active — whole batches up to the
+    # cut's seq, whatever a writer appends meanwhile
+    sds = [sd for cache in _caches_in_range(vnode, trs)
+           if (sd := cache.series.get((table, sid))) is not None]
+    if not sds:
+        return parts, 0, n_pages
+    n_files = len(parts)
+    with stages.stage("memcache_ms"):
+        rows = 0
+        for sd in sds:
+            ts, mfields, _ = sd.materialize(vnode.mem_seq)
+            rows += len(ts)
+            tmask = _time_mask(ts, trs)
             if tmask is not None:
-                vals, valid = vals[tmask], valid[tmask]
-            fields[name] = (vt, vals, valid)
-        parts.append((ts, fields))
-    return parts
+                if not tmask.any():
+                    continue
+                ts = ts[tmask]
+            fields = {}
+            for name in field_names:
+                src = next((c for c in targets[name][1]
+                            if c in mfields), None)
+                if src is None:
+                    continue
+                vt, vals, valid = mfields[src]
+                if tmask is not None:
+                    vals, valid = vals[tmask], valid[tmask]
+                fields[name] = (vt, vals, valid)
+            parts.append((ts, fields))
+    stages.count("memcache.series")
+    stages.count("memcache.rows", rows)
+    return parts, len(parts) - n_files, n_pages
+
+
+def _merged_series(vnode: VnodeCut, table: str, sid: int,
+                   field_names: list[str], trs: TimeRanges):
+    """One series through the per-series path → (ts, fields, TSM pages
+    read): its parts in priority order, merged. Where memcache rows take
+    part, the merge is their cost too (`memcache_ms`)."""
+    parts, n_mem, n_pages = _series_parts(vnode, table, sid, field_names,
+                                          trs)
+    with stages.stage("memcache_ms") if n_mem and len(parts) > 1 \
+            else nullcontext():
+        return (*merge_parts(parts, field_names), n_pages)
 
 
 def merge_parts(parts, field_names: list[str]):
@@ -241,57 +266,27 @@ def merge_parts(parts, field_names: list[str]):
 # ---------------------------------------------------------------------------
 
 
-class _DeltaVersion:
-    """Version facade whose levels hold ONLY `new_fids`; readers,
-    tombstones and paths delegate to the live Version (same caches)."""
+class DeltaVnodeView(VnodeCut):
+    """A cut exposing only data NEWER than a ScanToken: the TSM files in
+    `new_fids` plus memcache rows with WAL seq > `after_seq` (and within
+    the cut). scan_vnode runs against it unchanged — the result is the
+    delta batch that merge_scan_batches folds into the cached snapshot.
+    Index and schemas are the live ones (valid because the coordinator
+    only takes this path when destructive_version matched)."""
 
-    def __init__(self, version, new_fids: frozenset):
-        self._version = version
-        self.levels = [
-            {fid: fm for fid, fm in lvl.items() if fid in new_fids}
-            for lvl in version.levels]
+    __slots__ = ()
 
-    def reader(self, fm):
-        return self._version.reader(fm)
-
-    def tombstone(self, fm):
-        return self._version.tombstone(fm)
-
-    def file_path(self, fm):
-        return self._version.file_path(fm)
-
-    def all_files(self):
-        out = []
-        for lvl in self.levels:
-            out.extend(lvl.values())
-        return out
-
-
-class _DeltaSummary:
-    def __init__(self, version):
-        self.version = version
-
-
-class DeltaVnodeView:
-    """Vnode facade exposing only data NEWER than a ScanToken: the TSM
-    files in `new_fids` plus memcache rows with WAL seq > `after_seq`.
-    scan_vnode runs against it unchanged — the result is the delta batch
-    that merge_scan_batches folds into the cached snapshot. Index and
-    schemas are the live ones (valid because the coordinator only takes
-    this path when destructive_version matched)."""
-
-    def __init__(self, vnode: VnodeStorage, new_fids: frozenset,
+    def __init__(self, vnode: VnodeStorage | VnodeCut, new_fids: frozenset,
                  after_seq: int):
-        self.vnode_id = vnode.vnode_id
-        self.summary = _DeltaSummary(
-            _DeltaVersion(vnode.summary.version, new_fids))
-        self.index = vnode.index
-        self.schemas = vnode.schemas
-        act = vnode.active.suffix_view(after_seq)
-        self.active = act if act is not None \
-            else MemCache(vnode.vnode_id)
-        self.immutables = [sv for c in list(vnode.immutables)
-                           if (sv := c.suffix_view(after_seq)) is not None]
+        cut = vnode.cut()
+        act = cut.active.suffix_view(after_seq, cut.mem_seq)
+        super().__init__(
+            cut.vnode_id, cut.index, cut.schemas,
+            _CutSummary(cut.summary.version.only(new_fids)),
+            [sv for c in cut.immutables
+             if (sv := c.suffix_view(after_seq, cut.mem_seq)) is not None],
+            act if act is not None else MemCache(cut.vnode_id),
+            cut.mem_seq, cut.token)
 
 
 def merge_scan_batches(cached: ScanBatch, delta: ScanBatch):
@@ -379,7 +374,7 @@ def merge_scan_batches(cached: ScanBatch, delta: ScanBatch):
     return merged, (order[group_starts] if pure_append else None)
 
 
-def _field_targets(vnode: VnodeStorage, table: str,
+def _field_targets(vnode: VnodeCut, table: str,
                    field_names: list[str]) -> dict:
     """name → (column_id | None, [name, *prior_names]).
 
@@ -435,7 +430,11 @@ def _resolve_chunk_col(maps, cid, cands):
     return None
 
 
-def scan_vnode(vnode: VnodeStorage, table: str,
+# fresh cuts a scan may take when a compaction unlinked a file under it
+RECUTS = 2
+
+
+def scan_vnode(vnode: VnodeStorage | VnodeCut, table: str,
                series_ids: np.ndarray | None = None,
                time_ranges: TimeRanges | None = None,
                field_names: list[str] | None = None,
@@ -474,6 +473,21 @@ def scan_vnode(vnode: VnodeStorage, table: str,
     batch is only valid for queries with that exact spec — the
     coordinator keys its cache accordingly.
     """
+    if not isinstance(vnode, VnodeCut):
+        # one cut for the whole scan: the file set, the memcaches and the
+        # seq every series is read at. A compaction may replace files of
+        # the cut and unlink them before the scan has opened them: the
+        # state has moved on whole, so cut again (a caller that hands a
+        # cut in holds a token to it, and cuts again itself)
+        for recut in range(RECUTS + 1):
+            try:
+                return scan_vnode(
+                    vnode.cut(), table, series_ids, time_ranges,
+                    field_names, page_filter, page_constraints, n_threads,
+                    upload_hook, decode_hook, compressed_spec)
+            except FileNotFoundError:
+                if recut == RECUTS:
+                    raise
     trs = time_ranges if time_ranges is not None else TimeRanges.all()
     if series_ids is None:
         file_sids = set()
@@ -481,7 +495,7 @@ def scan_vnode(vnode: VnodeStorage, table: str,
             r = vnode.summary.version.reader(fm)
             file_sids.update(int(s) for s in r.series_ids(table))
         series_ids = np.array(
-            sorted(file_sids | _mem_series_ids(vnode, table)),
+            sorted(file_sids | _mem_series_ids(vnode, table, trs)),
             dtype=np.uint64)
     if field_names is None:
         field_names = _discover_fields(vnode, table)
@@ -509,8 +523,8 @@ def scan_vnode(vnode: VnodeStorage, table: str,
         # series instead of materializing the rest of the vnode
         deadline_mod.check_current()
         sid = int(sid)
-        parts = _series_parts(vnode, table, sid, field_names, trs)
-        ts, fields = merge_parts(parts, field_names)
+        ts, fields, _pages = _merged_series(vnode, table, sid, field_names,
+                                            trs)
         if len(ts) == 0:
             continue
         ts_parts.append(ts)
@@ -646,12 +660,20 @@ def _count_cold_pruned(n: int) -> None:
     tiering._count_cold("prune", "pages_pruned", n)
 
 
-def _mem_series_ids(vnode: VnodeStorage, table: str) -> set:
-    """Series ids with unflushed rows for `table` (active + immutables)."""
-    sids = {sid for (t, sid) in vnode.active.series if t == table}
-    for c in vnode.immutables:
-        sids |= {sid for (t, sid) in c.series if t == table}
-    return sids
+def _caches_in_range(vnode: VnodeCut, trs: TimeRanges) -> list[MemCache]:
+    """The cut's memcaches that can hold a row inside `trs`, in ascending
+    priority. A cache whose [min_ts, max_ts] lies outside every range has
+    nothing for this scan: a fleet writing at "now" does not take a query
+    over last week off the page plan."""
+    return [c for c in vnode.caches()
+            if trs.is_all or trs.overlaps(TimeRange(c.min_ts, c.max_ts))]
+
+
+def _mem_series_ids(vnode: VnodeCut, table: str, trs: TimeRanges) -> set:
+    """Series ids of `table` with unflushed rows that may lie inside `trs`
+    (active + immutables): they need the per-series merge."""
+    return {sid for c in _caches_in_range(vnode, trs)
+            for (t, sid) in c.series_keys() if t == table}
 
 
 def _page_constraints(page_filter, field_names) -> dict:
@@ -838,7 +860,7 @@ def _submit_device_page(dev_lane, r, pm, colname, out_off, vt,
     return True
 
 
-def _scan_vnode_native(vnode: VnodeStorage, table: str,
+def _scan_vnode_native(vnode: VnodeCut, table: str,
                        series_ids, trs: TimeRanges,
                        field_names: list[str], constraints: dict,
                        n_threads: int,
@@ -865,7 +887,7 @@ def _scan_vnode_native(vnode: VnodeStorage, table: str,
                     TimeRange(fm.min_ts, fm.max_ts)):
                 continue
             files.append((fm, version.reader(fm)))
-    mem_sids = _mem_series_ids(vnode, table)
+    mem_sids = _mem_series_ids(vnode, table, trs)
     targets = _field_targets(vnode, table, field_names)
 
     # ---------------------------------------------------------------- plan
@@ -875,10 +897,12 @@ def _scan_vnode_native(vnode: VnodeStorage, table: str,
     total = 0
     any_trim = False
     any_pruned = False
+    merged_pages = [0]
     for sid in series_ids:
         sid = int(sid)
         entry = _plan_series(vnode, table, sid, files, mem_sids, trs,
-                             constraints, field_names, targets)
+                             constraints, field_names, targets,
+                             merged_pages)
         if entry is None:
             continue
         if entry[0] == "p":   # series pruned away entirely by constraints
@@ -891,6 +915,12 @@ def _scan_vnode_native(vnode: VnodeStorage, table: str,
             any_pruned = any_pruned or entry[5]
         else:
             total += len(entry[2])
+
+    if dev_lane is not None and merged_pages[0]:
+        # every page scanned is booked once: these went neither to the
+        # device lane nor to the native decoder but through the per-series
+        # merge (memcache rows, tombstones or overlapping chunks)
+        dev_lane.declined("series_merge", merged_pages[0])
 
     # --------------------------------------------- compressed-domain lane
     # lane zero: before any bytes move, pages provably skippable or
@@ -1279,10 +1309,12 @@ def _scan_vnode_native(vnode: VnodeStorage, table: str,
 
 
 def _plan_series(vnode, table, sid, files, mem_sids, trs, constraints,
-                 field_names, targets):
+                 field_names, targets, merged_pages):
     """→ ("n", sid, [(reader, chunk, cols, admitted idx)], n_rows, trim,
     pruned) | ("f", sid, ts, fields) | ("p",) (rows existed but every
-    page was constraint-pruned) | None (no rows). `cols` maps QUERY
+    page was constraint-pruned) | None (no rows). An "f" series was read
+    and merged here, through the per-series path; the TSM pages that took
+    are added to `merged_pages[0]`. `cols` maps QUERY
     column names to each chunk's ColumnMeta (id-resolved — see
     _resolve_chunk_col), so constraint pruning and page decode stay
     correct across RENAME COLUMN."""
@@ -1316,8 +1348,9 @@ def _plan_series(vnode, table, sid, files, mem_sids, trs, constraints,
                 fallback = True   # misaligned pages (defensive)
                 break
     if fallback:
-        parts = _series_parts(vnode, table, sid, field_names, trs)
-        ts, fields = merge_parts(parts, field_names)
+        ts, fields, n_pages = _merged_series(vnode, table, sid, field_names,
+                                             trs)
+        merged_pages[0] += n_pages
         if len(ts) == 0:
             return None
         return ("f", sid, ts, fields)
@@ -1366,7 +1399,7 @@ def _plan_series(vnode, table, sid, files, mem_sids, trs, constraints,
     return ("n", sid, admitted, n_rows, trim, pruned)
 
 
-def _discover_fields(vnode: VnodeStorage, table: str) -> list[str]:
+def _discover_fields(vnode: VnodeCut, table: str) -> list[str]:
     names: set[str] = set()
     schema = vnode.schemas.get(table)
     if schema is not None:
@@ -1377,8 +1410,8 @@ def _discover_fields(vnode: VnodeStorage, table: str) -> list[str]:
         if g:
             for cm in g.chunks.values():
                 names.update(c.name for c in cm.columns)
-    for cache in [vnode.active, *vnode.immutables]:
-        for (t, sid), sd in cache.series.items():
+    for cache in vnode.caches():
+        for (t, _sid), sd in list(cache.series.items()):
             if t == table:
-                names.update(sd.field_chunks.keys())
+                names.update(sd.field_names())
     return sorted(names)

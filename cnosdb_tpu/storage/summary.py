@@ -80,16 +80,23 @@ class Version:
 
     # -- mutation (only via Summary.apply) -------------------------------
     def _apply(self, edit: VersionEdit):
+        # `levels` is replaced, never mutated: a scan that took the list
+        # (VnodeStorage.cut) keeps the file set it took. A dropped file's
+        # reader is forgotten, not closed — a scan may be inside it; the
+        # mmap and the descriptor go with the last reference, and the
+        # unlinked file with them
+        levels = [dict(lvl) for lvl in self.levels]
+        moved = {fm.file_id for fm in edit.add_files}
         for fid in edit.del_files:
-            for lvl in self.levels:
+            for lvl in levels:
                 lvl.pop(fid, None)
-            r = self._readers.pop(fid, None)
-            if r:
-                r.close()
             self._tombstones.pop(fid, None)
+            if fid not in moved:    # a promotion: the same bytes, one
+                self._readers.pop(fid, None)    # level up, stay open
         for fm in edit.add_files:
-            self.levels[fm.level][fm.file_id] = fm
+            levels[fm.level][fm.file_id] = fm
             self.max_file_id = max(self.max_file_id, fm.file_id)
+        self.levels = levels
         if edit.flushed_seq is not None:
             self.flushed_seq = max(self.flushed_seq, edit.flushed_seq)
 
@@ -104,6 +111,13 @@ class Version:
             out.extend(lvl.values())
         return out
 
+    def _current(self, fm: FileMeta) -> FileMeta:
+        """A scan's cut names a file by the level it had at the cut; a
+        promotion since has moved it (same id, same bytes): → the file
+        where it is now."""
+        return next((lvl[fm.file_id] for lvl in self.levels
+                     if fm.file_id in lvl), fm)
+
     def reader(self, fm: FileMeta) -> TsmReader:
         r = self._readers.get(fm.file_id)
         if r is None:
@@ -114,6 +128,7 @@ class Version:
             # tier-transparent
             from . import tiering
 
+            fm = self._current(fm)
             entry = tiering.cold_entry(self.dir, fm.file_id)
             if entry is not None:
                 r = tiering.open_cold_reader(self.file_path(fm), entry)
@@ -136,7 +151,8 @@ class Version:
 
         tb = self._tombstones.get(fm.file_id)
         if tb is None:
-            tb = self._tombstones[fm.file_id] = TsmTombstone(self.file_path(fm))
+            tb = self._tombstones[fm.file_id] = TsmTombstone(
+                self.file_path(self._current(fm)))
         return tb
 
     def level_size(self, level: int) -> int:
@@ -167,13 +183,21 @@ class Summary:
     def apply(self, edit: VersionEdit, sync: bool = True):
         """Durably record an edit, then mutate the live version
         (reference summary.rs:134 apply_version_edit)."""
+        self.record(edit, sync)
+        self.install(edit)
+
+    def record(self, edit: VersionEdit, sync: bool = True):
+        """The durable half of apply(): every recorded edit is installed
+        next (flush does it under the vnode's cut lock, with no I/O)."""
+        if self._edit_count >= 512:
+            self._rewrite()
         self._writer.append(edit.encode())
         if sync:
             self._writer.sync()
-        self.version._apply(edit)
         self._edit_count += 1
-        if self._edit_count >= 512:
-            self._rewrite()
+
+    def install(self, edit: VersionEdit):
+        self.version._apply(edit)
 
     def _rewrite(self):
         """Compact the manifest to a single snapshot edit (reference

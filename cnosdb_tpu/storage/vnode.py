@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 from dataclasses import dataclass
 
 import msgpack
@@ -50,6 +51,97 @@ class ScanToken:
     mem_seq: int
 
 
+class _CutVersion:
+    """The file set of one cut: `levels` is a Version's level list as it
+    stood (Version replaces the list on every edit, never mutates it);
+    readers, tombstones and paths are the live Version's (same caches)."""
+
+    def __init__(self, version, levels: list, opened: dict | None = None):
+        self._version = version
+        self.levels = levels
+        # readers this cut has opened: a compaction that drops a file
+        # forgets its reader, and the scan keeps the one it has
+        self._opened: dict = opened if opened is not None else {}
+
+    def reader(self, fm):
+        r = self._opened.get(fm.file_id)
+        if r is None:
+            r = self._opened[fm.file_id] = self._version.reader(fm)
+        return r
+
+    def tombstone(self, fm):
+        return self._version.tombstone(fm)
+
+    def file_path(self, fm):
+        return self._version.file_path(fm)
+
+    def all_files(self):
+        out = []
+        for lvl in self.levels:
+            out.extend(lvl.values())
+        return out
+
+    def only(self, fids: frozenset) -> "_CutVersion":
+        """→ the same cut holding just the files in `fids`."""
+        return _CutVersion(self._version, [
+            {fid: fm for fid, fm in lvl.items() if fid in fids}
+            for lvl in self.levels], self._opened)
+
+
+class _CutSummary:
+    def __init__(self, version: _CutVersion):
+        self.version = version
+
+
+class VnodeCut:
+    """What one scan reads of a vnode: the TSM file set, the immutable
+    memcaches, the active memcache and the last applied WAL seq, all as of
+    ONE instant (`VnodeStorage.cut()`), so a write, a switch or an inline
+    flush beside the scan moves nothing under it. The memcaches are the
+    live objects: a reader takes from them only whole batches with
+    seq <= `mem_seq` (`SeriesData.chunks`). Shaped like the vnode the scan
+    functions were written against (`summary.version`, `index`, `schemas`,
+    `active`, `immutables`)."""
+
+    __slots__ = ("vnode_id", "index", "schemas", "summary", "immutables",
+                 "active", "mem_seq", "token")
+
+    def __init__(self, vnode_id, index, schemas, summary, immutables,
+                 active, mem_seq, token):
+        self.vnode_id = vnode_id
+        self.index = index
+        self.schemas = schemas
+        self.summary = summary
+        self.immutables = immutables
+        self.active = active
+        self.mem_seq = mem_seq
+        self.token = token
+
+    def cut(self) -> "VnodeCut":
+        return self
+
+    def caches(self) -> list[MemCache]:
+        """Memcaches in ascending priority: immutables old→new, active."""
+        return [*self.immutables, self.active]
+
+
+# process totals of the write path, folded into /metrics at scrape time
+# (cnosdb_wal_bytes_total, cnosdb_memcache_flush_total,
+# cnosdb_memcache_flush_rows_total)
+_INGEST_LOCK = lockwatch.Lock("vnode.ingest_counters")
+_INGEST = {"wal_bytes": 0, "memcache_flush": 0, "memcache_flush_rows": 0}
+
+
+def _count_ingest(name: str, n: int) -> None:
+    with _INGEST_LOCK:
+        _INGEST[name] += n
+
+
+def ingest_counters_snapshot() -> dict[str, int]:
+    with _INGEST_LOCK:
+        return dict(_INGEST)
+
+
 class VnodeStorage:
     def __init__(self, vnode_id: int, dir_path: str,
                  schemas: dict[str, TskvTableSchema] | None = None,
@@ -62,6 +154,13 @@ class VnodeStorage:
         self.schemas = schemas if schemas is not None else {}
         self.memcache_bytes = memcache_bytes
         self.lock = lockwatch.RLock(f"vnode.{vnode_id}")
+        # what a scan cuts — (file set, memcaches, applied seq, versions)
+        # — changes only under this lock, in steps of a few assignments:
+        # a batch's publication, the switch, a flushed cache leaving as
+        # its file enters. A writer holds `lock` for a whole apply or
+        # flush; a reader takes only this one (cut()) and never waits
+        # for either. Order: lock, then _cut_lock; never the reverse.
+        self._cut_lock = lockwatch.Lock(f"vnode.{vnode_id}.cut")
         self.summary = Summary(dir_path)
         self.index = TSIndex(os.path.join(dir_path, "index"))
         self.wal = Wal(os.path.join(dir_path, "wal"), sync_on_append=wal_sync)
@@ -91,23 +190,35 @@ class VnodeStorage:
         self.applied_seq = self.summary.version.flushed_seq
         self._replay_wal()
 
+    def cut(self) -> VnodeCut:
+        """→ the consistent cut one scan reads (VnodeCut). References are
+        copied under the cut lock; materializing happens outside it."""
+        t0 = time.perf_counter()
+        with self._cut_lock:
+            version = self.summary.version
+            levels = version.levels
+            immutables = list(self.immutables)
+            active = self.active
+            # applied_seq, NOT wal.next_seq-1: a raft-replicated entry
+            # sits in the WAL before it commits/applies. A token taken
+            # in that window must not claim the entry's seq — the
+            # delta path (DeltaVnodeView, seq > token.mem_seq) would
+            # then skip its rows forever once they apply.
+            mem_seq = self.applied_seq
+            dv, xv = self.data_version, self.destructive_version
+            index, schemas = self.index, self.schemas
+        stages.book("memcache_wait_ms", t0)
+        token = ScanToken(dv, xv, frozenset(
+            fid for lvl in levels for fid in lvl), mem_seq)
+        return VnodeCut(self.vnode_id, index, schemas,
+                        _CutSummary(_CutVersion(version, levels)),
+                        immutables, active, mem_seq, token)
+
     def scan_token(self) -> ScanToken:
-        """Capture the snapshot token for a scan ABOUT to run. Taken under
-        the vnode lock so the file set and seq are mutually consistent; a
-        write racing the subsequent (unlocked) decode only makes the token
-        conservative — its rows re-decode on the next delta and dedup."""
-        with self.lock:
-            return ScanToken(
-                self.data_version,
-                self.destructive_version,
-                frozenset(fm.file_id
-                          for fm in self.summary.version.all_files()),
-                # applied_seq, NOT wal.next_seq-1: a raft-replicated entry
-                # sits in the WAL before it commits/applies. A token taken
-                # in that window must not claim the entry's seq — the
-                # delta path (DeltaVnodeView, seq > token.mem_seq) would
-                # then skip its rows forever once they apply.
-                self.applied_seq)
+        """The snapshot token of the state as it stands (serving-plane
+        invalidation, backup). A scan takes its token from the cut it
+        reads: `cut().token`."""
+        return self.cut().token
 
     # ------------------------------------------------------------------ boot
     def _replay_wal(self):
@@ -118,17 +229,22 @@ class VnodeStorage:
     # ------------------------------------------------------------------ write
     def write(self, batch: WriteBatch, sync: bool = False) -> int:
         """Log + apply one write batch; → assigned WAL seq."""
+        t0 = time.perf_counter()
         with self.lock:
-            # stamp schema version + column ids into the WAL payload so a
-            # post-crash replay can re-key fields by id across RENAME/DROP
-            batch.stamp_schema(self.schemas)
-            data = batch.encode()
-            seq = self.wal.append(WalEntryType.WRITE, data)
-            if sync:
-                self.wal.sync()
-            self._apply_write(batch, seq)
-            if seq > self.applied_seq:
-                self.applied_seq = seq
+            stages.book("write.lock_wait_ms", t0)
+            with stages.stage("write.wal_ms"):
+                # stamp schema version + column ids into the WAL payload so
+                # a post-crash replay can re-key fields by id across
+                # RENAME/DROP
+                batch.stamp_schema(self.schemas)
+                data = batch.encode()
+                seq = self.wal.append(WalEntryType.WRITE, data)
+                if sync:
+                    self.wal.sync()
+            _count_ingest("wal_bytes", len(data))
+            with stages.stage("write.apply_ms"):
+                self._apply_write(batch, seq)
+            self._flush_if_full()
             return seq
 
     def apply_entry(self, entry_type: int, data: bytes, seq: int):
@@ -138,13 +254,16 @@ class VnodeStorage:
             self._apply_entry(entry_type, data, seq, logged=True)
 
     def _apply_entry(self, entry_type: int, data: bytes, seq: int, logged: bool):
+        if entry_type == WalEntryType.WRITE:
+            # publishes its own seq, once its rows are all in
+            self._apply_write(WriteBatch.decode(data), seq)
+            self._flush_if_full()
+            return
         # advance even for no-op entries (blank/membership, empty deletes):
         # the entry's full effect is reflected once this call returns
         if seq > self.applied_seq:
             self.applied_seq = seq
-        if entry_type == WalEntryType.WRITE:
-            self._apply_write(WriteBatch.decode(data), seq)
-        elif entry_type == WalEntryType.DELETE_TABLE:
+        if entry_type == WalEntryType.DELETE_TABLE:
             obj = msgpack.unpackb(data, raw=False)
             self._apply_drop_table(obj["table"])
         elif entry_type == WalEntryType.DELETE_SERIES:
@@ -173,7 +292,6 @@ class VnodeStorage:
         # RAFT_BLANK/MEMBERSHIP: no storage effect
 
     def _apply_write(self, batch: WriteBatch, seq: int):
-        self.data_version += 1
         for table, series_list in batch.tables.items():
             # the batch's schema stamp vs the live schema: replayed entries
             # written before a RENAME/DROP re-key their fields by column id
@@ -189,33 +307,54 @@ class VnodeStorage:
                             fields[tgt] = v
                     sr = SeriesRows(sr.key, sr.timestamps, fields)
                 self.active.write_series(table, sid, sr, seq)
+        # the batch becomes readable here, whole: a cut takes memcache
+        # rows up to its applied_seq, and the seq moves before the version
+        # a cached batch is compared by
+        with self._cut_lock:
+            if seq > self.applied_seq:
+                self.applied_seq = seq
+            self.data_version += 1
+
+    def _flush_if_full(self):
+        """The inline flush: the writer whose batch filled the cache
+        flushes it, under the vnode lock (that IS the backpressure)."""
         if self.active.should_flush():
-            self.flush()
+            with stages.stage("write.flush_ms"):
+                self.flush()
 
     # ------------------------------------------------------------------ flush
     def switch_to_immutable(self):
         with self.lock:
             if self.active.is_empty:
                 return
-            self.active.mark_immutable()
-            self.immutables.append(self.active)
-            self.active = MemCache(self.vnode_id, self.memcache_bytes)
+            with self._cut_lock:
+                self.active.mark_immutable()
+                self.immutables.append(self.active)
+                self.active = MemCache(self.vnode_id, self.memcache_bytes)
 
     def flush(self, sync: bool = True):
         """Rotate active cache and persist ALL immutables to L0 files."""
         flushed = False
         with self.lock:
             self.switch_to_immutable()
-            if self.immutables:
-                self.data_version += 1
+            for cache in list(self.immutables):
                 flushed = True
-            for cache in self.immutables:
                 fid = self.summary.next_file_id()
                 path = os.path.join(self.dir, "delta", f"_{fid:06d}.tsm")
                 edit = flush_memcache(cache, fid, path, self.schemas)
                 if edit is not None:
-                    self.summary.apply(edit, sync=sync)
-            self.immutables.clear()
+                    self.summary.record(edit, sync=sync)
+                    _count_ingest("memcache_flush", 1)
+                    _count_ingest("memcache_flush_rows", sum(
+                        sd.n_rows for sd in cache.series.values()))
+                # the file enters and its cache leaves in one step of the
+                # cut: a scan reads the rows from one of them, never both
+                # and never neither
+                with self._cut_lock:
+                    if edit is not None:
+                        self.summary.install(edit)
+                    self.immutables.remove(cache)
+                    self.data_version += 1
             self.index.sync()
             self.wal.sync()
             self.wal.purge_to(self.summary.version.flushed_seq + 1)
@@ -239,8 +378,8 @@ class VnodeStorage:
             self.destructive_version += 1
             for cache in [self.active, *self.immutables]:
                 for (t, _sid), sd in cache.series.items():
-                    if t == table and old in sd.field_chunks:
-                        sd.field_chunks[new] = sd.field_chunks.pop(old)
+                    if t == table:
+                        sd.rename_field(old, new)
 
     def drop_mem_field(self, table: str, name: str):
         """ALTER ... DROP COLUMN: purge buffered rows of the dropped
@@ -252,7 +391,7 @@ class VnodeStorage:
             for cache in [self.active, *self.immutables]:
                 for (t, _sid), sd in cache.series.items():
                     if t == table:
-                        sd.field_chunks.pop(name, None)
+                        sd.drop_field(name)
 
     # ------------------------------------------------------------------ compact
     def _compaction_exclude(self) -> frozenset:
@@ -534,12 +673,14 @@ class VnodeStorage:
                 os.makedirs(os.path.dirname(path), exist_ok=True)
                 with open(path, "wb") as f:  # lint: disable=lock-blocking (snapshot install must be atomic vs readers; consistency over latency)
                     f.write(raw)
-            self.summary = Summary(self.dir)
-            self.index = TSIndex(os.path.join(self.dir, "index"))
-            self.active = MemCache(self.vnode_id, self.memcache_bytes)
-            self.immutables = []
-            self.data_version += 1
-            self.destructive_version += 1
+            summary = Summary(self.dir)
+            index = TSIndex(os.path.join(self.dir, "index"))
+            with self._cut_lock:
+                self.summary, self.index = summary, index
+                self.active = MemCache(self.vnode_id, self.memcache_bytes)
+                self.immutables = []
+                self.data_version += 1
+                self.destructive_version += 1
 
     def checksum(self) -> str:
         """Content checksum of every live row, independent of physical

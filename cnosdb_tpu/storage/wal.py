@@ -190,6 +190,10 @@ class Wal:
         self._writer.append(e.encode())
         if self.sync_on_append:
             self._writer.sync()
+        else:
+            # the entry leaves the process before its write is
+            # acknowledged: a kill -9 after the 200 loses nothing
+            self._writer.flush()
         self._next_seq = seq + 1
         if self._writer.size >= self.max_segment_size:
             self._roll()

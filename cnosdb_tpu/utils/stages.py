@@ -56,6 +56,28 @@ STAGE_CATALOG: dict[str, str] = {
     "delta_rows": "rows decoded by delta scans (a full rescan's worth "
                   "means tokens are being invalidated)",
     "decode_ms": "TSM read+decode (cache-miss and delta scans)",
+    "memcache_ms": "the scan's memcache share, inside decode_ms: "
+                   "materialize() of each touched series' unflushed "
+                   "batches up to the cut, and the merge of those rows "
+                   "into the series (summed over scan threads)",
+    "memcache_wait_ms": "waiting for the vnode's cut lock — held by a "
+                        "writer for a batch's publication, the switch or "
+                        "a flushed file's entry, never for an apply",
+    "memcache.series": "series the scan read through a memcache (each "
+                       "takes the per-series path, not the page plan)",
+    "memcache.rows": "rows the scan materialized from memcaches, before "
+                     "the time predicate",
+    "write.parse_ms": "a write request: parse_lines of the body (on a "
+                      "thread of the write pool, not the event loop's)",
+    "write.lock_wait_ms": "a write request: waiting for the vnode lock "
+                          "behind other writers (and an inline flush)",
+    "write.wal_ms": "a write request: schema stamp + encode + WAL append "
+                    "(+ sync where set), under the vnode lock",
+    "write.apply_ms": "a write request: series ids + memcache apply + "
+                      "the batch's publication, less any flush",
+    "write.flush_ms": "a write request: the inline flush its batch "
+                      "triggered by filling the memcache (booked only "
+                      "when one ran)",
     "device_decode_ms": "batched device codec kernels within a scan "
                         "(the accelerator half of decode_ms)",
     "device_decode_engagements": "pages decoded by the device-decode "
@@ -231,7 +253,8 @@ class QueryProfile:
 
     __slots__ = ("qid", "sql", "trace_id", "node_id", "started_at",
                  "wall_ms", "error", "ms", "counts", "device",
-                 "subprofiles", "traced", "intervals", "dropped", "_lock")
+                 "subprofiles", "traced", "annotate", "intervals",
+                 "dropped", "_lock")
 
     def __init__(self, qid: str | None = None, node_id=None,
                  sql: str | None = None):
@@ -242,6 +265,11 @@ class QueryProfile:
         self.started_at = time.time()
         # one timeline per request: set at ingress (module docstring)
         self.traced = False
+        # a write request's profile: its stages are sums (one histogram
+        # observation a batch) and, while a profiler trace runs, a
+        # `TraceAnnotation("cnosdb.<stage>")` — no collector span, no
+        # interval (a TraceAnnotation outside a trace records nothing)
+        self.annotate = False
         # (start, end) of each stage on the perf_counter clock, whatever
         # thread it ran on; emptied once finish() has read them
         self.intervals: list[tuple] = []
@@ -470,6 +498,15 @@ def _child_span(prof: "QueryProfile", name: str):
         else prof.trace_id)
 
 
+def _annotation(prof: "QueryProfile", name: str):
+    """→ the profiler annotation of one stage, None where jax is not
+    loaded (never import it from here: a text-only query stays jax-free)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    return jax.profiler.TraceAnnotation("cnosdb." + name, qid=str(prof.qid))
+
+
 class _TracedStage:
     """One stage of a traced request: the sum, the collector span, the
     profiler annotation, and the (start, end) pair for `untraced_ms`."""
@@ -482,12 +519,8 @@ class _TracedStage:
     def __enter__(self):
         self.span = _child_span(self.prof, self.name)
         self.span.__enter__()
-        # never import jax from here: a text-only query stays jax-free
-        jax = sys.modules.get("jax")
-        self.ann = None
-        if jax is not None:
-            self.ann = jax.profiler.TraceAnnotation(
-                "cnosdb." + self.name, qid=str(self.prof.qid))
+        self.ann = _annotation(self.prof, self.name)
+        if self.ann is not None:
             self.ann.__enter__()
         self.t0 = time.perf_counter()
         return self
@@ -512,9 +545,14 @@ def stage(name: str):
         with _TracedStage(prof, name):
             yield
         return
+    ann = _annotation(prof, name) if prof.annotate else None
     t0 = time.perf_counter()
     try:
-        yield
+        if ann is None:
+            yield
+        else:
+            with ann:
+                yield
     finally:
         prof.add_ms(name, (time.perf_counter() - t0) * 1e3)
 

@@ -820,6 +820,118 @@ def test_new_metrics_series_at_zero_before_first_query(http):
     assert "queue_wait_ms_avg" not in stats
 
 
+def test_write_stage_series_at_zero_then_one_count_a_stage_a_batch(http):
+    """`cnosdb_write_stage_ms{stage}` and the three write-path counters
+    are on a fresh /metrics at 0 (a reader of a window's rise needs both
+    ends); each acknowledged batch is one observation of every stage it
+    ran — `flush` only for the batch whose inline flush ran."""
+    def scrape():
+        _t, samples = _check_prometheus(http.request("GET", "/metrics")[1])
+        return {(n, l): v for n, l, v in samples}
+
+    from cnosdb_tpu.server.http import WRITE_STAGES
+
+    assert WRITE_STAGES == ("parse", "lock_wait", "wal", "apply", "flush")
+    got = scrape()
+    for st in WRITE_STAGES:
+        assert got[("cnosdb_write_stage_ms_count", f'{{stage="{st}"}}')] == 0
+        assert got[("cnosdb_write_stage_ms_sum", f'{{stage="{st}"}}')] == 0
+    # process totals (a fresh process reads 0: the served-path test of
+    # tests/test_ingest_under_queries.py takes their rise from one)
+    base = {name: got[(name, "")] for name in (
+        "cnosdb_wal_bytes_total", "cnosdb_memcache_flush_total",
+        "cnosdb_memcache_flush_rows_total")}
+
+    def rise(name):
+        return got[(name, "")] - base[name]
+
+    _seed_http(http)
+    _seed_http(http)
+    got = scrape()
+    for st in ("parse", "lock_wait", "wal", "apply"):
+        assert got[("cnosdb_write_stage_ms_count",
+                    f'{{stage="{st}"}}')] == 2, st
+    assert got[("cnosdb_write_stage_ms_count", '{stage="flush"}')] == 0
+    for st in ("parse", "wal", "apply"):
+        assert got[("cnosdb_write_stage_ms_sum", f'{{stage="{st}"}}')] > 0
+    assert rise("cnosdb_wal_bytes_total") > 2 * 40 * 8
+    assert rise("cnosdb_memcache_flush_total") == 0
+    # a flush is counted with its rows wherever it was asked for; only an
+    # inline one is a stage of a write
+    status, body, _ = http.request("POST", "/api/v1/sql?db=public", "FLUSH")
+    assert status == 200, body
+    got = scrape()
+    flushed = rise("cnosdb_memcache_flush_total")    # usage_schema's too
+    assert flushed >= 1
+    assert rise("cnosdb_memcache_flush_rows_total") >= 40
+    assert got[("cnosdb_write_stage_ms_count", '{stage="flush"}')] == 0
+    # the inline flush: a cache that one batch fills
+    http.server.coord.engine.vnodes[("cnosdb.public", 1)].active.max_bytes = 1
+    _seed_http(http)
+    got = scrape()
+    assert got[("cnosdb_write_stage_ms_count", '{stage="flush"}')] == 1
+    assert got[("cnosdb_write_stage_ms_count", '{stage="apply"}')] == 3
+    assert rise("cnosdb_memcache_flush_total") == flushed + 1
+    for key in ("write.parse_ms", "write.lock_wait_ms", "write.wal_ms",
+                "write.apply_ms", "write.flush_ms"):
+        assert key in stages.STAGE_CATALOG
+
+
+def test_traced_request_over_unflushed_rows_holds_the_memcache_stages(http):
+    """A scan that finds unflushed rows books its memcache share: spans
+    `memcache_ms` inside `decode_ms` and `memcache_wait_ms` under
+    `http:sql`, counts `memcache.series` / `memcache.rows` — in the
+    summary header, /debug/profile, EXPLAIN ANALYZE and the span tree."""
+    _seed_flushed_ints(http, hosts=3, steps=40)
+    lines = "\n".join(
+        f"cpu,host=h{i} usage={t}i {1672531200000000000 + t * 10 * 10**9}"
+        for i in range(2) for t in range(40, 50))
+    status, body, _ = http.request("POST", "/api/v1/write?db=public", lines)
+    assert status == 200, body
+    tid = "feedc0de0035"
+    status, body, hdrs = http.request(
+        "POST", "/api/v1/sql?db=public", _BUCKETED,
+        headers={"X-CnosDB-Profile": "1", "cnos-trace-id": tid})
+    assert status == 200, body
+    st = json.loads(hdrs["X-CnosDB-Profile-Summary"])["stages"]
+    # two of the three series have unflushed rows, ten each
+    assert st["memcache.series"] == 2 and st["memcache.rows"] == 20
+    assert 0 < st["memcache_ms"] <= st["decode_ms"]
+    assert st["memcache_wait_ms"] >= 0
+    spans = _trace_spans(http, tid)
+    by_id = {s["span_id"]: s for s in spans}
+    mem = [s for s in spans if s["name"] == "memcache_ms"]
+    assert len(mem) >= 2
+    for s in mem:
+        chain = _ancestors(s, by_id)
+        assert chain[0] == "decode_ms" and chain[-1] == "http:sql", chain
+    wait = [s for s in spans if s["name"] == "memcache_wait_ms"]
+    assert wait and all(_ancestors(s, by_id)[-1] == "http:sql"
+                        for s in wait)
+    qid = json.loads(hdrs["X-CnosDB-Profile-Summary"])["qid"]
+    full = json.loads(http.request("GET", f"/debug/profile?qid={qid}")[1])
+    assert full["counts"]["memcache.series"] == 2
+    assert full["ms"]["memcache_ms"] > 0 and "memcache_wait_ms" in full["ms"]
+    # (another time range: the first answer's scan is cached)
+    status, body, _ = http.request(
+        "POST", "/api/v1/sql?db=public", "EXPLAIN ANALYZE " + _BUCKETED.replace(
+            "FROM cpu", "FROM cpu WHERE time >= '2023-01-01T00:00:00Z'"))
+    assert status == 200, body
+    for key in ("memcache_ms", "memcache_wait_ms", "memcache.series",
+                "memcache.rows"):
+        assert key in body, (key, body)
+        assert key in stages.STAGE_CATALOG
+    # rows all in files: a scan books the wait for its cut, nothing else
+    status, body, _ = http.request("POST", "/api/v1/sql?db=public", "FLUSH")
+    assert status == 200, body
+    status, _b, hdrs = http.request(
+        "POST", "/api/v1/sql?db=public", _BUCKETED,
+        headers={"X-CnosDB-Profile": "1"})
+    st = json.loads(hdrs["X-CnosDB-Profile-Summary"])["stages"]
+    assert "memcache_ms" not in st and "memcache.series" not in st
+    assert "memcache_wait_ms" in st
+
+
 def test_render_error_is_not_an_sql_error(http, monkeypatch):
     """Rendering runs outside the CnosError handler: an error raised there
     propagates (500) instead of counting as a failed query, and the root
