@@ -467,43 +467,31 @@ class ScanLane:
     def has_masks(self) -> bool:
         return bool(self.mask_pages)
 
-    def filter_plan(self, plan: list) -> list:
-        out = []
-        for entry in plan:
-            if entry[0] != "n":
-                out.append(entry)
-                continue
-            _tag, sid, admitted, n_rows, trim, pruned = entry
-            new_chunks = []
-            removed = 0
-            for (r, cm, cols, idx) in admitted:
-                keep_idx = []
-                for i in idx:
-                    removed += self._classify(sid, r, cm, cols, i, keep_idx)
-                if keep_idx:
-                    new_chunks.append((r, cm, cols, keep_idx))
-            n2 = n_rows - removed
-            if n2 > 0:
-                out.append(("n", sid, new_chunks, n2, trim, pruned))
-        return out
+    def filter_pages(self, inside: np.ndarray, page_at) -> np.ndarray:
+        """Classify the scan's planned pages → bool over them, False
+        where a page leaves the plan (skipped or answered). `inside[k]`:
+        page k lies inside one of the query's time ranges; `page_at(k)`
+        → (sid, reader, ChunkMeta, {query column: ColumnMeta}, the
+        page's position in the chunk)."""
+        keep = np.ones(len(inside), dtype=bool)
+        # rows outside the query's time ranges can't be answered away:
+        # the page must materialize so assembly's trim drops them
+        n_trim = len(inside) - int(np.count_nonzero(inside))
+        if n_trim:
+            count_outcome("mat", "trim", n_trim)
+        for k in np.flatnonzero(inside).tolist():
+            keep[k] = not self._classify(*page_at(k))
+        return keep
 
-    def _classify(self, sid, r, cm, cols, i, keep_idx) -> int:
-        """Classify page i; append to keep_idx when it must materialize.
-        → rows removed from the series plan (0 when kept)."""
+    def _classify(self, sid, r, cm, cols, i) -> int:
+        """Classify page i of a chunk, one inside a time range → the
+        rows it takes out of the plan (0: it must materialize)."""
         spec = self.spec
         tp = cm.time_pages[i]
 
         def _mat(reason):
             count_outcome("mat", reason)
-            keep_idx.append(i)
             return 0
-
-        # rows outside the query's time ranges can't be answered away:
-        # the page must materialize so assembly's trim drops them
-        if not self.trs.is_all and not any(
-                tr.min_ts <= tp.min_ts and tp.max_ts <= tr.max_ts
-                for tr in self.trs.ranges):
-            return _mat("trim")
 
         # ---- predicate tri-state over the full conjunction
         verdict = _TRUE
@@ -539,12 +527,11 @@ class ScanLane:
                 self._mask_keep[id(cm)] = cm
                 self.mask_pages.setdefault((id(cm), i), []).extend(
                     mask_builders)
-                keep_idx.append(i)
                 return 0
             return _mat("pred_mixed")
 
         # ---- all conjuncts TRUE: try to answer every aggregate
-        return self._answer(sid, r, cm, cols, i, tp, keep_idx, _mat)
+        return self._answer(sid, r, cm, cols, i, tp, _mat)
 
     def _col_mixed(self, r, cols, i, colname, cons) -> bool:
         pm = cols[colname].pages[i]
@@ -621,7 +608,7 @@ class ScanLane:
         return verdict
 
     # -- aggregate answering ---------------------------------------------
-    def _answer(self, sid, r, cm, cols, i, tp, keep_idx, _mat) -> int:
+    def _answer(self, sid, r, cm, cols, i, tp, _mat) -> int:
         spec = self.spec
         straddle = False
         bts = None

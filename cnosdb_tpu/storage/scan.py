@@ -576,15 +576,29 @@ def scan_vnode(vnode: VnodeStorage | VnodeCut, table: str,
 # Most scans hit fully-compacted vnodes: per series, a handful of chunks
 # whose time ranges are provably disjoint FROM METADATA ALONE (no decode
 # needed to know the merge is a concatenation). For those, the whole
-# vnode's page set is planned up front — output row offsets computed from
-# chunk metadata — and decoded by native/pagedec.cpp in one GIL-free
-# multithreaded call per (file, column), writing straight into the final
-# concatenated arrays. Series that need real merging (memcache overlap,
-# tombstones, overlapping L0 chunks) fall back to the per-series Python
-# path and splice into their reserved span. This replaces the role of the
-# reference's reader tree (tskv/src/reader/iterator.rs:94-121) for the
-# dominant compacted-read shape, with page-statistics predicate pruning
-# (reference column_group/statistics.rs) applied before any byte decodes.
+# vnode's page set is planned up front, a FILE at a time: every reader
+# keeps a columnar copy of a table's chunk and page metadata
+# (tsm.PageIndex, built by the first scan that touches it; the file never
+# changes), and from it the asked series' chunk rows (one searchsorted),
+# time admission and the "inside one range, so no trim" test (array
+# comparisons against the ranges), the output row offsets (a cumulative
+# sum in the batch's order: series as asked, a series' chunks by min_ts,
+# pages in file order) and the (n_pages, 6) descriptors of
+# native/pagedec.cpp's one GIL-free multithreaded call per (file, column)
+# are array operations — no Python a series or a page. The query's columns
+# are resolved against a file once (by id, then by name lineage). What
+# metadata alone cannot plan keeps its own path, decided by what the scan
+# sees in its input: a series with unflushed rows in range, a matching
+# tombstone, chunks overlapping across files or pages not aligned is read
+# and merged one at a time (`_merged_series`) and spliced into its
+# reserved span; a (file, column) with a page the native decoder cannot
+# take unasked (a cold reader, a string, another type or encoding, a
+# device-first lane) is routed a page at a time; page constraints and the
+# compressed-domain lane's verdicts run a page at a time over the
+# time-admitted pages. This replaces the role of the reference's reader
+# tree (tskv/src/reader/iterator.rs:94-121) for the dominant
+# compacted-read shape, with page-statistics predicate pruning (reference
+# column_group/statistics.rs) applied before any byte decodes.
 
 _NATIVE_NUMERIC = {
     int(ValueType.FLOAT): 1,      # pagedec kind: gorilla f64
@@ -860,6 +874,178 @@ def _submit_device_page(dev_lane, r, pm, colname, out_off, vt,
     return True
 
 
+class _PlanFile:
+    """One TSM file of a scan: its reader, the table's PageIndex, where
+    the asked series stand in it and the query's columns resolved against
+    it (once a file, not once a chunk) — then, once the plan is made, the
+    file's own pages of it."""
+
+    __slots__ = ("meta", "reader", "index", "cold", "series", "rows",
+                 "cols", "page", "chunk", "off", "_chunk_cols")
+
+    def __init__(self, meta, reader, index, series, rows, cols):
+        self.meta = meta
+        self.reader = reader
+        self.index = index
+        self.cold = reader.is_cold
+        self.series = series    # positions in the asked series ids …
+        self.rows = rows        # … and those series' chunk rows
+        self.cols = cols        # query column → ColumnIndex | None
+        self.page = None        # the file's planned pages: rows of the
+        self.chunk = None       # index's page arrays, their chunk rows
+        self.off = None         # and their first rows in the batch
+        self._chunk_cols: dict[int, dict] = {}
+
+    def chunk_cols(self, c: int) -> dict:
+        """→ query column name → ColumnMeta of chunk row `c` (what the
+        per-page paths read: constraints, the compressed-domain lane,
+        the cold prefetch)."""
+        cols = self._chunk_cols.get(c)
+        if cols is None:
+            cols = self._chunk_cols[c] = {
+                name: col.metas[c] for name, col in self.cols.items()
+                if col is not None and col.present[c]}
+        return cols
+
+
+class _Pages:
+    """A scan's page plan: an array row a time page, in the order the
+    batch has — series in `series_ids` order, a series' chunks by min_ts,
+    a chunk's pages in file order."""
+
+    __slots__ = ("file", "page", "chunk", "series", "n_rows", "inside")
+
+    def __init__(self, file, page, chunk, series, n_rows, inside):
+        self.file = file        # position in the scan's _PlanFile list
+        self.page = page        # row of that file's index page arrays
+        self.chunk = chunk      # row of its chunk arrays
+        self.series = series    # position in the asked series ids
+        self.n_rows = n_rows
+        # the page lies inside ONE of the time ranges: all its rows pass
+        # and it needs no row-level trim (anything else trims)
+        self.inside = inside
+
+    def __len__(self):
+        return len(self.page)
+
+    def take(self, keep: np.ndarray) -> None:
+        for k in self.__slots__:
+            setattr(self, k, getattr(self, k)[keep])
+
+    def at(self, k: int, pfiles: list):
+        """→ (file, chunk row, page's position in the chunk) of row k."""
+        f = pfiles[int(self.file[k])]
+        c = int(self.chunk[k])
+        return f, c, int(self.page[k] - f.index.page_lo[c])
+
+
+def _series_to_merge(vnode: VnodeCut, table: str, sids: np.ndarray,
+                     pfiles: list, trs: TimeRanges) -> np.ndarray:
+    """→ bool over `sids`: the series whose metadata alone does NOT prove
+    the merge of their parts a concatenation — unflushed rows that may lie
+    inside `trs`, a tombstone of one of their files that matches them,
+    chunks that overlap in time across files, a chunk whose pages are not
+    aligned. They take the per-series path (`_merged_series`)."""
+    merge = np.zeros(len(sids), dtype=bool)
+    mem = _mem_series_ids(vnode, table, trs)
+    if mem:
+        merge |= np.isin(sids, np.fromiter(mem, dtype=np.uint64,
+                                           count=len(mem)))
+    version = vnode.summary.version
+    for f in pfiles:
+        tb = version.tombstone(f.meta)
+        if not tb.is_empty:
+            for e in tb.entries:
+                if e.table is None or e.table == table:
+                    merge[f.series if e.series_id is None else f.series[
+                        sids[f.series] == np.uint64(e.series_id)]] = True
+        if not f.index.all_aligned:
+            merge[f.series[~f.index.aligned[f.rows]]] = True
+    spans = sorted((f.meta.min_ts, f.meta.max_ts) for f in pfiles)
+    if any(a[1] >= b[0] for a, b in zip(spans, spans[1:])):
+        # (files that do not meet in time hold no two chunks that do)
+        series = np.concatenate([f.series for f in pfiles])
+        lo = np.concatenate([f.index.min_ts[f.rows] for f in pfiles])
+        hi = np.concatenate([f.index.max_ts[f.rows] for f in pfiles])
+        order = np.lexsort((lo, series))
+        series, lo, hi = series[order], lo[order], hi[order]
+        clash = (series[1:] == series[:-1]) & (hi[:-1] >= lo[1:])
+        merge[series[1:][clash]] = True
+    return merge
+
+
+def _plan_pages(pfiles: list, merge: np.ndarray | None,
+                trs: TimeRanges) -> _Pages:
+    """The time-admitted pages of every series the index plans (all but
+    those under `merge`), from array operations a file."""
+    from .tsm import ranges
+
+    parts = []
+    for fi, f in enumerate(pfiles):
+        index = f.index
+        series, rows = f.series, f.rows
+        if merge is not None:
+            keep = ~merge[series]
+            series, rows = series[keep], rows[keep]
+        if index.single_pages:      # a chunk's row is its page's row
+            page = rows
+        else:
+            lo = index.page_lo[rows]
+            n = index.page_lo[rows + 1] - lo
+            page = ranges(lo, n)
+            of = np.repeat(np.arange(len(rows)), n)   # a page's chunk
+            series, rows = series[of], rows[of]
+        if trs.is_all:
+            inside = np.ones(len(page), dtype=bool)
+        else:
+            t_min, t_max = index.t_min[page], index.t_max[page]
+            admitted = inside = None
+            for r in trs.ranges:
+                meets = (t_min <= r.max_ts) & (t_max >= r.min_ts)
+                within = (t_min >= r.min_ts) & (t_max <= r.max_ts)
+                admitted = meets if admitted is None else admitted | meets
+                inside = within if inside is None else inside | within
+            if admitted is None:
+                admitted = inside = np.zeros(len(page), dtype=bool)
+            if not admitted.all():
+                if f.cold:
+                    _count_cold_pruned(len(page) - int(admitted.sum()))
+                page, series, rows, inside = page[admitted], \
+                    series[admitted], rows[admitted], inside[admitted]
+        parts.append((np.full(len(page), fi, dtype=np.int64), page, rows,
+                      series, index.time[page, 3], inside,
+                      index.min_ts[rows]))
+    if not parts:
+        none = np.empty(0, dtype=np.int64)
+        return _Pages(none, none, none, none, none, np.empty(0, dtype=bool))
+    if len(parts) == 1:
+        return _Pages(*parts[0][:6])
+    cols = [np.concatenate(c) for c in zip(*parts)]
+    # pages of one chunk are admitted in file order; chunks of one series
+    # that the index plans never share a min_ts (they do not overlap)
+    order = np.lexsort((cols[1], cols[6], cols[3]))
+    return _Pages(*(c[order] for c in cols[:6]))
+
+
+def _constrain_pages(pages: _Pages, pfiles: list, constraints: dict) -> bool:
+    """Drop the planned pages whose statistics prove no row can satisfy a
+    constrained conjunct → whether any was dropped. Runs a page at a time
+    over the time-admitted pages only."""
+    keep = np.ones(len(pages), dtype=bool)
+    for k in range(len(pages)):
+        f, c, i = pages.at(k, pfiles)
+        keep[k] = _page_admits(f.chunk_cols(c), i, constraints)
+    if keep.all():
+        return False
+    for fi, f in enumerate(pfiles):
+        if f.cold:
+            n = int((~keep & (pages.file == fi)).sum())
+            if n:
+                _count_cold_pruned(n)
+    pages.take(keep)
+    return True
+
+
 def _scan_vnode_native(vnode: VnodeCut, table: str,
                        series_ids, trs: TimeRanges,
                        field_names: list[str], constraints: dict,
@@ -878,49 +1064,53 @@ def _scan_vnode_native(vnode: VnodeCut, table: str,
         # lane skips/answers pages before any decode, and survivors fall
         # through to the per-page Python jobs
         return None
-    version = vnode.summary.version
-    files = []
-    for level in (4, 3, 2, 1, 0):
-        fms = sorted(version.levels[level].values(), key=lambda f: f.file_id)
-        for fm in fms:
-            if not trs.is_all and not trs.overlaps(
-                    TimeRange(fm.min_ts, fm.max_ts)):
-                continue
-            files.append((fm, version.reader(fm)))
-    mem_sids = _mem_series_ids(vnode, table, trs)
-    targets = _field_targets(vnode, table, field_names)
 
     # ---------------------------------------------------------------- plan
-    # per series: ("n", sid, [(reader, chunk, cols, [page idx])], n_rows,
-    #             needs_trim, pruned) or ("f", sid, ts, fields)
-    plan = []
-    total = 0
-    any_trim = False
-    any_pruned = False
-    merged_pages = [0]
-    for sid in series_ids:
-        sid = int(sid)
-        entry = _plan_series(vnode, table, sid, files, mem_sids, trs,
-                             constraints, field_names, targets,
-                             merged_pages)
-        if entry is None:
-            continue
-        if entry[0] == "p":   # series pruned away entirely by constraints
-            any_pruned = True
-            continue
-        plan.append(entry)
-        if entry[0] == "n":
-            total += entry[3]
-            any_trim = any_trim or entry[4]
-            any_pruned = any_pruned or entry[5]
-        else:
-            total += len(entry[2])
+    with stages.stage("scan.plan_ms"):
+        sids = np.asarray(series_ids, dtype=np.uint64)
+        version = vnode.summary.version
+        targets = {n: (cid, tuple(names)) for n, (cid, names)
+                   in _field_targets(vnode, table, field_names).items()}
+        pfiles: list[_PlanFile] = []
+        for level in (4, 3, 2, 1, 0):
+            for fm in sorted(version.levels[level].values(),
+                             key=lambda f: f.file_id):
+                if not trs.is_all and not trs.overlaps(
+                        TimeRange(fm.min_ts, fm.max_ts)):
+                    continue
+                r = version.reader(fm)
+                index = r.page_index(table)
+                if index is None:
+                    continue
+                series, rows = index.rows_of(sids)
+                if len(series):
+                    pfiles.append(_PlanFile(
+                        fm, r, index, series, rows,
+                        {n: index.column(targets[n]) for n in field_names}))
 
-    if dev_lane is not None and merged_pages[0]:
-        # every page scanned is booked once: these went neither to the
-        # device lane nor to the native decoder but through the per-series
-        # merge (memcache rows, tombstones or overlapping chunks)
-        dev_lane.declined("series_merge", merged_pages[0])
+        # the series metadata cannot plan are read and merged here, one
+        # at a time, and splice into their reserved span further down
+        merge = _series_to_merge(vnode, table, sids, pfiles, trs)
+        spliced: dict[int, tuple] = {}   # position in sids → (ts, fields)
+        merged_pages = 0
+        n_merged = int(np.count_nonzero(merge))
+        for j in np.flatnonzero(merge).tolist() if n_merged else ():
+            ts, fields, n_pages = _merged_series(vnode, table, int(sids[j]),
+                                                 field_names, trs)
+            merged_pages += n_pages
+            if len(ts):
+                spliced[j] = (ts, fields)
+        if dev_lane is not None and merged_pages:
+            # every page scanned is booked once: these went neither to the
+            # device lane nor to the native decoder but through the
+            # per-series merge (memcache rows, tombstones or overlapping
+            # chunks)
+            dev_lane.declined("series_merge", merged_pages)
+
+        pages = _plan_pages(pfiles, merge if n_merged else None, trs)
+        any_pruned = bool(constraints) and len(pages) > 0 \
+            and _constrain_pages(pages, pfiles, constraints)
+        any_trim = not pages.inside.all()
 
     # --------------------------------------------- compressed-domain lane
     # lane zero: before any bytes move, pages provably skippable or
@@ -930,11 +1120,40 @@ def _scan_vnode_native(vnode: VnodeCut, table: str,
     if compressed_spec is not None:
         lane = compressed_domain.ScanLane(compressed_spec, trs,
                                           vnode.index)
+
+        def lane_page(k):
+            f, c, i = pages.at(k, pfiles)
+            return (int(sids[pages.series[k]]), f.reader, f.index.chunks[c],
+                    f.chunk_cols(c), i)
+
         with stages.stage("compressed_ms"):
-            plan = lane.filter_plan(plan)
+            keep = lane.filter_pages(pages.inside, lane_page)
         if lane.engaged:
             any_pruned = True
-            total = sum(e[3] if e[0] == "n" else len(e[2]) for e in plan)
+            pages.take(keep)
+
+    with stages.stage("scan.plan_ms"):
+        # rows a series, and where each series and each page starts
+        rows = np.bincount(pages.series, weights=pages.n_rows,
+                           minlength=len(sids)).astype(np.int64)
+        page_off = np.cumsum(pages.n_rows) - pages.n_rows
+        series_off = None       # only a spliced series asks for its own
+        if spliced:
+            planned_off = np.cumsum(rows) - rows
+            for j, (ts, _fields) in spliced.items():
+                rows[j] = len(ts)
+            series_off = np.cumsum(rows) - rows
+            page_off += (series_off - planned_off)[pages.series]
+        total = int(rows.sum())
+        stages.count("scan_plan.indexed_series",
+                     int(np.count_nonzero(rows)) - len(spliced))
+        if n_merged:
+            stages.count("scan_plan.merged_series", n_merged)
+        for fi, f in enumerate(pfiles):
+            of = slice(None) if len(pfiles) == 1 \
+                else np.flatnonzero(pages.file == fi)
+            f.page, f.chunk, f.off = pages.page[of], pages.chunk[of], \
+                page_off[of]
 
     if total == 0:
         b = ScanBatch(table, np.empty(0, dtype=np.uint64), [],
@@ -956,19 +1175,14 @@ def _scan_vnode_native(vnode: VnodeCut, table: str,
     # front in one coalesced ranged-GET pass, so the decode lanes below
     # hit the block cache instead of issuing a GET per page
     cold_wants: dict[int, tuple] = {}
-    for entry in plan:
-        if entry[0] != "n":
+    for f in pfiles:
+        if not (f.cold and len(f.page)):
             continue
-        for r, cm, cols, idx in entry[2]:
-            if not getattr(r, "is_cold", False):
-                continue
-            lst = cold_wants.setdefault(id(r), (r, []))[1]
-            for i in idx:
-                lst.append(cm.time_pages[i])
-                for name in field_names:
-                    col = cols.get(name)
-                    if col is not None:
-                        lst.append(col.pages[i])
+        lst = cold_wants.setdefault(id(f.reader), (f.reader, []))[1]
+        for c, p in zip(f.chunk.tolist(), f.page.tolist()):
+            i = p - int(f.index.page_lo[c])
+            lst.append(f.index.chunks[c].time_pages[i])
+            lst.extend(cm.pages[i] for cm in f.chunk_cols(c).values())
     if lane is not None:
         # closed-form jobs read only the pages they need (often just the
         # time page) — those ranges join the same coalesced GET pass, so
@@ -980,84 +1194,138 @@ def _scan_vnode_native(vnode: VnodeCut, table: str,
         with stages.stage("compressed_ms"):
             lane.run_jobs()
 
-    # ------------------------------------------------------- column typing
-    ftypes: dict[str, ValueType] = {}
-    for entry in plan:
-        if entry[0] == "n":
-            for _r, _cm, cols, _idx in entry[2]:
-                for name, col in cols.items():
-                    if name in field_names and name not in ftypes \
-                            and col.pages:
-                        ftypes[name] = ValueType(col.pages[0].value_type)
-        else:
-            for name, (vt, _v, _m) in entry[3].items():
-                ftypes.setdefault(name, vt)
+    with stages.stage("scan.plan_ms"):
+        # --------------------------------------------------- column typing
+        # a column is typed by the first chunk of the batch that holds it,
+        # and the columns stand in the order they first appear
+        first_seen: dict[str, tuple] = {}   # name → (batch row, ValueType)
 
-    # ----------------------------------------------------------- allocate
-    ts_all = np.empty(total, dtype=np.int64)
-    numeric_cols: dict[str, tuple[np.ndarray, np.ndarray]] = {}
-    string_parts: dict[str, list] = {}
-    string_valid: dict[str, np.ndarray] = {}
-    for name, vt in ftypes.items():
-        if vt in (ValueType.STRING, ValueType.GEOMETRY):
-            string_parts[name] = []
-            string_valid[name] = np.zeros(total, dtype=bool)
-            continue
-        dt = vt.numpy_dtype()
-        numeric_cols[name] = (np.zeros(total, dtype=dt),
-                              np.zeros(total, dtype=bool))
+        def _seen(name, off, vt):
+            if name not in first_seen or off < first_seen[name][0]:
+                first_seen[name] = (off, vt)
 
-    # ------------------------------------------- descriptors per (file, col)
-    # groups[id(reader)] = {"base": u8 view, "cols": {key: (desc, jobs)}}
-    # key None = time column
-    groups: dict[int, dict] = {}
-    py_jobs: list = []   # (reader, pm, colname|None, out_off, vt)
+        for f in pfiles:
+            if not len(f.page):
+                continue
+            first = None
+            for name, col in f.cols.items():
+                if col is None:
+                    continue
+                if col.everywhere:
+                    if first is None:
+                        first = int(f.off[0]), int(f.chunk[0])
+                    off, c = first
+                else:
+                    k = int(col.present[f.chunk].argmax())
+                    off, c = int(f.off[k]), int(f.chunk[k])
+                    if not col.present[c]:
+                        continue
+                _seen(name, off, ValueType(int(col.vt0[c])))
+        for j, (_ts, fields) in spliced.items():
+            for name, (vt, _v, _m) in fields.items():
+                _seen(name, int(series_off[j]), vt)
+        rank = {name: k for k, name in enumerate(field_names)}
+        ftypes: dict[str, ValueType] = {
+            name: first_seen[name][1] for name in sorted(
+                first_seen, key=lambda n: (first_seen[n][0], rank[n]))}
 
-    def _group(r):
-        g = groups.get(id(r))
-        if g is None:
-            g = groups[id(r)] = {"base": r.buffer_array(), "cols": {},
-                                 "reader": r}
-        return g
+        # ------------------------------------------------------- allocate
+        ts_all = np.empty(total, dtype=np.int64)
+        numeric_cols: dict[str, tuple[np.ndarray, np.ndarray]] = {}
+        string_parts: dict[str, list] = {}
+        string_valid: dict[str, np.ndarray] = {}
+        for name, vt in ftypes.items():
+            if vt in (ValueType.STRING, ValueType.GEOMETRY):
+                string_parts[name] = []
+                string_valid[name] = np.zeros(total, dtype=bool)
+                continue
+            dt = vt.numpy_dtype()
+            numeric_cols[name] = (np.zeros(total, dtype=dt),
+                                  np.zeros(total, dtype=bool))
 
-    def _add_page(r, pm, colname, out_off, kind):
-        g = _group(r)
-        lst = g["cols"].setdefault(colname, ([], []))
-        lst[0].append((pm.offset, pm.size, out_off, pm.n_rows, kind,
-                       pm.n_values))
-        lst[1].append((pm, out_off))
+        # --------------------------------------- descriptors per (file, col)
+        # one native task a (file, column): (group, column | None for the
+        # time column, descriptors, job_at) — job_at(b) → (PageMeta,
+        # out_off) of descriptor b, asked for a page the decoder rejects
+        tasks: list = []
+        groups: dict[int, dict] = {}   # id(reader) → its base + page lists
+        py_jobs: list = []   # (reader, pm, colname|None, out_off, vt)
 
-    # the route of a page: a lane that stands behind the native decoder
-    # (auto mode — decoded values land in the host arrays allocated
-    # above) leaves it every page it can take; a device-first lane
-    # (forced, or handed in directly) is asked first, as before
-    native_first = dev_lane is not None and dev_lane.native_first
-    n_native_first = 0
-    kept_sids: list[int] = []
-    keys = []
-    counts: list[int] = []
-    fallback_writes = []   # (entry, base_off)
-    bytes_materialized = 0   # page bytes routed into ANY decode lane
-    off = 0
-    for entry in plan:
-        if entry[0] == "f":
-            _tag, sid, ts, fields = entry
-            n = len(ts)
-            fallback_writes.append((entry, off))
-            kept_sids.append(sid)
-            keys.append(vnode.index.get_series_key(sid))
-            counts.append(n)
-            off += n
-            continue
-        _tag, sid, chunks, n_rows, _trim, _pruned = entry
-        kept_sids.append(sid)
-        keys.append(vnode.index.get_series_key(sid))
-        counts.append(n_rows)
-        for r, cm, cols, idx in chunks:
-            cold = getattr(r, "is_cold", False)
-            time_native = native_ok and not cold
-            for i in idx:
-                tp = cm.time_pages[i]
+        def _group(r):
+            g = groups.get(id(r))
+            if g is None:
+                g = groups[id(r)] = {"base": r.buffer_array(), "cols": {},
+                                     "reader": r}
+            return g
+
+        def _add_page(r, pm, colname, out_off, kind):
+            lst = _group(r)["cols"].setdefault(colname, ([], []))
+            lst[0].append((pm.offset, pm.size, out_off, pm.n_rows, kind,
+                           pm.n_values))
+            lst[1].append((pm, out_off))
+
+        # the route of a page: a lane that stands behind the native decoder
+        # (auto mode — decoded values land in the host arrays allocated
+        # above) leaves it every page it can take; a device-first lane
+        # (forced, or handed in directly) is asked first. Where nobody is
+        # asked first, a (file, column) whose index says that every page
+        # is one the native decoder takes — a local reader, one value
+        # type (the column's) in encodings it knows — gets its
+        # descriptors by indexing; every other (file, column) is routed
+        # a page at a time, in plan order
+        native_first = dev_lane is not None and dev_lane.native_first
+        unasked = native_ok and (dev_lane is None or native_first)
+        n_native_first = 0
+        bytes_materialized = 0   # page bytes routed into ANY decode lane
+        paged: dict[int, tuple] = {}   # file → (time paged?, [columns])
+        for fi, f in enumerate(pfiles):
+            if not len(f.page):
+                continue
+            direct = unasked and not f.cold
+            index = f.index
+            if direct:
+                desc = index.time[f.page]
+                desc[:, 2] = f.off
+                tasks.append((_group(f.reader), None, desc, _job_at(
+                    index, None, f.chunk, f.page, f.off)))
+            names = []
+            for name in field_names:
+                col = f.cols[name]
+                if col is None:
+                    continue
+                page, chunk, off = f.page, f.chunk, f.off
+                if not col.everywhere:
+                    held = col.present[chunk]
+                    page, chunk, off = page[held], chunk[held], off[held]
+                    if not len(page):
+                        continue
+                kind = _NATIVE_NUMERIC.get(col.vt_all) \
+                    if col.vt_all == int(ftypes[name]) else None
+                if direct and kind is not None \
+                        and col.encodings <= _NATIVE_ENC[kind]:
+                    desc = col.desc[page]
+                    desc[:, 2] = off
+                    desc[:, 4] = kind
+                    tasks.append((_group(f.reader), name, desc, _job_at(
+                        index, col.metas, chunk, page, off)))
+                else:
+                    names.append(name)
+            if names or not direct:
+                paged[fi] = (not direct, names)
+        if tasks:
+            planned = np.concatenate([t[2] for t in tasks])
+            n_native_first = len(planned)
+            bytes_materialized = int(planned[:, 1].sum())
+
+        for k in (np.flatnonzero(np.isin(pages.file, list(paged))).tolist()
+                  if paged else ()):
+            f, c, i = pages.at(k, pfiles)
+            time_paged, names = paged[int(pages.file[k])]
+            r, off = f.reader, int(page_off[k])
+            if time_paged:
+                tp = f.index.chunks[c].time_pages[i]
+                bytes_materialized += tp.size
+                time_native = native_ok and not f.cold
                 if native_first and time_native:
                     n_native_first += 1
                     queued = False
@@ -1074,41 +1342,46 @@ def _scan_vnode_native(vnode: VnodeCut, table: str,
                         _add_page(r, tp, None, off, 0)
                     else:
                         py_jobs.append((r, tp, None, off, None))
-                for name in field_names:
-                    col = cols.get(name)
-                    if col is None:
-                        continue   # absent column: stays zero/invalid
-                    pm = col.pages[i]
-                    vt = ftypes.get(name)
-                    miss = _native_miss(native_ok, cold, pm, vt)
-                    if native_first and miss is None:
-                        n_native_first += 1
-                    elif dev_lane is not None and pm.value_type == int(vt) \
-                            and (vt in (ValueType.STRING,
-                                        ValueType.GEOMETRY)
-                                 or dev_lane.accepts(pm.value_type,
-                                                     pm.encoding)) \
-                            and _submit_device_page(
-                                dev_lane, r, pm, name, off, vt,
-                                numeric_cols, string_parts, string_valid,
-                                ts_all):
-                        continue
-                    if miss is None:
-                        _add_page(r, pm, name, off,
-                                  _NATIVE_NUMERIC[pm.value_type])
-                    else:
-                        # neither the device lane nor the native decoder
-                        # takes it: per-page Python path
-                        _count_fallback(miss)
-                        py_jobs.append((r, pm, name, off, vt))
-                bytes_materialized += tp.size + sum(
-                    cols[name].pages[i].size for name in field_names
-                    if name in cols)
-                if lane is not None:
-                    lane.apply_page_masks(cm, i, off, total)
-                off += tp.n_rows
+            for name in names:
+                col = f.cols[name]
+                if not col.present[c]:
+                    continue   # absent column: stays zero/invalid
+                pm = col.metas[c].pages[i]
+                bytes_materialized += pm.size
+                vt = ftypes[name]
+                miss = _native_miss(native_ok, f.cold, pm, vt)
+                if native_first and miss is None:
+                    n_native_first += 1
+                elif dev_lane is not None and pm.value_type == int(vt) \
+                        and (vt in (ValueType.STRING, ValueType.GEOMETRY)
+                             or dev_lane.accepts(pm.value_type,
+                                                 pm.encoding)) \
+                        and _submit_device_page(
+                            dev_lane, r, pm, name, off, vt,
+                            numeric_cols, string_parts, string_valid,
+                            ts_all):
+                    continue
+                if miss is None:
+                    _add_page(r, pm, name, off,
+                              _NATIVE_NUMERIC[pm.value_type])
+                else:
+                    # neither the device lane nor the native decoder
+                    # takes it: per-page Python path
+                    _count_fallback(miss)
+                    py_jobs.append((r, pm, name, off, vt))
+        for g in groups.values():
+            for colname, (desc_list, jobs) in g["cols"].items():
+                tasks.append((g, colname, np.array(
+                    desc_list, dtype=np.int64).reshape(-1, 6),
+                    jobs.__getitem__))
 
-    if n_native_first:
+        if lane is not None and lane.has_masks:
+            for k in np.flatnonzero(pages.inside).tolist():
+                f, c, i = pages.at(k, pfiles)
+                lane.apply_page_masks(f.index.chunks[c], i,
+                                      int(page_off[k]), total)
+
+    if native_first and n_native_first:
         dev_lane.declined("native_first", n_native_first)
 
     # ------------------------------------------------------ device decode
@@ -1127,23 +1400,16 @@ def _scan_vnode_native(vnode: VnodeCut, table: str,
     # task of a column has finished cleanly, its final array is handed to
     # the uploader while the remaining columns still decode (decode N+1
     # overlaps device_put of N — device_put enqueues are async).
-    tasks = []
     col_remaining: dict[str, int] = {}
-    for g in groups.values():
-        for colname, (desc_list, jobs) in g["cols"].items():
-            desc = np.array(desc_list, dtype=np.int64).reshape(-1, 6)
-            if colname is None:
-                out_vals, out_valid = ts_all, None
-            else:
-                out_vals, out_valid = numeric_cols[colname]
-                col_remaining[colname] = col_remaining.get(colname, 0) + 1
-            tasks.append((g, colname, desc, out_vals, out_valid, jobs))
+    for _g, colname, _desc, _at in tasks:
+        if colname is not None:
+            col_remaining[colname] = col_remaining.get(colname, 0) + 1
 
     uploader = None
-    if upload_hook is not None and not fallback_writes \
-            and not (any_trim and not trs.is_all) \
+    if upload_hook is not None and not spliced \
+            and not any_trim \
             and (lane is None or not lane.has_masks):
-        # fallback series splice into every column after decode, a time
+        # merged series splice into every column after decode, a time
         # trim re-slices the arrays, and compressed-domain survivor masks
         # gather a subset — all would invalidate an eagerly shipped copy,
         # so only clean scans pipeline uploads
@@ -1158,17 +1424,19 @@ def _scan_vnode_native(vnode: VnodeCut, table: str,
                 uploader.put(name, ftypes[name], vals, valid)
 
     def _run(task):
-        g, _colname, desc, out_vals, out_valid, _jobs = task
+        g, colname, desc, _at = task
+        out_vals, out_valid = (ts_all, None) if colname is None \
+            else numeric_cols[colname]
         return native.decode_pages(g["base"], desc, out_vals, out_valid,
                                    n_threads=per_task_threads)
 
     def _finish(task, status) -> bool:
         """Fold one task's result back in (main thread); False = abort."""
-        g, colname, _desc, _ov, _om, jobs = task
+        g, colname, _desc, job_at = task
         if status is None:
             return False   # library vanished mid-flight: legacy path
         for bi in np.nonzero(status)[0]:
-            pm, out_off = jobs[bi]
+            pm, out_off = job_at(bi)
             _count_fallback("native_reject")
             py_jobs.append((g["reader"], pm, colname, out_off,
                             ftypes.get(colname)))
@@ -1182,221 +1450,146 @@ def _scan_vnode_native(vnode: VnodeCut, table: str,
             uploader.put(colname, ftypes[colname], vals, valid)
         return True
 
-    if len(tasks) > 1:
-        from concurrent.futures import as_completed
+    with stages.stage("scan.native_ms"):
+        if len(tasks) > 1:
+            from concurrent.futures import as_completed
 
-        from ..utils.executor import submit as _submit
+            from ..utils.executor import submit as _submit
 
-        per_task_threads = 1 if len(tasks) >= n_threads \
-            else max(1, n_threads // len(tasks))
-        futs = {_submit("decode", _run, t): t for t in tasks}
-        aborted = False
-        for f in as_completed(futs):
-            if not _finish(futs[f], f.result()):
-                aborted = True
-        if aborted:
-            return None
-    else:
-        per_task_threads = n_threads
-        for t in tasks:
-            if not _finish(t, _run(t)):
+            per_task_threads = 1 if len(tasks) >= n_threads \
+                else max(1, n_threads // len(tasks))
+            futs = {_submit("decode", _run, t): t for t in tasks}
+            aborted = False
+            for fut in as_completed(futs):
+                if not _finish(futs[fut], fut.result()):
+                    aborted = True
+            if aborted:
                 return None
-
-    # ------------------------------------------------ python page fallbacks
-    for r, pm, colname, out_off, vt in py_jobs:
-        deadline_mod.check_current()
-        n = pm.n_rows
-        if colname is None:
-            ts_all[out_off:out_off + n] = r.read_time_page(pm)
-            continue
-        dense, nm = r.read_field_page(pm)
-        if vt in (ValueType.STRING, ValueType.GEOMETRY):
-            da = _as_dict_part(dense)
-            if nm is None:
-                codes = da.codes.astype(np.int32)
-                valid_p = np.ones(n, dtype=bool)
-            else:
-                codes = np.zeros(n, dtype=np.int32)
-                codes[~nm] = da.codes
-                valid_p = ~nm
-            string_parts[colname].append(
-                (out_off, DictArray(codes, da.values)))
-            string_valid[colname][out_off:out_off + n] = valid_p
-            continue
-        vals, valid = numeric_cols[colname]
-        if nm is None:
-            vals[out_off:out_off + n] = dense
-            valid[out_off:out_off + n] = True
         else:
-            vals[out_off:out_off + n][~nm] = dense
-            valid[out_off:out_off + n] = ~nm
+            per_task_threads = n_threads
+            for t in tasks:
+                if not _finish(t, _run(t)):
+                    return None
 
-    # ------------------------------------------------ fallback series write
-    for entry, base_off in fallback_writes:
-        _tag, sid, ts, fields = entry
-        n = len(ts)
-        ts_all[base_off:base_off + n] = ts
-        for name, (vt, vals_p, valid_p) in fields.items():
-            if name not in ftypes:
+    with stages.stage("scan.trim_ms"):
+        # -------------------------------------------- python page fallbacks
+        for r, pm, colname, out_off, vt in py_jobs:
+            deadline_mod.check_current()
+            n = pm.n_rows
+            if colname is None:
+                ts_all[out_off:out_off + n] = r.read_time_page(pm)
                 continue
+            dense, nm = r.read_field_page(pm)
             if vt in (ValueType.STRING, ValueType.GEOMETRY):
-                da = _as_dict_part(vals_p)
-                string_parts[name].append(
-                    (base_off, DictArray(da.codes.astype(np.int32),
-                                         da.values)))
-                string_valid[name][base_off:base_off + n] = valid_p
+                da = _as_dict_part(dense)
+                if nm is None:
+                    codes = da.codes.astype(np.int32)
+                    valid_p = np.ones(n, dtype=bool)
+                else:
+                    codes = np.zeros(n, dtype=np.int32)
+                    codes[~nm] = da.codes
+                    valid_p = ~nm
+                string_parts[colname].append(
+                    (out_off, DictArray(codes, da.values)))
+                string_valid[colname][out_off:out_off + n] = valid_p
+                continue
+            vals, valid = numeric_cols[colname]
+            if nm is None:
+                vals[out_off:out_off + n] = dense
+                valid[out_off:out_off + n] = True
             else:
-                vals, valid = numeric_cols[name]
-                vals[base_off:base_off + n] = vals_p
-                valid[base_off:base_off + n] = valid_p
+                vals[out_off:out_off + n][~nm] = dense
+                valid[out_off:out_off + n] = ~nm
 
-    sid_ordinal = np.repeat(
-        np.arange(len(kept_sids), dtype=np.int32),
-        np.asarray(counts, dtype=np.int64))
+        # -------------------------------------------- merged series splice
+        for j, (ts, fields) in spliced.items():
+            base_off = int(series_off[j])
+            n = len(ts)
+            ts_all[base_off:base_off + n] = ts
+            for name, (vt, vals_p, valid_p) in fields.items():
+                if vt in (ValueType.STRING, ValueType.GEOMETRY):
+                    da = _as_dict_part(vals_p)
+                    string_parts[name].append(
+                        (base_off, DictArray(da.codes.astype(np.int32),
+                                             da.values)))
+                    string_valid[name][base_off:base_off + n] = valid_p
+                else:
+                    vals, valid = numeric_cols[name]
+                    vals[base_off:base_off + n] = vals_p
+                    valid[base_off:base_off + n] = valid_p
 
-    # --------------------------------------------------- assemble + trim
-    out_fields: dict = {}
-    for name, (vals, valid) in numeric_cols.items():
-        out_fields[name] = (ftypes[name], vals, valid)
-    for name, parts in string_parts.items():
-        das = [p[1] for p in parts]
-        union = unify_dictionaries(das) if das else np.array([""],
-                                                            dtype=object)
-        codes_all = np.zeros(total, dtype=np.int32)
-        for (p_off, da), d in zip(parts, das):
-            codes_all[p_off:p_off + len(da.codes)] = d.remap_to(union)
-        out_fields[name] = (ftypes[name], DictArray(codes_all, union),
-                            string_valid[name])
+        kept = np.flatnonzero(rows)
+        kept_sids = sids[kept]
+        keys = [vnode.index.get_series_key(sid)
+                for sid in kept_sids.tolist()]
+        sid_ordinal = np.repeat(np.arange(len(kept), dtype=np.int32),
+                                rows[kept])
 
-    row_mask = lane.row_mask if lane is not None else None
-    if (any_trim and not trs.is_all) or row_mask is not None:
-        keep = _time_mask(ts_all, trs) if (any_trim and not trs.is_all) \
-            else None
-        if row_mask is not None:
-            # late materialization: only rows surviving every
-            # compressed-domain predicate mask are gathered
-            keep = row_mask if keep is None else (keep & row_mask)
-        if keep is not None and not keep.all():
-            ts_all = ts_all[keep]
-            sid_ordinal = sid_ordinal[keep]
-            out_fields = {
-                name: (vt,
-                       (DictArray(v.codes[keep], v.values)
-                        if isinstance(v, DictArray) else v[keep]),
-                       m[keep])
-                for name, (vt, v, m) in out_fields.items()}
-            # drop series trimmed to zero rows and renumber ordinals
-            pres = np.bincount(sid_ordinal, minlength=len(kept_sids))
-            if (pres == 0).any():
-                keep_s = np.nonzero(pres > 0)[0]
-                remap = np.full(len(kept_sids), -1, dtype=np.int32)
-                remap[keep_s] = np.arange(len(keep_s), dtype=np.int32)
-                sid_ordinal = remap[sid_ordinal]
-                kept_sids = [kept_sids[i] for i in keep_s]
-                keys = [keys[i] for i in keep_s]
+        # ------------------------------------------------- assemble + trim
+        out_fields: dict = {}
+        for name, (vals, valid) in numeric_cols.items():
+            out_fields[name] = (ftypes[name], vals, valid)
+        for name, parts in string_parts.items():
+            das = [p[1] for p in parts]
+            union = unify_dictionaries(das) if das else np.array(
+                [""], dtype=object)
+            codes_all = np.zeros(total, dtype=np.int32)
+            for (p_off, da), d in zip(parts, das):
+                codes_all[p_off:p_off + len(da.codes)] = d.remap_to(union)
+            out_fields[name] = (ftypes[name], DictArray(codes_all, union),
+                                string_valid[name])
 
-    b = ScanBatch(table, np.array(kept_sids, dtype=np.uint64), keys,
-                  ts_all, sid_ordinal, out_fields)
-    b._pages_pruned = any_pruned
-    if lane is not None:
-        bytes_materialized += lane.bytes_materialized
-        lane.attach(b)
-    if bytes_materialized:
-        stages.count("compressed.bytes_materialized", bytes_materialized)
-    if uploader is not None:
-        uploader.attach(b)
-    return b
+        row_mask = lane.row_mask if lane is not None else None
+        if any_trim or row_mask is not None:
+            keep = _time_mask(ts_all, trs) if any_trim else None
+            if row_mask is not None:
+                # late materialization: only rows surviving every
+                # compressed-domain predicate mask are gathered
+                keep = row_mask if keep is None else (keep & row_mask)
+            if keep is not None and not keep.all():
+                ts_all = ts_all[keep]
+                sid_ordinal = sid_ordinal[keep]
+                out_fields = {
+                    name: (vt,
+                           (DictArray(v.codes[keep], v.values)
+                            if isinstance(v, DictArray) else v[keep]),
+                           m[keep])
+                    for name, (vt, v, m) in out_fields.items()}
+                # drop series trimmed to zero rows and renumber ordinals
+                pres = np.bincount(sid_ordinal, minlength=len(kept_sids))
+                if (pres == 0).any():
+                    keep_s = np.nonzero(pres > 0)[0]
+                    remap = np.full(len(kept_sids), -1, dtype=np.int32)
+                    remap[keep_s] = np.arange(len(keep_s), dtype=np.int32)
+                    sid_ordinal = remap[sid_ordinal]
+                    kept_sids = kept_sids[keep_s]
+                    keys = [keys[i] for i in keep_s]
+
+        b = ScanBatch(table, kept_sids, keys, ts_all, sid_ordinal,
+                      out_fields)
+        b._pages_pruned = any_pruned
+        if lane is not None:
+            bytes_materialized += lane.bytes_materialized
+            lane.attach(b)
+        if bytes_materialized:
+            stages.count("compressed.bytes_materialized",
+                         bytes_materialized)
+        if uploader is not None:
+            uploader.attach(b)
+        return b
 
 
-def _plan_series(vnode, table, sid, files, mem_sids, trs, constraints,
-                 field_names, targets, merged_pages):
-    """→ ("n", sid, [(reader, chunk, cols, admitted idx)], n_rows, trim,
-    pruned) | ("f", sid, ts, fields) | ("p",) (rows existed but every
-    page was constraint-pruned) | None (no rows). An "f" series was read
-    and merged here, through the per-series path; the TSM pages that took
-    are added to `merged_pages[0]`. `cols` maps QUERY
-    column names to each chunk's ColumnMeta (id-resolved — see
-    _resolve_chunk_col), so constraint pruning and page decode stay
-    correct across RENAME COLUMN."""
-    fallback = sid in mem_sids
-    chunks = []
-    if not fallback:
-        version = vnode.summary.version
-        for fm, r in files:
-            cm = r.chunk(table, sid)
-            if cm is None:
-                continue
-            tb = version.tombstone(fm)
-            if not tb.is_empty and any(
-                    e.matches_series(table, sid) for e in tb.entries):
-                fallback = True
-                break
-            chunks.append((r, cm))
-    if not fallback and len(chunks) > 1:
-        chunks.sort(key=lambda rc: rc[1].min_ts)
-        for (_ra, a), (_rb, b) in zip(chunks, chunks[1:]):
-            if a.max_ts >= b.min_ts:
-                fallback = True
-                break
-    if not fallback:
-        for _r, cm in chunks:
-            P = len(cm.time_pages)
-            if any(len(c.pages) != P
-                   or any(cp.n_rows != tp.n_rows for cp, tp
-                          in zip(c.pages, cm.time_pages))
-                   for c in cm.columns):
-                fallback = True   # misaligned pages (defensive)
-                break
-    if fallback:
-        ts, fields, n_pages = _merged_series(vnode, table, sid, field_names,
-                                             trs)
-        merged_pages[0] += n_pages
-        if len(ts) == 0:
-            return None
-        return ("f", sid, ts, fields)
-    admitted = []
-    n_rows = 0
-    trim = False
-    pruned = False
-    time_admitted = 0
-    for r, cm in chunks:
-        cols = {}
-        maps = _chunk_maps(cm)
-        for qname in field_names:
-            cid, cands = targets[qname]
-            c = _resolve_chunk_col(maps, cid, cands)
-            if c is not None:
-                cols[qname] = c
-        idx = []
-        cold = getattr(r, "is_cold", False)
-        cold_pruned = 0
-        for i, tp in enumerate(cm.time_pages):
-            if not trs.is_all and not trs.overlaps(
-                    TimeRange(tp.min_ts, tp.max_ts)):
-                if cold:
-                    cold_pruned += 1
-                continue
-            time_admitted += 1
-            if constraints and not _page_admits(cols, i, constraints):
-                pruned = True
-                if cold:
-                    cold_pruned += 1
-                continue
-            idx.append(i)
-            n_rows += tp.n_rows
-            # a page fully inside ONE range needs no row-level trim (all
-            # its rows pass); anything else trims conservatively
-            if not trs.is_all and not any(
-                    r0.min_ts <= tp.min_ts and tp.max_ts <= r0.max_ts
-                    for r0 in trs.ranges):
-                trim = True
-        if cold_pruned:
-            _count_cold_pruned(cold_pruned)
-        if idx:
-            admitted.append((r, cm, cols, idx))
-    if n_rows == 0:
-        return ("p",) if pruned and time_admitted else None
-    return ("n", sid, admitted, n_rows, trim, pruned)
+def _job_at(index, metas, chunk, page, off):
+    """→ job_at(b) → (PageMeta, out_off) of row b of a task's
+    descriptors, for a (file, column) planned from the index (`metas`:
+    the column's ColumnMeta a chunk row, None for the time column)."""
+    def job_at(b):
+        c = int(chunk[b])
+        i = int(page[b] - index.page_lo[c])
+        held = index.chunks[c].time_pages if metas is None \
+            else metas[c].pages
+        return held[i], int(off[b])
+    return job_at
 
 
 def _discover_fields(vnode: VnodeCut, table: str) -> list[str]:

@@ -383,6 +383,7 @@ class ColdTsmReader(TsmReader):
         self.tail_off = int(side_tail_off)
         self.groups, self.bloom, self.footer = parse_tail(
             tail, data_path, tail_off=self.tail_off)
+        self._page_indexes = {}
         self.min_ts = self.footer.min_ts
         self.max_ts = self.footer.max_ts
         self.series_count = self.footer.series_count

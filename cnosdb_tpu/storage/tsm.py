@@ -38,6 +38,7 @@ from ..utils.zstd_compat import zstandard
 from ..models.codec import Encoding
 from ..models.schema import ValueType
 from ..models.strcol import DictArray
+from ..utils import stages
 from ..utils.bloom import BloomFilter
 from . import codecs
 
@@ -169,6 +170,177 @@ class ChunkGroupMeta:
     def from_list(cls, l):
         cm = {c[0]: ChunkMeta.from_list(c) for c in l[1]}
         return cls(l[0], cm)
+
+
+# ---------------------------------------------------------------------------
+# page index: the same metadata, held a column at a time
+# ---------------------------------------------------------------------------
+def ranges(lo: np.ndarray, n: np.ndarray) -> np.ndarray:
+    """→ arange(lo[0], lo[0] + n[0]), arange(lo[1], lo[1] + n[1]), … as
+    one int64 array."""
+    ends = np.cumsum(n)
+    return np.arange(int(ends[-1]) if len(ends) else 0, dtype=np.int64) \
+        + np.repeat(lo - (ends - n), n)
+
+
+def _desc_rows(pages: list) -> np.ndarray:
+    """→ i64 [len(pages), 6], a page's [offset, size, 0, n_rows, 0,
+    n_values]: native.decode_pages' descriptor less out_off and kind."""
+    return np.array([(p.offset, p.size, 0, p.n_rows, 0, p.n_values)
+                     for p in pages], dtype=np.int64).reshape(-1, 6)
+
+
+class ColumnIndex:
+    """One column of a (file, table) across the file's chunks. Row p of
+    `desc` is the column's page beside time page p — an aligned chunk's
+    pages stand one to one beside its time pages. Where a chunk lacks
+    the column (or is not aligned) `present` is False, its rows are zero
+    and its `vt0` is -1."""
+
+    __slots__ = ("present", "everywhere", "desc", "metas", "vt0", "vt_all",
+                 "encodings")
+
+    def __init__(self, present, desc, metas, vt0, vt_all, encodings):
+        self.present = present          # bool [C]
+        self.everywhere = bool(present.all())
+        self.desc = desc                # i64 [P, 6], see _desc_rows
+        self.metas = metas              # ColumnMeta | None a chunk
+        self.vt0 = vt0                  # i64 [C]: type of the first page
+        self.vt_all = vt_all            # the one type of every page | None
+        self.encodings = encodings      # frozenset of the pages' encodings
+
+    @classmethod
+    def of(cls, parts: list, index: "PageIndex") -> "ColumnIndex":
+        """`parts`: (chunk row, ColumnMeta) of every chunk holding it."""
+        n_chunks = len(index.sids)
+        rows = np.fromiter((ci for ci, _c in parts), dtype=np.int64,
+                           count=len(parts))
+        present = np.zeros(n_chunks, dtype=bool)
+        present[rows] = True
+        metas = [None] * n_chunks
+        for ci, c in parts:
+            metas[ci] = c
+        pages = [p for _ci, c in parts for p in c.pages]
+        lo = index.page_lo[rows]
+        desc = np.zeros((len(index.time), 6), dtype=np.int64)
+        desc[ranges(lo, index.page_lo[rows + 1] - lo)] = _desc_rows(pages)
+        vts = np.fromiter((p.value_type for p in pages), dtype=np.int64,
+                          count=len(pages))
+        vt0 = np.full(n_chunks, -1, dtype=np.int64)
+        vt0[rows] = [c.pages[0].value_type for _ci, c in parts]
+        return cls(present, desc, metas, vt0,
+                   int(vts[0]) if (vts == vts[0]).all() else None,
+                   frozenset(p.encoding for p in pages))
+
+    @classmethod
+    def first_of(cls, cols: list, index: "PageIndex") -> "ColumnIndex":
+        """→ a chunk's column is the first of `cols` the chunk holds."""
+        present = np.zeros(len(index.sids), dtype=bool)
+        desc = np.zeros_like(cols[0].desc)
+        metas = [None] * len(present)
+        vt0 = np.full(len(present), -1, dtype=np.int64)
+        n_pages = np.diff(index.page_lo)
+        for col in cols:
+            take = col.present & ~present
+            pages = np.repeat(take, n_pages)
+            desc[pages] = col.desc[pages]
+            vt0[take] = col.vt0[take]
+            for ci in np.flatnonzero(take).tolist():
+                metas[ci] = col.metas[ci]
+            present |= take
+        vts = {col.vt_all for col in cols}
+        return cls(present, desc, metas, vt0,
+                   vts.pop() if len(vts) == 1 else None,
+                   frozenset().union(*(col.encodings for col in cols)))
+
+
+class PageIndex:
+    """The chunk and page metadata of one (file, table) as arrays, built
+    once for the life of the reader (a TSM file is immutable) so that a
+    scan plans a file with array operations, not chunk by chunk and page
+    by page. Chunks stand in series-id order; a chunk's time pages are
+    rows page_lo[c]:page_lo[c + 1] of `time`, `t_min`, `t_max` and of
+    every column's `desc`. ChunkMeta / PageMeta stay what every other
+    reader of the metadata uses; `chunks` leads back to them."""
+
+    __slots__ = ("sids", "chunks", "min_ts", "max_ts", "page_lo", "aligned",
+                 "all_aligned", "single_pages", "time", "t_min", "t_max",
+                 "_sids_end",
+                 "_columns", "_resolved", "__weakref__")
+
+    def __init__(self, group: ChunkGroupMeta):
+        chunks = sorted(group.chunks.values(), key=lambda cm: cm.series_id)
+        n = len(chunks)
+        self.chunks = chunks
+        self.sids = np.fromiter((cm.series_id for cm in chunks),
+                                dtype=np.uint64, count=n)
+        # searchsorted answers n for an id past the last: a row to read
+        self._sids_end = np.append(self.sids, self.sids[-1:])
+        self.min_ts = np.fromiter((cm.min_ts for cm in chunks),
+                                  dtype=np.int64, count=n)
+        self.max_ts = np.fromiter((cm.max_ts for cm in chunks),
+                                  dtype=np.int64, count=n)
+        self.page_lo = np.zeros(n + 1, dtype=np.int64)
+        np.cumsum([len(cm.time_pages) for cm in chunks],
+                  out=self.page_lo[1:])
+        tps = [p for cm in chunks for p in cm.time_pages]
+        self.single_pages = len(tps) == n and all(
+            len(cm.time_pages) == 1 for cm in chunks)
+        self.time = _desc_rows(tps)
+        self.t_min = np.fromiter((p.min_ts for p in tps), dtype=np.int64,
+                                 count=len(tps))
+        self.t_max = np.fromiter((p.max_ts for p in tps), dtype=np.int64,
+                                 count=len(tps))
+        # every column's pages stand one to one beside the time pages
+        # (what the writer produces; checked once here, not a request)
+        self.aligned = np.fromiter(
+            (all(len(c.pages) == len(cm.time_pages)
+                 and all(cp.n_rows == tp.n_rows
+                         for cp, tp in zip(c.pages, cm.time_pages))
+                 for c in cm.columns) for cm in chunks),
+            dtype=bool, count=n)
+        self.all_aligned = bool(self.aligned.all())
+        parts: dict[tuple, list] = {}
+        for ci, cm in enumerate(chunks):
+            if not self.aligned[ci]:
+                continue
+            for c in cm.columns:
+                held = parts.setdefault((c.column_id, c.name), [])
+                if c.pages and not (held and held[-1][0] == ci):
+                    held.append((ci, c))
+        self._columns = {key: ColumnIndex.of(held, self)
+                         for key, held in parts.items() if held}
+        self._resolved: dict[tuple, ColumnIndex | None] = {}
+
+    def rows_of(self, series_ids: np.ndarray):
+        """→ (positions in `series_ids` of the series with a chunk here,
+        those chunks' rows), positions ascending."""
+        at = np.searchsorted(self.sids, series_ids)
+        found = np.flatnonzero(self._sids_end[at] == series_ids)
+        return found, at[found]
+
+    def column(self, key: tuple):
+        """→ the ColumnIndex of one query column, `key` its (column id |
+        None, (name, *prior names)); None where no chunk holds it. A
+        chunk's column is the one carrying the id, else the first of the
+        names a chunk column bears — one WITHOUT an id where the query
+        column's is known: a chunk column carrying another id is
+        provably another (renamed or dropped) column, even if its name
+        matches. Resolved once a (file, table), not once a chunk."""
+        try:
+            return self._resolved[key]
+        except KeyError:
+            column_id, names = key
+        cols = [col for (cid, _nm), col in self._columns.items()
+                if cid and cid == column_id]
+        for name in names:
+            cols += [col for (cid, nm), col in self._columns.items()
+                     if nm == name and (column_id is None or not cid)
+                     and not any(col is c for c in cols)]
+        got = None if not cols else cols[0] if len(cols) == 1 \
+            else ColumnIndex.first_of(cols, self)
+        self._resolved[key] = got
+        return got
 
 
 # ---------------------------------------------------------------------------
@@ -411,6 +583,7 @@ class TsmReader:
         if magic != MAGIC:
             raise TsmError("bad magic", path=path)
         self.groups, self.bloom, self.footer = parse_tail(self._buf, path)
+        self._page_indexes: dict[str, PageIndex] = {}
         self.min_ts = self.footer.min_ts
         self.max_ts = self.footer.max_ts
         self.series_count = self.footer.series_count
@@ -450,6 +623,20 @@ class TsmReader:
         if not g:
             return np.empty(0, dtype=np.uint64)
         return np.fromiter(g.chunks.keys(), dtype=np.uint64, count=len(g.chunks))
+
+    def page_index(self, table: str) -> PageIndex | None:
+        """→ the table's PageIndex, None where the file holds no chunk of
+        it. Built by the first scan that asks and kept as long as the
+        reader: the file never changes. Two scans may build it at once;
+        both build the same, and one of the two is kept."""
+        index = self._page_indexes.get(table)
+        if index is None:
+            g = self.groups.get(table)
+            if g is None or not g.chunks:
+                return None
+            stages.count("scan_plan.index_builds")
+            index = self._page_indexes.setdefault(table, PageIndex(g))
+        return index
 
     def maybe_contains_series(self, series_id: int) -> bool:
         return self.bloom.maybe_contains_u64(series_id)

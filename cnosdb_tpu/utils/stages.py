@@ -56,6 +56,28 @@ STAGE_CATALOG: dict[str, str] = {
     "delta_rows": "rows decoded by delta scans (a full rescan's worth "
                   "means tokens are being invalidated)",
     "decode_ms": "TSM read+decode (cache-miss and delta scans)",
+    "scan.plan_ms": "a scan's plan, inside decode_ms: the files, the page "
+                    "index lookups, time admission and page constraints, "
+                    "row offsets, column typing, the output arrays and "
+                    "the native descriptors a (file, column) — with the "
+                    "read and merge of every series metadata cannot plan "
+                    "(memcache_ms lies inside it)",
+    "scan.native_ms": "a scan's native.decode_pages tasks, inside "
+                      "decode_ms: one a (file, column), on the decode "
+                      "pool (wall, not thread-summed)",
+    "scan.trim_ms": "a scan's assembly, inside decode_ms: pages no fast "
+                    "lane took, the merged series' splice, the series "
+                    "ordinals, string dictionaries, and the row-level "
+                    "time trim / survivor gather of every column",
+    "scan_plan.indexed_series": "series of the batch planned from the "
+                                "files' page indexes by array operations",
+    "scan_plan.merged_series": "series that took the per-series read and "
+                               "merge instead: unflushed rows in range, a "
+                               "matching tombstone, chunks overlapping "
+                               "across files, or pages not aligned",
+    "scan_plan.index_builds": "page indexes built (one a (file, table), "
+                              "by the first scan that touches it; 0 on "
+                              "a warmed, frozen store)",
     "memcache_ms": "the scan's memcache share, inside decode_ms: "
                    "materialize() of each touched series' unflushed "
                    "batches up to the cut, and the merge of those rows "

@@ -234,7 +234,8 @@ def _scan_steps(v):
 
 
 SCAN_FUNCS = [scan_mod.scan_vnode, scan_mod._scan_vnode_native,
-              scan_mod._plan_series, scan_mod._series_parts,
+              scan_mod._series_to_merge, scan_mod._plan_pages,
+              scan_mod._merged_series, scan_mod._series_parts,
               scan_mod._mem_series_ids]
 
 

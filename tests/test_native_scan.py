@@ -290,3 +290,257 @@ def test_unsigned_and_bool_roundtrip(tmp_engine_dir):
     np.testing.assert_array_equal(got.fields["u"][1], want.fields["u"][1])
     np.testing.assert_array_equal(got.fields["b"][1], want.fields["b"][1])
     v.close()
+
+
+# ---------------------------------------------------------------------------
+# the indexed plan against the per-series reference path
+# ---------------------------------------------------------------------------
+def _assert_bit_identical(a, b):
+    """Two ScanBatches, array by array: series, keys, time, ordinals, each
+    column's validity and every valid value (strings by what they read;
+    the slot of a NULL holds whatever its lane left there)."""
+    np.testing.assert_array_equal(a.series_ids, b.series_ids)
+    assert a.series_ids.dtype == b.series_ids.dtype
+    assert a.series_keys == b.series_keys
+    np.testing.assert_array_equal(a.ts, b.ts)
+    np.testing.assert_array_equal(a.sid_ordinal, b.sid_ordinal)
+    assert a.sid_ordinal.dtype == b.sid_ordinal.dtype
+    assert set(a.fields) == set(b.fields)
+    for name, (vt_a, vals_a, valid_a) in a.fields.items():
+        vt_b, vals_b, valid_b = b.fields[name]
+        assert vt_a == vt_b, name
+        np.testing.assert_array_equal(valid_a, valid_b, err_msg=name)
+        if not (isinstance(vals_a, DictArray)
+                or isinstance(vals_b, DictArray)):
+            assert vals_a.dtype == vals_b.dtype, name
+        np.testing.assert_array_equal(
+            np.asarray(_as_objects(vals_a))[valid_a],
+            np.asarray(_as_objects(vals_b))[valid_b], err_msg=name)
+
+
+def _where(b, keep):
+    """→ the rows of `b` under `keep`, with the series they leave."""
+    from cnosdb_tpu.storage.scan import ScanBatch
+
+    left, ordinal = np.unique(b.sid_ordinal[keep], return_inverse=True)
+    return ScanBatch(
+        b.table, b.series_ids[left], [b.series_keys[k] for k in left],
+        b.ts[keep], ordinal.astype(np.int32),
+        {name: (vt, _as_objects(vals)[keep], valid[keep])
+         for name, (vt, vals, valid) in b.fields.items()})
+
+
+def _as_objects(vals):
+    return vals.materialize() if isinstance(vals, DictArray) else vals
+
+
+def _small_pages(monkeypatch, rows=64):
+    """Flushes write pages of `rows` rows, so a chunk has several."""
+    from cnosdb_tpu.storage import tsm
+
+    monkeypatch.setattr(tsm.TsmWriter.__init__, "__defaults__", (rows,))
+
+
+def _fleet(v, hosts=6, lo=0, hi=400, seed=0, **only):
+    """`hosts` series over [lo, hi) with all four field kinds, a few
+    nulls; `only` narrows the fields written."""
+    rng = np.random.default_rng(seed)
+    n = hi - lo
+    for h in range(hosts):
+        cols = dict(
+            f=[None if x % 17 == 3 else float(x) / 7 for x in range(n)],
+            i=rng.integers(-9, 9, n), b=rng.integers(0, 2, n) > 0,
+            s=[None if x % 29 == 5 else f"w{x % 7}" for x in range(n)])
+        if only:
+            cols = {k: c for k, c in cols.items() if only.get(k)}
+        _write(v, f"h{h}", range(lo, hi), **cols)
+
+
+def _sids(v):
+    return scan_vnode(v, "m", field_names=["f"]).series_ids
+
+
+def _disjoint_flushes(v, monkeypatch):
+    for base in (0, 1000, 2000):
+        _fleet(v, lo=base, hi=base + 300, seed=base)
+        v.flush()
+    return [{}, {"field_names": ["i", "s"]}]
+
+
+def _overlapping_l0(v, monkeypatch):
+    _fleet(v, hi=300)
+    v.flush()
+    _write(v, "h1", range(200, 500), f=np.full(300, 2.0))   # overlaps h1
+    _write(v, "h9", range(0, 50), i=np.arange(50))
+    v.flush()
+    return [{}, {"time_ranges": TimeRanges([TimeRange(100, 250)])}]
+
+
+def _tombstoned_series(v, monkeypatch):
+    _fleet(v)
+    v.flush()
+    _fleet(v, lo=400, hi=800, seed=1)
+    v.flush()
+    sids = _sids(v)
+    v.delete_time_range("m", [int(sids[2])], 100, 450)
+    return [{}, {"series_ids": sids[[0, 2, 4]]}]
+
+
+def _memcache_rows(v, monkeypatch):
+    _fleet(v)
+    v.flush()
+    _write(v, "h1", range(380, 450), f=np.full(70, 9.0))   # in the range
+    _write(v, "h3", range(9000, 9010), f=np.ones(10))      # outside it
+    return [{}, {"time_ranges": TimeRanges([TimeRange(50, 500)])},
+            {"time_ranges": TimeRanges([TimeRange(50, 379)])}]
+
+
+def _renamed_and_absent_columns(v, monkeypatch):
+    _fleet(v, hi=200, f=True, i=True)              # no b, no s
+    v.flush()
+    v.schemas["m"].rename_column("i", "count")     # the id stays
+    _fleet(v, lo=200, hi=400, seed=2, f=True, b=True)
+    _write(v, "h0", range(400, 500), i=None, f=np.ones(100))
+    wb = WriteBatch()
+    wb.add_series("m", SeriesRows(
+        SeriesKey("m", {"host": "h1"}), list(range(400, 450)),
+        {"count": (int(ValueType.INTEGER), list(range(50)))}))
+    v.write(wb)
+    v.flush()
+    return [{}, {"field_names": ["count", "b"]},
+            {"field_names": ["b", "s", "count"]}]
+
+
+def _multi_range_trim(v, monkeypatch):
+    _small_pages(monkeypatch)
+    _fleet(v, hi=500)
+    v.flush()
+    _fleet(v, lo=500, hi=900, seed=3)
+    v.flush()
+    return [{"time_ranges": TimeRanges([TimeRange(30, 100),
+                                        TimeRange(130, 191),
+                                        TimeRange(450, 555)])},
+            {"time_ranges": TimeRanges([TimeRange(64, 127)])},
+            {"time_ranges": TimeRanges([TimeRange(0, 10**6)])}]
+
+
+def _range_drops_a_series(v, monkeypatch):
+    _small_pages(monkeypatch)
+    _fleet(v, hosts=3, hi=300)
+    _write(v, "late", range(5000, 5200), f=np.arange(200.0))
+    v.flush()
+    return [{"time_ranges": TimeRanges([TimeRange(10, 250)])},
+            {"time_ranges": TimeRanges([TimeRange(4000, 6000)])},
+            {"time_ranges": TimeRanges([TimeRange(10**6, 10**7)])}]
+
+
+def _constraints_prune(v, monkeypatch):
+    from cnosdb_tpu.sql.expr import BinOp, Column, Literal
+
+    _small_pages(monkeypatch)
+    for h in range(4):
+        _write(v, f"h{h}", range(0, 512),
+               f=np.repeat(np.arange(8.0) * 10 + h, 64))
+    _write(v, "low", range(0, 128), f=np.zeros(128))   # pruned whole
+    v.flush()
+    flt = BinOp(">", Column("f"), Literal(45.0))
+    return [{"page_filter": flt},
+            {"page_filter": flt,
+             "time_ranges": TimeRanges([TimeRange(100, 400)])}]
+
+
+def _cold_reader(v, monkeypatch):
+    from cnosdb_tpu.storage import tiering
+
+    _small_pages(monkeypatch)
+    v.picker.l0_trigger = 2
+    for lo in (0, 200):
+        _fleet(v, hosts=3, lo=lo, hi=lo + 200, seed=lo)
+        v.flush()
+    v.compact_full()                            # → one L1 file, [0, 400)
+    _fleet(v, hosts=4, lo=400, hi=600, seed=4)
+    v.flush()                                   # a hot file beside it
+    bucket = os.path.join(os.path.dirname(v.dir), "bucket")
+    os.makedirs(bucket)
+    tiering.configure(bucket)
+    assert tiering.tier_vnode(v, boundary_ns=400) == 1
+    assert any(v.summary.version.reader(fm).is_cold
+               for fm in v.summary.version.all_files())
+    return [{}, {"time_ranges": TimeRanges([TimeRange(70, 450)])}]
+
+
+def _subset_and_permutation(v, monkeypatch):
+    _fleet(v, hosts=8)
+    v.flush()
+    _fleet(v, hosts=8, lo=400, hi=700, seed=5)
+    v.flush()
+    sids = _sids(v)
+    return [{"series_ids": sids[[5, 1, 6]]},
+            {"series_ids": sids[::-1].copy()},
+            {"series_ids": np.concatenate(
+                [sids[3:5], np.array([12345], dtype=np.uint64)])}]
+
+
+@pytest.mark.parametrize("build", [
+    _disjoint_flushes, _overlapping_l0, _tombstoned_series, _memcache_rows,
+    _renamed_and_absent_columns, _multi_range_trim, _range_drops_a_series,
+    _constraints_prune, _cold_reader, _subset_and_permutation],
+    ids=lambda f: f.__name__.strip("_"))
+def test_indexed_plan_equals_the_per_series_path(tmp_engine_dir,
+                                                 monkeypatch, build):
+    """The plan made from the files' page indexes gives the ScanBatch the
+    per-series reference path gives, bit for bit, whatever route a series
+    takes (indexed, or read and merged one at a time)."""
+    from cnosdb_tpu.storage import tiering
+
+    v = VnodeStorage(1, tmp_engine_dir, schemas=_schema())
+    try:
+        for kw in build(v, monkeypatch):
+            got, want = _both_scans(v, **kw)
+            if "page_filter" in kw:
+                # a pruned batch holds every row the filter keeps, and is
+                # compared by those (the reference path prunes nothing)
+                assert got._pages_pruned and got.n_rows < want.n_rows
+                got, want = (_where(b, b.fields["f"][1] > 45.0)
+                             for b in (got, want))
+            _assert_bit_identical(got, want)
+            assert got.n_rows or "time_ranges" in kw
+    finally:
+        v.close()
+        tiering.configure(None)
+        tiering.block_cache_clear()
+
+
+def test_a_compaction_drops_the_old_index_with_its_reader(tmp_engine_dir):
+    """A file's page index lives and dies with the file's reader: the
+    compaction's new file gets its own, built by the first scan of it."""
+    import gc
+    import weakref
+
+    v = VnodeStorage(1, tmp_engine_dir, schemas=_schema())
+    v.picker.l0_trigger = 2
+    for base in (0, 500):
+        _fleet(v, hosts=3, lo=base, hi=base + 200)
+        v.flush()
+    before = scan_vnode(v, "m")
+    version = v.summary.version
+    old = [version.reader(fm) for fm in version.all_files()]
+    assert len(old) == 2 and all(r.page_index("m") is not None for r in old)
+    assert old[0].page_index("m") is old[0].page_index("m")
+    gone = [weakref.ref(r.page_index("m")) for r in old]
+    old_ids = {fm.file_id for fm in version.all_files()}
+    v.compact_full()
+    (fm,) = version.all_files()
+    assert fm.file_id not in old_ids
+    assert not old_ids & set(version._readers)
+    new = version.reader(fm)
+    assert not new._page_indexes            # nobody has scanned it yet
+    after = scan_vnode(v, "m")
+    _assert_bit_identical(after, before)
+    index = new.page_index("m")
+    assert index is not None and len(index.sids) == 3
+    assert len(index.time) == 3             # one chunk a series, one page
+    del old
+    gc.collect()
+    assert all(ref() is None for ref in gone)
+    v.close()

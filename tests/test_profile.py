@@ -581,6 +581,68 @@ def test_auto_mode_profile_carries_no_device_decode_stage(http, monkeypatch):
                 if s["name"].startswith("device_decode")]
 
 
+def test_scan_books_its_plan_native_and_trim_stages(http):
+    """A scan's three sections are stages inside `decode_ms` — in the
+    summary header, /debug/profile, EXPLAIN ANALYZE and the span tree —
+    and it counts the series it planned from the page index, those it had
+    to read and merge one at a time, and the indexes it had to build."""
+    _seed_flushed_ints(http, hosts=6)
+    sections = ("scan.plan_ms", "scan.native_ms", "scan.trim_ms")
+    counts = ("scan_plan.indexed_series", "scan_plan.merged_series",
+              "scan_plan.index_builds")
+
+    def traced(sql, tid):
+        status, body, hdrs = http.request(
+            "POST", "/api/v1/sql?db=public", sql,
+            headers={"X-CnosDB-Profile": "1", "cnos-trace-id": tid})
+        assert status == 200, body
+        summary = json.loads(hdrs["X-CnosDB-Profile-Summary"])
+        full = json.loads(http.request(
+            "GET", f"/debug/profile?qid={summary['qid']}")[1])
+        return summary["stages"], full
+
+    st, full = traced(_BUCKETED, "feedc0de0036")
+    assert all(full["ms"][k] > 0 for k in sections), full["ms"]
+    assert sum(full["ms"][k] for k in sections) <= full["ms"]["decode_ms"]
+    assert all(k in st for k in sections)
+    # a flushed store: every series off the index, which this scan built
+    assert st["scan_plan.indexed_series"] == 6
+    assert st["scan_plan.index_builds"] == 1
+    assert "scan_plan.merged_series" not in st
+    spans = _trace_spans(http, "feedc0de0036")
+    by_id = {s["span_id"]: s for s in spans}
+    for key in sections:
+        got = [s for s in spans if s["name"] == key]
+        assert got, key
+        for s in got:
+            chain = _ancestors(s, by_id)
+            assert chain[0] == "decode_ms" and chain[-1] == "http:sql", chain
+    # the same scan again (another range: the first one's batch is
+    # cached) finds the index built
+    later = _BUCKETED.replace(
+        "FROM cpu", "FROM cpu WHERE time >= '2023-01-01T00:00:00Z'")
+    st, _full = traced(later, "feedc0de0037")
+    assert st["scan_miss"] == 1 and st["scan_plan.indexed_series"] == 6
+    assert "scan_plan.index_builds" not in st
+    # unflushed rows over two of the six: those two are read and merged
+    lines = "\n".join(
+        f"cpu,host=h{i} usage={t}i {1672531200000000000 + t * 10 * 10**9}"
+        for i in range(2) for t in range(300, 310))
+    status, body, _ = http.request("POST", "/api/v1/write?db=public", lines)
+    assert status == 200, body
+    st, _full = traced(later.replace("00:00:00Z", "00:00:10Z"),
+                       "feedc0de0038")
+    assert st["scan_miss"] == 1 and st["scan_plan.merged_series"] == 2
+    assert st["scan_plan.indexed_series"] == 4
+    assert "scan_plan.index_builds" not in st
+    status, body, _ = http.request(
+        "POST", "/api/v1/sql?db=public", "EXPLAIN ANALYZE " + later)
+    assert status == 200, body
+    for key in sections + counts:
+        assert key in stages.STAGE_CATALOG
+        assert key in body or key == "scan_plan.index_builds", (key, body)
+
+
 def _seed_sharded_ints(h, monkeypatch, hosts=16, steps=200):
     """A `WITH SHARD 4` database of two INTEGER fields, flushed, and the
     mesh lane opened to a table this small on four of the virtual devices."""
@@ -903,8 +965,9 @@ def test_traced_request_over_unflushed_rows_holds_the_memcache_stages(http):
     mem = [s for s in spans if s["name"] == "memcache_ms"]
     assert len(mem) >= 2
     for s in mem:
-        chain = _ancestors(s, by_id)
-        assert chain[0] == "decode_ms" and chain[-1] == "http:sql", chain
+        chain = _ancestors(s, by_id)    # the merge is part of the plan
+        assert chain[:2] == ["scan.plan_ms", "decode_ms"] \
+            and chain[-1] == "http:sql", chain
     wait = [s for s in spans if s["name"] == "memcache_wait_ms"]
     assert wait and all(_ancestors(s, by_id)[-1] == "http:sql"
                         for s in wait)
