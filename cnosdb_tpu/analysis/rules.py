@@ -898,7 +898,7 @@ class BackupAccounting(Rule):
     name = "backup-accounting"
     motivation = ("PR 16 disaster-recovery plane: every exit out of the "
                   "archive/backup/restore lanes must book an (op, "
-                  "outcome) into cnosdb_backup_total — an unaccounted "
+                  "outcome) with _count_backup — an unaccounted "
                   "early return makes the RPO/backup telemetry lie, and "
                   "a DR plane that silently skips segments or vnodes is "
                   "discovered exactly when the backup is needed")

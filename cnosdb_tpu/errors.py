@@ -177,8 +177,8 @@ class WriteBackpressure(AdmissionRejected):
     """Write shed by memory backpressure: the broker delayed the write
     waiting for flush progress, the delay budget ran out, and the node
     is still above its soft watermark. HTTP 503 + Retry-After (derived
-    from flush progress) like its parent, but counted separately
-    (cnosdb_requests_backpressured_total) so dashboards can tell a
-    memory squeeze from an admission-queue overflow."""
+    from flush progress) like its parent, with a code of its own so
+    clients can tell a memory squeeze from an admission-queue
+    overflow."""
 
     code = "100004"

@@ -97,16 +97,10 @@ class DeviceBatch:
                     self.field_all_valid[name] = all_valid
                     self.fields[name] = (vt, dev_vals, dev_valid)
                     continue
-                dev_vals = vals if vt != ValueType.BOOLEAN \
-                    else vals.astype(np.int64)
-                all_valid = bool(valid.all())
+                dev_vals, dev_valid, all_valid = _put_column(
+                    vt, vals, valid, self.n_pad)
                 self.field_all_valid[name] = all_valid
-                self.fields[name] = (
-                    vt,
-                    _put(_pad_to(dev_vals, self.n_pad, 0)),
-                    None if all_valid
-                    else _put(_pad_to(valid, self.n_pad, False)),
-                )
+                self.fields[name] = (vt, dev_vals, dev_valid)
             self.est_bytes = self._estimate_bytes()
             _LIVE_BATCHES.add(self)
 
@@ -117,20 +111,23 @@ class DeviceBatch:
         self.n_rows = n
         self.n_pad = pad_rows(max(n, 1))
         self.n_series = batch.n_series
-        self.ts_min = int(batch.ts.min()) if n else 0
-        self.ts_max = int(batch.ts.max()) if n else 0
-        self.epoch_ns = self.ts_min
-        rel = batch.ts - self.epoch_ns
-        # i32 seconds covers ~68 years of batch span; beyond that the host
-        # path handles it (flag checked in _device_eligible)
-        self.i32_ok = n == 0 or bool(rel.max() < (2**31 - 2) * 1_000_000_000)
-        sec = (rel // 1_000_000_000).astype(np.int32)
-        ns = (rel - sec.astype(np.int64) * 1_000_000_000).astype(np.int32)
-        # an optional input is skipped (static kernel flag) when derivable
-        # — a buffer not passed is a buffer not uploaded or kept in HBM:
-        self.ns_all_zero = bool((ns == 0).all())   # second-aligned data
+        with stages.stage("upload.meta_ms"):
+            self.ts_min = int(batch.ts.min()) if n else 0
+            self.ts_max = int(batch.ts.max()) if n else 0
+            self.epoch_ns = self.ts_min
+            rel = batch.ts - self.epoch_ns
+            # i32 seconds covers ~68 years of batch span; beyond that the
+            # host path handles it (flag checked in _device_eligible)
+            self.i32_ok = n == 0 \
+                or bool(rel.max() < (2**31 - 2) * 1_000_000_000)
+            sec = (rel // 1_000_000_000).astype(np.int32)
+            ns = (rel - sec.astype(np.int64) * 1_000_000_000).astype(np.int32)
+            # an optional input is skipped (static kernel flag) when
+            # derivable — a buffer not passed is a buffer not uploaded or
+            # kept in HBM:
+            self.ns_all_zero = bool((ns == 0).all())   # second-aligned data
         self.ts_ns = None if self.ns_all_zero \
-            else _put(_pad_to(ns, self.n_pad, 0))
+            else _put_padded(ns, self.n_pad)
         # Regular-series fast path: when every series is a contiguous run
         # with a constant whole-second stride (the normal telemetry shape),
         # ship ONLY [n_series, 3] params (row_start, sec0, stride_s); the
@@ -146,21 +143,23 @@ class DeviceBatch:
         # on the chip
         if n and self.ns_all_zero and _os.environ.get(
                 "CNOSDB_TPU_REGULAR", "0") == "1":
-            self.series_params = _regular_series_params(
-                batch.sid_ordinal, sec, batch.n_series, self.n_pad)
+            with stages.stage("upload.meta_ms"):
+                self.series_params = _regular_series_params(
+                    batch.sid_ordinal, sec, batch.n_series, self.n_pad)
         if self.series_params is not None:
             self.ts_sec = None
             self.sid_ordinal = None
         else:
-            self.ts_sec = _put(_pad_to(sec, self.n_pad, 0))
-            self.sid_ordinal = _put(_pad_to(batch.sid_ordinal, self.n_pad, 0))
+            self.ts_sec = _put_padded(sec, self.n_pad)
+            self.sid_ordinal = _put_padded(batch.sid_ordinal, self.n_pad)
         # in_rows derives from iota < n_rows inside the kernel (no buffer)
         self.in_rows = None
         # globally unique time-order rank (first/last selection key),
         # shipped lazily — only first/last kernels reference it
-        order = np.argsort(batch.ts, kind="stable")
-        rank = np.empty(n, dtype=np.int32)
-        rank[order] = np.arange(n, dtype=np.int32)
+        with stages.stage("upload.meta_ms"):
+            order = np.argsort(batch.ts, kind="stable")
+            rank = np.empty(n, dtype=np.int32)
+            rank[order] = np.arange(n, dtype=np.int32)
         self._rank_np = rank
         self.rank = None
         self.fields: dict[str, tuple[ValueType, object, object]] = {}
@@ -204,16 +203,8 @@ class EagerUploader:
             return
         try:
             with stages.stage("upload_ms"):
-                dev_vals = vals if vt != ValueType.BOOLEAN \
-                    else vals.astype(np.int64)
-                all_valid = bool(valid.all())
                 self._cols[name] = (
-                    vt,
-                    _put(_pad_to(dev_vals, self.n_pad, 0)),
-                    None if all_valid
-                    else _put(_pad_to(valid, self.n_pad, False)),
-                    all_valid,
-                )
+                    vt, *_put_column(vt, vals, valid, self.n_pad))
         except Exception:
             stages.count_error("scan.eager_upload")
 
@@ -250,7 +241,8 @@ def merged_device_batch(merged, cached, delta,
         sent = n_c + n_d
         g = np.full(db.n_pad, sent, dtype=np.int32)
         g[:merged.n_rows] = append_gather
-        g_dev = _put(g)
+        with stages.stage("upload.put_ms"):
+            g_dev = _put(g)
         pre = getattr(delta, "_preuploaded", None)
         pre_cols = pre[1] if pre is not None else {}
         for name, (vt, vals, valid) in merged.fields.items():
@@ -259,14 +251,10 @@ def merged_device_batch(merged, cached, delta,
             of = old.fields.get(name) if name in cached.fields else None
             if of is None or of[0] != vt or old.n_pad < n_c:
                 # new/retyped column: plain upload of the merged array
-                dev_vals = vals if vt != ValueType.BOOLEAN \
-                    else vals.astype(np.int64)
-                all_valid = bool(valid.all())
+                dev_vals, dev_valid, all_valid = _put_column(
+                    vt, vals, valid, db.n_pad)
                 db.field_all_valid[name] = all_valid
-                db.fields[name] = (
-                    vt, _put(_pad_to(dev_vals, db.n_pad, 0)),
-                    None if all_valid
-                    else _put(_pad_to(valid, db.n_pad, False)))
+                db.fields[name] = (vt, dev_vals, dev_valid)
                 continue
             _vt, old_vals, old_valid = of
             df = delta.fields.get(name)
@@ -285,10 +273,11 @@ def merged_device_batch(merged, cached, delta,
                         n_d, dtype=np.int64 if vt == ValueType.BOOLEAN
                         else vt.numpy_dtype())
                     d_valid = np.zeros(n_d, dtype=bool)
-                d_vals_dev = _put(np.ascontiguousarray(d_vals))
                 d_all_valid = bool(d_valid.all())
-                d_valid_dev = None if d_all_valid \
-                    else _put(np.ascontiguousarray(d_valid))
+                with stages.stage("upload.put_ms"):
+                    d_vals_dev = _put(np.ascontiguousarray(d_vals))
+                    d_valid_dev = None if d_all_valid \
+                        else _put(np.ascontiguousarray(d_valid))
             zero = jnp.zeros(1, dtype=old_vals.dtype)
             cat = jnp.concatenate([old_vals[:n_c], d_vals_dev, zero])
             vals_dev = cat[g_dev]
@@ -337,6 +326,35 @@ def _regular_series_params(sid_ordinal: np.ndarray, sec: np.ndarray,
             stride = 1
         params[s] = (a, seg[0], stride)
     return params
+
+
+def _put_column(vt: ValueType, vals: np.ndarray, valid: np.ndarray,
+                n_pad: int):
+    """One field column → (device values, device validity | None,
+    all_valid): the one staging of a column, shared by DeviceBatch, the
+    eager uploader and the delta merge. The host copies (a BOOLEAN's
+    widening, `valid.all()`, the pads) are `upload.stage_ms`, the puts
+    — values first — `upload.put_ms`; validity is neither padded nor
+    shipped where every row is valid."""
+    with stages.stage("upload.stage_ms"):
+        if vt == ValueType.BOOLEAN:
+            vals = vals.astype(np.int64)
+        all_valid = bool(valid.all())
+        host = [_pad_to(vals, n_pad, 0)]
+        if not all_valid:
+            host.append(_pad_to(valid, n_pad, False))
+    with stages.stage("upload.put_ms"):
+        dev = [_put(a) for a in host]
+    return dev[0], None if all_valid else dev[1], all_valid
+
+
+def _put_padded(a: np.ndarray, n_pad: int):
+    """One per-row i32 array of the batch's meta (seconds, ns remainder,
+    series ordinals) → its zero-padded device twin, split like a column's."""
+    with stages.stage("upload.stage_ms"):
+        host = _pad_to(a, n_pad, 0)
+    with stages.stage("upload.put_ms"):
+        return _put(host)
 
 
 def _pad_to(a: np.ndarray, n: int, fill) -> np.ndarray:

@@ -218,7 +218,9 @@ def launch_fused(dbatch: DeviceBatch, filter_expr: Expr | None,
         args.append(vals)
         if has_valid:
             args.append(valid)
-    with _DISPATCH_LOCK:
+    # the wait for the lock is part of the dispatch: eight vnodes' threads
+    # meet here
+    with stages.stage("kernel.dispatch_ms"), _DISPATCH_LOCK:
         dev_out = fn(*args)
     int_cols = {name for name in present
                 if jnp.issubdtype(dbatch.fields[name][1].dtype, jnp.integer)}

@@ -479,12 +479,17 @@ def aggregate_column_host(values: np.ndarray, valid: np.ndarray,
     # +1: the zero-padded tail is a run of its own
     run_pad = run_pad_for(np_pad, max_runs + 1) if max_runs else 0
     if np_pad != n:
-        values = _pad(values, np_pad)
-        valid = _pad(valid, np_pad, fill=False)
-        seg_ids = _pad(seg_ids, np_pad, fill=0)
-        rank = _pad(rank, np_pad, fill=0)
-    out = segment_aggregate(values, valid, seg_ids, rank,
-                            num_segments=ns_pad, run_pad=run_pad, **wants)
+        with stages.stage("kernel.pad_ms"):
+            values = _pad(values, np_pad)
+            valid = _pad(valid, np_pad, fill=False)
+            seg_ids = _pad(seg_ids, np_pad, fill=0)
+            rank = _pad(rank, np_pad, fill=0)
+    # the call returns once the program is enqueued: numpy arguments are
+    # put on the device inside it, and a new shape compiles here
+    with stages.stage("kernel.dispatch_ms"):
+        out = segment_aggregate(values, valid, seg_ids, rank,
+                                num_segments=ns_pad, run_pad=run_pad,
+                                **wants)
     with stages.stage("kernel.fetch_ms"):
         host = {k: np.asarray(v) for k, v in out.items()}  # lint: disable=host-sync (THE audited transfer point: one batched pull per aggregate call)
     note_run_path(host.pop("by_runs", None))
@@ -499,8 +504,10 @@ def note_run_path(by_runs) -> None:
     "by_runs" flag of local_segment_partials, None where the path was not
     compiled in."""
     if by_runs is not None:
-        stages.count("segment_runs.engaged" if by_runs
-                     else "segment_runs.fallback")
+        # both keys a launch: a request that never fell back reads 0,
+        # not nothing
+        stages.count("segment_runs.engaged", int(bool(by_runs)))
+        stages.count("segment_runs.fallback", int(not by_runs))
 
 
 def _pad(a: np.ndarray, n: int, fill=0):

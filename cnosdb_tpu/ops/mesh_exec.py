@@ -304,228 +304,234 @@ def _build_prep(live, query, m, n_dev):
             return hit[1]
 
     with stages.stage("mesh.plan_ms"):
-        masks = [host_row_mask(b, query.filter) for b in live]
-        keep = [i for i, (b, mk) in enumerate(zip(live, masks))
-                if mk is None or mk.any()]
-        live = [live[i] for i in keep]
-        masks = [masks[i] for i in keep]
+        with stages.stage("mesh.mask_ms"):
+            masks = [host_row_mask(b, query.filter) for b in live]
+            keep = [i for i, (b, mk) in enumerate(zip(live, masks))
+                    if mk is None or mk.any()]
+            live = [live[i] for i in keep]
+            masks = [masks[i] for i in keep]
         if not live:
             prep = {"n_out": 0, "est_bytes": 0}
             return prep
-        layouts = [host_group_layout(b, query.group_tags,
-                                     query.group_fields, query.time_bucket)
-                   for b in live]
+        with stages.stage("mesh.layout_ms"):
+            layouts = [host_group_layout(b, query.group_tags,
+                                         query.group_fields, query.time_bucket)
+                       for b in live]
 
-        # ---- global tag groups: glab insertion order is batch-major over
-        # each batch's local label table — _merge_results_vec's exact rule
-        glab: dict[tuple, int] = {}
-        tag_luts = []
-        for hl in layouts:
-            lut = np.empty(len(hl.group_labels), dtype=np.int64)
-            for i, lab in enumerate(hl.group_labels):
-                lut[i] = glab.setdefault(lab, len(glab))
-            tag_luts.append(lut)
-        lab_table = [None] * len(glab)
-        for lab, g in glab.items():
-            lab_table[g] = lab
+            # ---- global tag groups: glab insertion order is batch-major
+            # over each batch's local label table — _merge_results_vec's
+            # exact rule
+            glab: dict[tuple, int] = {}
+            tag_luts = []
+            for hl in layouts:
+                lut = np.empty(len(hl.group_labels), dtype=np.int64)
+                for i, lab in enumerate(hl.group_labels):
+                    lut[i] = glab.setdefault(lab, len(glab))
+                tag_luts.append(lut)
+            lab_table = [None] * len(glab)
+            for lab, g in glab.items():
+                lab_table[g] = lab
 
-        # ---- global field-group dictionaries (one per GROUP BY field)
-        n_gf = len(query.group_fields)
-        gdicts: list[dict] = [{} for _ in range(n_gf)]
-        gvals: list[list] = [[] for _ in range(n_gf)]
-        for hl in layouts:
-            for fi in range(n_gf):
-                for v in hl.gf_dicts[fi]:
-                    ck = _canon(v)
-                    if ck not in gdicts[fi]:
-                        gdicts[fi][ck] = len(gdicts[fi])
-                        gvals[fi].append(v)
-        gdims = [len(d) + 1 for d in gdicts]   # +1: the NULL group slot
-        gf_luts = []
-        for hl in layouts:
-            per_field = []
-            for fi in range(n_gf):
-                local = hl.gf_dicts[fi]
-                lut = np.empty(len(local) + 1, dtype=np.int64)
-                for i, v in enumerate(local):
-                    lut[i] = gdicts[fi][_canon(v)]
-                lut[len(local)] = gdims[fi] - 1   # local NULL → global NULL
-                per_field.append(lut)
-            gf_luts.append(per_field)
+            # ---- global field-group dictionaries (one per GROUP BY field)
+            n_gf = len(query.group_fields)
+            gdicts: list[dict] = [{} for _ in range(n_gf)]
+            gvals: list[list] = [[] for _ in range(n_gf)]
+            for hl in layouts:
+                for fi in range(n_gf):
+                    for v in hl.gf_dicts[fi]:
+                        ck = _canon(v)
+                        if ck not in gdicts[fi]:
+                            gdicts[fi][ck] = len(gdicts[fi])
+                            gvals[fi].append(v)
+            gdims = [len(d) + 1 for d in gdicts]   # +1: the NULL group slot
+            gf_luts = []
+            for hl in layouts:
+                per_field = []
+                for fi in range(n_gf):
+                    local = hl.gf_dicts[fi]
+                    lut = np.empty(len(local) + 1, dtype=np.int64)
+                    for i, v in enumerate(local):
+                        lut[i] = gdicts[fi][_canon(v)]
+                    # local NULL → global NULL
+                    lut[len(local)] = gdims[fi] - 1
+                    per_field.append(lut)
+                gf_luts.append(per_field)
 
-        n_groups = max(len(glab), 1)
-        for d in gdims:
-            n_groups *= d
+            n_groups = max(len(glab), 1)
+            for d in gdims:
+                n_groups *= d
 
-        # ---- per-batch decode: local seg → (tag gid, field codes, bucket)
-        per_batch_gid = []
-        per_batch_bstart = []
-        for bi, (b, hl) in enumerate(zip(live, layouts)):
-            seg = hl.seg_ids.astype(np.int64)
-            grp = seg // hl.n_buckets
-            codes = []
-            for fi in range(n_gf - 1, -1, -1):
-                dim = hl.gf_dims[fi]
-                codes.append(grp % dim)
-                grp //= dim
-            g = tag_luts[bi][grp]
-            for fi in range(n_gf):
-                g = g * gdims[fi] + gf_luts[bi][fi][codes[n_gf - 1 - fi]]
-            per_batch_gid.append(g)
-            if query.time_bucket is not None:
-                per_batch_bstart.append(
-                    hl.bucket_starts[seg % hl.n_buckets])
-            else:
-                per_batch_bstart.append(None)
-
-        # ---- global bucket times: sorted union of PRESENT bucket starts
-        if query.time_bucket is not None:
-            parts = []
-            for bs, mk in zip(per_batch_bstart, masks):
-                parts.append(np.unique(bs if mk is None else bs[mk]))
-            utimes = np.unique(np.concatenate(parts))
-            n_t = len(utimes)
-        else:
-            utimes, n_t = None, 1
-        n_seg = n_groups * n_t
-        seg_pad = pad_segments(n_seg)
-        if n_dev * slots * seg_pad > _MAX_FOLD_CELLS \
-                or slots * seg_pad > np.iinfo(np.int32).max:
-            return None
-
-        # ---- per-row global segment ids + presence
-        presence = np.zeros(n_seg, dtype=np.int64)
-        gsegs = []
-        for g, bs, mk in zip(per_batch_gid, per_batch_bstart, masks):
-            gs = g * n_t
-            if bs is not None:
-                gs = gs + np.searchsorted(utimes, bs)
-            gsegs.append(gs)
-            presence += np.bincount(gs if mk is None else gs[mk],
-                                    minlength=n_seg)
-
-        # ---- global time-order rank (first/last tie-breaking: timestamp,
-        # then batch order, then row order — the stable argsort of the
-        # batch-order concatenation encodes all three)
-        if needs_rank:
-            cts = np.concatenate([b.ts for b in live])
-            order = np.argsort(cts, kind="stable")
-            grank = np.empty(len(cts), dtype=np.int32)
-            grank[order] = np.arange(len(cts), dtype=np.int32)
-            sorted_ts = cts[order]
-        else:
-            grank = sorted_ts = None
-
-        # ---- shard-major padded layout: batch i → shard i//slots
-        shard_rows = [0] * n_dev
-        for i, b in enumerate(live):
-            shard_rows[i // slots] += b.n_rows
-        row_pad = pad_rows(max(max(shard_rows), 1))
-        total = n_dev * row_pad
-        # a bound on a shard's contiguous equal-segment runs, from the
-        # plan alone: every series of a series-major, time-ascending batch
-        # passes each bucket once (+1: the zero-padded tail). String-field
-        # group keys shred that structure: no bound, the row scatter.
-        row_runs = [1] * n_dev
-        for i, b in enumerate(live):
-            row_runs[i // slots] += max(b.n_series, 1) * n_t
-        row_run_pad = 0 if n_gf else run_pad_for(row_pad, max(row_runs))
-        seg_arr = np.zeros(total, dtype=np.int32)
-        base_valid = np.zeros(total, dtype=bool)
-        rank_arr = np.zeros(total, dtype=np.int32)
-        col_host: dict[str, tuple] = {}
-        for c in wants:
-            vt = ValueType.INTEGER if c == "time" else live[0].fields[c][0]
-            dt = np.int64 if vt == ValueType.INTEGER else np.float64
-            col_host[c] = (vt, np.zeros(total, dtype=dt),
-                           np.zeros(total, dtype=bool))
-        cursor = [0] * n_dev
-        concat_off = 0
-        placements = []   # (batch idx, shard, slot, dest row offset)
-        for i, b in enumerate(live):
-            sh, slot = divmod(i, slots)
-            d0 = sh * row_pad + cursor[sh]
-            d1 = d0 + b.n_rows
-            cursor[sh] += b.n_rows
-            placements.append((i, sh, slot, d0))
-            seg_arr[d0:d1] = (slot * seg_pad + gsegs[i]).astype(np.int32)
-            mk = masks[i]
-            base_valid[d0:d1] = True if mk is None else mk
-            if grank is not None:
-                rank_arr[d0:d1] = grank[concat_off:concat_off + b.n_rows]
-            for c, (vt, vals, cvalid) in col_host.items():
-                if c == "time":
-                    vals[d0:d1] = b.ts
-                    cvalid[d0:d1] = base_valid[d0:d1]
+            # ---- per-batch decode: local seg → (tag gid, field codes,
+            # bucket)
+            per_batch_gid = []
+            per_batch_bstart = []
+            for bi, (b, hl) in enumerate(zip(live, layouts)):
+                seg = hl.seg_ids.astype(np.int64)
+                grp = seg // hl.n_buckets
+                codes = []
+                for fi in range(n_gf - 1, -1, -1):
+                    dim = hl.gf_dims[fi]
+                    codes.append(grp % dim)
+                    grp //= dim
+                g = tag_luts[bi][grp]
+                for fi in range(n_gf):
+                    g = g * gdims[fi] + gf_luts[bi][fi][codes[n_gf - 1 - fi]]
+                per_batch_gid.append(g)
+                if query.time_bucket is not None:
+                    per_batch_bstart.append(
+                        hl.bucket_starts[seg % hl.n_buckets])
                 else:
-                    f = b.fields.get(c)
-                    if f is not None:
-                        vals[d0:d1] = np.asarray(f[1])
-                        cvalid[d0:d1] = base_valid[d0:d1] & f[2]
-            concat_off += b.n_rows
+                    per_batch_bstart.append(None)
 
-        # ---- f64 sum run plans: the legacy CPU host kernels are
-        # run-aware (ufunc.reduceat per contiguous equal-segment run, run
-        # partials folded per segment in run order), and reduceat's
-        # within-run association is numpy's pairwise reduce — no device
-        # scatter order reproduces it. So replicate the per-batch branch
-        # decision tpu_exec.launch_scan_aggregate makes, stage the
-        # per-run reduceat partials with the SAME numpy call, and let the
-        # kernel fold runs → segments → shards on the mesh. Batches the
-        # legacy path sums flat stage one run per row (bincount is a
-        # sequential C loop, so row-order is exact for those). Integer
-        # sums and every other aggregate are order-exact as flat scatters.
-        run_host: dict[str, tuple] = {}
-        from .placement import scan_device
-        from .tpu_exec import _FORCE_DEVICE, _ordered_within_series
-        cpu_mode = scan_device().platform == "cpu" and not _FORCE_DEVICE()
-        if cpu_mode:
-            ordered = [_ordered_within_series(b) for b in live]
-            for c, (vt, _vals, _cvalid) in col_host.items():
-                if "sum" not in wants[c] or vt != ValueType.FLOAT:
-                    continue
-                col_fl = bool({"first", "last"} & wants[c])
-                plans = []
-                for i, b in enumerate(live):
-                    plans.append(_legacy_sum_runs(
-                        b, gsegs[i], masks[i], b.fields[c][2], col_fl,
-                        needs_rank, ordered[i],
-                        bool(layouts[i].gf_dims)))
-                if not any(p is not None for p in plans):
-                    continue   # every batch sums flat: one-level is exact
-                nruns = []
-                for bi, p in enumerate(plans):
-                    if p is None:   # flat batch → one run per summed row
-                        b = live[bi]
-                        mk = masks[bi]
-                        inc = b.fields[c][2] if mk is None \
-                            else (mk & b.fields[c][2])
-                        rows = np.flatnonzero(inc)
-                        starts = np.arange(len(rows), dtype=np.int64)
-                        plans[bi] = (rows, starts)
-                    nruns.append(len(plans[bi][1]))
-                shard_runs = [0] * n_dev
-                for (i, sh, slot, d0), nr in zip(placements, nruns):
-                    shard_runs[sh] += nr
-                run_pad = max(max(shard_runs), 1)
-                run_sums = np.zeros(n_dev * run_pad, dtype=np.float64)
-                run_segs = np.full(n_dev * run_pad, slots * seg_pad,
-                                   dtype=np.int32)
-                cur_r = [0] * n_dev
-                for (i, sh, slot, d0), p in zip(placements, plans):
-                    rows, starts = p
-                    b = live[i]
-                    cv = np.asarray(b.fields[c][1])
-                    sub = cv if rows is None else cv[rows]
-                    nr = len(starts)
-                    if nr == 0:
+            # ---- global bucket times: sorted union of PRESENT bucket starts
+            if query.time_bucket is not None:
+                parts = []
+                for bs, mk in zip(per_batch_bstart, masks):
+                    parts.append(np.unique(bs if mk is None else bs[mk]))
+                utimes = np.unique(np.concatenate(parts))
+                n_t = len(utimes)
+            else:
+                utimes, n_t = None, 1
+            n_seg = n_groups * n_t
+            seg_pad = pad_segments(n_seg)
+            if n_dev * slots * seg_pad > _MAX_FOLD_CELLS \
+                    or slots * seg_pad > np.iinfo(np.int32).max:
+                return None
+
+            # ---- per-row global segment ids + presence
+            presence = np.zeros(n_seg, dtype=np.int64)
+            gsegs = []
+            for g, bs, mk in zip(per_batch_gid, per_batch_bstart, masks):
+                gs = g * n_t
+                if bs is not None:
+                    gs = gs + np.searchsorted(utimes, bs)
+                gsegs.append(gs)
+                presence += np.bincount(gs if mk is None else gs[mk],
+                                        minlength=n_seg)
+
+            # ---- global time-order rank (first/last tie-breaking: timestamp,
+            # then batch order, then row order — the stable argsort of the
+            # batch-order concatenation encodes all three)
+            if needs_rank:
+                cts = np.concatenate([b.ts for b in live])
+                order = np.argsort(cts, kind="stable")
+                grank = np.empty(len(cts), dtype=np.int32)
+                grank[order] = np.arange(len(cts), dtype=np.int32)
+                sorted_ts = cts[order]
+            else:
+                grank = sorted_ts = None
+
+        with stages.stage("mesh.stage_ms"):
+            # ---- shard-major padded layout: batch i → shard i//slots
+            shard_rows = [0] * n_dev
+            for i, b in enumerate(live):
+                shard_rows[i // slots] += b.n_rows
+            row_pad = pad_rows(max(max(shard_rows), 1))
+            total = n_dev * row_pad
+            # a bound on a shard's contiguous equal-segment runs, from the
+            # plan alone: every series of a series-major, time-ascending batch
+            # passes each bucket once (+1: the zero-padded tail). String-field
+            # group keys shred that structure: no bound, the row scatter.
+            row_runs = [1] * n_dev
+            for i, b in enumerate(live):
+                row_runs[i // slots] += max(b.n_series, 1) * n_t
+            row_run_pad = 0 if n_gf else run_pad_for(row_pad, max(row_runs))
+            seg_arr = np.zeros(total, dtype=np.int32)
+            base_valid = np.zeros(total, dtype=bool)
+            rank_arr = np.zeros(total, dtype=np.int32)
+            col_host: dict[str, tuple] = {}
+            for c in wants:
+                vt = ValueType.INTEGER if c == "time" else live[0].fields[c][0]
+                dt = np.int64 if vt == ValueType.INTEGER else np.float64
+                col_host[c] = (vt, np.zeros(total, dtype=dt),
+                               np.zeros(total, dtype=bool))
+            cursor = [0] * n_dev
+            concat_off = 0
+            placements = []   # (batch idx, shard, slot, dest row offset)
+            for i, b in enumerate(live):
+                sh, slot = divmod(i, slots)
+                d0 = sh * row_pad + cursor[sh]
+                d1 = d0 + b.n_rows
+                cursor[sh] += b.n_rows
+                placements.append((i, sh, slot, d0))
+                seg_arr[d0:d1] = (slot * seg_pad + gsegs[i]).astype(np.int32)
+                mk = masks[i]
+                base_valid[d0:d1] = True if mk is None else mk
+                if grank is not None:
+                    rank_arr[d0:d1] = grank[concat_off:concat_off + b.n_rows]
+                for c, (vt, vals, cvalid) in col_host.items():
+                    if c == "time":
+                        vals[d0:d1] = b.ts
+                        cvalid[d0:d1] = base_valid[d0:d1]
+                    else:
+                        f = b.fields.get(c)
+                        if f is not None:
+                            vals[d0:d1] = np.asarray(f[1])
+                            cvalid[d0:d1] = base_valid[d0:d1] & f[2]
+                concat_off += b.n_rows
+
+            # ---- f64 sum run plans: the legacy CPU host kernels are
+            # run-aware (ufunc.reduceat per contiguous equal-segment run, run
+            # partials folded per segment in run order), and reduceat's
+            # within-run association is numpy's pairwise reduce — no device
+            # scatter order reproduces it. So replicate the per-batch branch
+            # decision tpu_exec.launch_scan_aggregate makes, stage the
+            # per-run reduceat partials with the SAME numpy call, and let the
+            # kernel fold runs → segments → shards on the mesh. Batches the
+            # legacy path sums flat stage one run per row (bincount is a
+            # sequential C loop, so row-order is exact for those). Integer
+            # sums and every other aggregate are order-exact as flat scatters.
+            run_host: dict[str, tuple] = {}
+            from .placement import scan_device
+            from .tpu_exec import _FORCE_DEVICE, _ordered_within_series
+            cpu_mode = scan_device().platform == "cpu" and not _FORCE_DEVICE()
+            if cpu_mode:
+                ordered = [_ordered_within_series(b) for b in live]
+                for c, (vt, _vals, _cvalid) in col_host.items():
+                    if "sum" not in wants[c] or vt != ValueType.FLOAT:
                         continue
-                    off = sh * run_pad + cur_r[sh]
-                    cur_r[sh] += nr
-                    run_sums[off:off + nr] = np.add.reduceat(sub, starts)
-                    gs = gsegs[i] if rows is None else gsegs[i][rows]
-                    run_segs[off:off + nr] = slot * seg_pad + gs[starts]
-                run_host[c] = (run_sums, run_segs, run_pad)
+                    col_fl = bool({"first", "last"} & wants[c])
+                    plans = []
+                    for i, b in enumerate(live):
+                        plans.append(_legacy_sum_runs(
+                            b, gsegs[i], masks[i], b.fields[c][2], col_fl,
+                            needs_rank, ordered[i],
+                            bool(layouts[i].gf_dims)))
+                    if not any(p is not None for p in plans):
+                        continue   # every batch sums flat: one-level is exact
+                    nruns = []
+                    for bi, p in enumerate(plans):
+                        if p is None:   # flat batch → one run per summed row
+                            b = live[bi]
+                            mk = masks[bi]
+                            inc = b.fields[c][2] if mk is None \
+                                else (mk & b.fields[c][2])
+                            rows = np.flatnonzero(inc)
+                            starts = np.arange(len(rows), dtype=np.int64)
+                            plans[bi] = (rows, starts)
+                        nruns.append(len(plans[bi][1]))
+                    shard_runs = [0] * n_dev
+                    for (i, sh, slot, d0), nr in zip(placements, nruns):
+                        shard_runs[sh] += nr
+                    run_pad = max(max(shard_runs), 1)
+                    run_sums = np.zeros(n_dev * run_pad, dtype=np.float64)
+                    run_segs = np.full(n_dev * run_pad, slots * seg_pad,
+                                       dtype=np.int32)
+                    cur_r = [0] * n_dev
+                    for (i, sh, slot, d0), p in zip(placements, plans):
+                        rows, starts = p
+                        b = live[i]
+                        cv = np.asarray(b.fields[c][1])
+                        sub = cv if rows is None else cv[rows]
+                        nr = len(starts)
+                        if nr == 0:
+                            continue
+                        off = sh * run_pad + cur_r[sh]
+                        cur_r[sh] += nr
+                        run_sums[off:off + nr] = np.add.reduceat(sub, starts)
+                        gs = gsegs[i] if rows is None else gsegs[i][rows]
+                        run_segs[off:off + nr] = slot * seg_pad + gs[starts]
+                    run_host[c] = (run_sums, run_segs, run_pad)
 
     with stages.stage("mesh.upload_ms"):
         spec = P(SHARD_AXIS)

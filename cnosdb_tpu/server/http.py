@@ -240,18 +240,8 @@ class HttpServer:
             dl.cancel("client disconnected")
             raise
         except CnosError as e:
-            self.metrics.incr("cnosdb_http_write_errors_total")
             if isinstance(e, DeadlineExceeded):
                 self.metrics.incr("cnosdb_requests_deadline_exceeded_total")
-            from ..errors import MemoryExceeded, WriteBackpressure
-
-            # memory-ladder outcomes get their own counters: 503-with-
-            # Retry-After (flushes draining, retry helps) vs 413 (the
-            # write itself is too big / node fail-closed over hard)
-            if isinstance(e, WriteBackpressure):
-                self.metrics.incr("cnosdb_requests_backpressured_total")
-            elif isinstance(e, MemoryExceeded):
-                self.metrics.incr("cnosdb_requests_memory_exceeded_total")
             return _err_response(_status_for(e), e)
         self.metrics.incr("cnosdb_http_writes_total")
         self.metrics.incr("cnosdb_http_points_written_total", n_rows)
@@ -356,10 +346,6 @@ class HttpServer:
             self.metrics.incr("cnosdb_http_sql_errors_total")
             if isinstance(e, DeadlineExceeded):
                 self.metrics.incr("cnosdb_requests_deadline_exceeded_total")
-            from ..errors import MemoryExceeded
-
-            if isinstance(e, MemoryExceeded):
-                self.metrics.incr("cnosdb_requests_memory_exceeded_total")
             return _err_response(_status_for(e), e)
         except Exception as e:
             span.finish(f"{type(e).__name__}: {e}")
@@ -622,7 +608,6 @@ class HttpServer:
                     session.tenant, session.database, batch))
         except CnosError as e:
             return _err_response(_status_for(e), e)
-        self.metrics.incr("cnosdb_prom_write_points_total", batch.n_rows())
         return web.Response(status=204)
 
     async def handle_prom_read(self, request):
@@ -760,10 +745,7 @@ class HttpServer:
                 None, lambda: self.coord.write_points(
                     session.tenant, session.database, batch))
         except CnosError as e:
-            self.metrics.incr("cnosdb_es_bulk_errors_total")
             return _err_response(_status_for(e), e)
-        self.metrics.incr("cnosdb_es_bulk_writes_total")
-        self.metrics.incr("cnosdb_es_bulk_points_written_total", batch.n_rows())
         return web.json_response({"errors": False, "items": batch.n_rows()})
 
     # --------------------------------------------------- traces (OTLP in)
@@ -1010,15 +992,11 @@ class HttpServer:
             area, _, what = name.partition(".")
             self.metrics.set_gauge("cnosdb_errors_total", n,
                                    area=area, kind=what or area)
-        # shared scan/decode pool health: live task counts + pool widths
+        # shared scan/decode pool health: live task counts
         for name, n in executor.active_counts().items():
             self.metrics.set_gauge("cnosdb_scan_executor_active", n,
                                    pool=name)
-        for name, n in executor.pool_sizes().items():
-            self.metrics.set_gauge("cnosdb_scan_executor_threads", n,
-                                   pool=name)
-        entries, nbytes = self.coord.scan_cache_stats()
-        self.metrics.set_gauge("cnosdb_scan_cache_entries", entries)
+        _entries, nbytes = self.coord.scan_cache_stats()
         self.metrics.set_gauge("cnosdb_scan_cache_bytes", nbytes)
         # request-lifecycle plane: admission gate counters + queue gauges
         # (cnosdb_requests_deadline_exceeded_total is a true counter,
@@ -1062,19 +1040,14 @@ class HttpServer:
 
         for name, n in _group_agg.counters_snapshot().items():
             self.metrics.set_gauge("cnosdb_group_agg_total", n, kind=name)
-        # memory-governance plane: per-(pool, action) ladder totals +
-        # live pool bytes (see /debug/memory for the full snapshot)
+        # memory-governance plane: per-(pool, action) ladder totals
+        # (live pool bytes: /debug/memory)
         from . import memory as _memory
 
         if _memory.enabled():
             for (pool, action), n in _memory.counters_snapshot().items():
                 self.metrics.set_counter("cnosdb_memory_total", n,
                                          pool=pool, action=action)
-            for pool, b in _memory.BROKER.usage().items():
-                self.metrics.set_gauge("cnosdb_memory_pool_bytes", b,
-                                       pool=pool)
-            self.metrics.set_gauge("cnosdb_memory_budget_bytes",
-                                   _memory.BROKER.total())
         # invariant plane: lock-order watchdog counters (all zero unless
         # the node runs with CNOSDB_LOCKWATCH=1; order_cycles > 0 means a
         # potential deadlock was observed — see /debug/lockgraph)
@@ -1089,8 +1062,6 @@ class HttpServer:
 
         _tx = _sys.modules.get("cnosdb_tpu.ops.tpu_exec")
         if _tx is not None:
-            self.metrics.set_gauge("cnosdb_agg_memo_bytes",
-                                   _tx.memo_bytes())
             for name, n in _tx.memo_counters_snapshot().items():
                 self.metrics.set_gauge("cnosdb_agg_memo_total", n,
                                        kind=name)
@@ -1135,43 +1106,26 @@ class HttpServer:
                 self.metrics.set_gauge("cnosdb_matview_total", n,
                                        kind=name)
         # cold-tier plane: per-(lane, reason) tier/fetch/prune/cache
-        # outcomes plus the block cache's live size — only when the
-        # tiering module is resident (nothing cold has happened otherwise)
+        # outcomes — only when the tiering module is resident (nothing
+        # cold has happened otherwise)
         _ct = _sys.modules.get("cnosdb_tpu.storage.tiering")
         if _ct is not None:
             for (lane, reason), n in _ct.cold_tier_snapshot().items():
                 self.metrics.set_counter("cnosdb_cold_tier_total", n,
                                          lane=lane, reason=reason)
-            bc = _ct.block_cache_stats()
-            self.metrics.set_gauge("cnosdb_cold_block_cache_bytes",
-                                   bc["bytes"])
-            self.metrics.set_gauge("cnosdb_cold_block_cache_entries",
-                                   bc["entries"])
-        # serving plane: per-(layer, outcome) cache/batch counters plus
-        # live cache sizes — only when the plane is resident
-        # (CNOSDB_SERVING=0 never imports it)
+        # serving plane: per-(layer, outcome) cache/batch counters — only
+        # when the plane is resident (CNOSDB_SERVING=0 never imports it)
         _sv = _sys.modules.get("cnosdb_tpu.server.serving")
         if _sv is not None:
             for (layer, outcome), n in _sv.counters_snapshot().items():
                 self.metrics.set_counter("cnosdb_serving_total", n,
                                          layer=layer, outcome=outcome)
-            for cache, (entries, nbytes) in _sv.cache_stats().items():
-                self.metrics.set_gauge(f"cnosdb_serving_{cache}_entries",
-                                       entries)
-                if cache == "result_cache":
-                    self.metrics.set_gauge(
-                        f"cnosdb_serving_{cache}_bytes", nbytes)
-            for width, n in _sv.width_histogram().items():
-                self.metrics.set_counter("cnosdb_serving_batch_width_total",
-                                         n, width=str(width))
-        # disaster-recovery plane: per-(op, outcome) archive/backup/
-        # restore counters plus the RPO gauge (age of the oldest sealed-
-        # but-unarchived WAL segment) — resident only once configured
+        # disaster-recovery plane: the RPO gauge (age of the oldest
+        # sealed-but-unarchived WAL segment) — resident only once
+        # configured; the per-(op, outcome) counters are on the admin
+        # backup page
         _bk = _sys.modules.get("cnosdb_tpu.storage.backup")
         if _bk is not None and _bk.archive_enabled():
-            for (op, outcome), n in _bk.backup_snapshot().items():
-                self.metrics.set_counter("cnosdb_backup_total", n,
-                                         op=op, outcome=outcome)
             self.metrics.set_gauge("cnosdb_backup_archive_lag_seconds",
                                    _bk.archive_lag_seconds())
         # gray-failure plane: hedge outcomes (fired/won/lost/cancelled/
@@ -1233,8 +1187,6 @@ class HttpServer:
                         await loop.run_in_executor(
                             None, lambda b=batch: self.coord.write_points(
                                 DEFAULT_TENANT, "public", b))
-                        self.metrics.incr("cnosdb_tcp_opentsdb_points_total",
-                                          batch.n_rows())
                     except CnosError as e:
                         writer.write(f"error: {e}\n".encode())
                         await writer.drain()

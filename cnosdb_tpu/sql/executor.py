@@ -4049,7 +4049,6 @@ class GroupSpiller:
         self._epoch += 1
         self.spill_count += 1
         memgov.count("query_groups", "spill")
-        stages.count("group_spill", 1)
         acc.clear()
         if self._booked:
             memgov.unbook("query_groups", self._booked)

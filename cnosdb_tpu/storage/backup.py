@@ -34,7 +34,8 @@ Three lanes, one store, laid out under the ``wal_archive_uri`` prefix:
   seq > flushed_seq and append-ts ≤ T.
 
 Every exit out of the archive/backup/restore lanes books an
-``cnosdb_backup_total{op,outcome}`` reason (``backup-accounting`` lint);
+``(op, outcome)`` reason (``backup_snapshot()``, shown under
+``counters`` on the admin backup page; ``backup-accounting`` lint);
 fault points ``backup.archive`` / ``backup.manifest`` /
 ``restore.install`` ride the chaos sweep like every other node point.
 """
@@ -117,7 +118,7 @@ def _manifest_key(prefix: str, owner: str, backup_id: str) -> str:
 
 
 # ---------------------------------------------------------------------------
-# accounting — cnosdb_backup_total{op,outcome}
+# accounting — (op, outcome) counts, read by backup_snapshot()
 # ---------------------------------------------------------------------------
 _counts_lock = lockwatch.Lock("backup.counters")
 _counts: dict[tuple[str, str], int] = {}

@@ -1147,8 +1147,7 @@ def _scan_vnode_native(vnode: VnodeCut, table: str,
         total = int(rows.sum())
         stages.count("scan_plan.indexed_series",
                      int(np.count_nonzero(rows)) - len(spliced))
-        if n_merged:
-            stages.count("scan_plan.merged_series", n_merged)
+        stages.count("scan_plan.merged_series", n_merged)
         for fi, f in enumerate(pfiles):
             of = slice(None) if len(pfiles) == 1 \
                 else np.flatnonzero(pages.file == fi)
@@ -1230,18 +1229,19 @@ def _scan_vnode_native(vnode: VnodeCut, table: str,
                 first_seen, key=lambda n: (first_seen[n][0], rank[n]))}
 
         # ------------------------------------------------------- allocate
-        ts_all = np.empty(total, dtype=np.int64)
         numeric_cols: dict[str, tuple[np.ndarray, np.ndarray]] = {}
         string_parts: dict[str, list] = {}
         string_valid: dict[str, np.ndarray] = {}
-        for name, vt in ftypes.items():
-            if vt in (ValueType.STRING, ValueType.GEOMETRY):
-                string_parts[name] = []
-                string_valid[name] = np.zeros(total, dtype=bool)
-                continue
-            dt = vt.numpy_dtype()
-            numeric_cols[name] = (np.zeros(total, dtype=dt),
-                                  np.zeros(total, dtype=bool))
+        with stages.stage("scan.alloc_ms"):
+            ts_all = np.empty(total, dtype=np.int64)
+            for name, vt in ftypes.items():
+                if vt in (ValueType.STRING, ValueType.GEOMETRY):
+                    string_parts[name] = []
+                    string_valid[name] = np.zeros(total, dtype=bool)
+                    continue
+                dt = vt.numpy_dtype()
+                numeric_cols[name] = (np.zeros(total, dtype=dt),
+                                      np.zeros(total, dtype=bool))
 
         # --------------------------------------- descriptors per (file, col)
         # one native task a (file, column): (group, column | None for the

@@ -630,12 +630,14 @@ class TsmReader:
         reader: the file never changes. Two scans may build it at once;
         both build the same, and one of the two is kept."""
         index = self._page_indexes.get(table)
-        if index is None:
+        built = index is None
+        if built:
             g = self.groups.get(table)
             if g is None or not g.chunks:
                 return None
-            stages.count("scan_plan.index_builds")
             index = self._page_indexes.setdefault(table, PageIndex(g))
+        # booked where it is found too: a warmed store reads 0, not nothing
+        stages.count("scan_plan.index_builds", int(built))
         return index
 
     def maybe_contains_series(self, series_id: int) -> bool:

@@ -20,6 +20,7 @@ share an axis; `duration_ns` is a difference of the monotonic
 from __future__ import annotations
 
 import contextvars
+import random
 import secrets
 import time
 from contextlib import contextmanager
@@ -27,6 +28,19 @@ from contextlib import contextmanager
 from . import lockwatch
 
 TRACE_HEADER = "cnos-trace-id"
+
+# A span id names a span inside its trace: an identifier, not a secret. It
+# is drawn in user space, from a generator seeded once from the OS —
+# `secrets.token_hex` is a system call that releases the GIL, a traced
+# request opens 45–150 stage spans, and beside the two thread-clock reads
+# of a traced stage it was a third of what the stage cost on the chip's
+# host and a GIL hand-over a span under concurrent clients (PERF.md §6,
+# PR 37)
+_span_ids = random.Random()
+
+
+def _new_span_id() -> str:
+    return "%08x" % _span_ids.getrandbits(32)
 
 _current_span: contextvars.ContextVar = contextvars.ContextVar(
     "cnos_current_span", default=None)
@@ -128,7 +142,7 @@ class TraceCollector:
             parent_id = cur.span_id
         if trace_id is None:
             trace_id = secrets.token_hex(8)
-        return Span(trace_id, secrets.token_hex(4), parent_id, name, self)
+        return Span(trace_id, _new_span_id(), parent_id, name, self)
 
     def from_headers(self, headers, name: str) -> Span:
         """Continue a trace propagated over HTTP/RPC: header value is

@@ -28,7 +28,20 @@ sum — a child `Span` of the context span in the one collector
 the work (so the interval lies in a profiler trace, on the device
 operations' clock), and a bare (start, end) pair the profile holds until
 `finish()` has derived `untraced_ms` from it. With the flag off a stage
-costs what it always did plus one attribute read.
+is one `perf_counter` pair and one `add_ms` (`_Stage`), and reads no
+other clock.
+
+Work told from wait: a traced stage also reads `time.thread_time()` at
+its two ends and books the difference under `cpu.<stage>` (ms, beside
+the stage wherever a stage is shown, and as the span's `cpu_ms` tag).
+That is the CPU time of the thread that entered the stage, native code
+it calls with the GIL released included; `wall - cpu` is time that
+thread waited — for the GIL, a lock, the device, a pool's result or the
+disk. A stage that blocks on pool tasks (`scan.native_ms`, `kernel_ms`
+around the fan-out) therefore reads ~0 CPU, and the tasks' own stages
+carry theirs. Like the walls, `cpu.*` sums over every thread that
+entered the stage. `book()` books none (another thread's interval), and
+a request that is not traced reads no thread clock.
 
 Stage catalog — every *literal* name passed to stage()/count() must
 appear in STAGE_CATALOG (enforced by the `stage-catalog` lint rule in
@@ -43,7 +56,7 @@ import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import nullcontext
 from . import lockwatch, spans
 
 # The documented profile schema. A name missing here is invisible to
@@ -62,6 +75,9 @@ STAGE_CATALOG: dict[str, str] = {
                     "the native descriptors a (file, column) — with the "
                     "read and merge of every series metadata cannot plan "
                     "(memcache_ms lies inside it)",
+    "scan.alloc_ms": "inside scan.plan_ms: the output arrays' allocation "
+                     "and clearing (one values + one validity array a "
+                     "column, the batch's timestamps)",
     "scan.native_ms": "a scan's native.decode_pages tasks, inside "
                       "decode_ms: one a (file, column), on the decode "
                       "pool (wall, not thread-summed)",
@@ -126,6 +142,16 @@ STAGE_CATALOG: dict[str, str] = {
     "untraced_ms": "wall_ms minus the union of the request's stage "
                    "intervals: time no span covers (traced requests only)",
     "upload_ms": "host→device column uploads",
+    "upload.meta_ms": "inside upload_ms: DeviceBatch._init_meta less its "
+                      "pads and puts — min / max, the i32 (sec, ns) "
+                      "split, the argsort and scatter of the first/last "
+                      "rank (host arithmetic over every row)",
+    "upload.stage_ms": "inside upload_ms: a column's astype, pad to the "
+                       "row size class and valid.all() — host copies",
+    "upload.put_ms": "inside upload_ms: the device_put calls alone — the "
+                     "enqueue and whatever the runtime does "
+                     "synchronously; the transfer itself is waited for "
+                     "in kernel.fetch_ms",
     "upload_bytes": "bytes moved host→device by those uploads",
     "fused_launches": "fused filter/bucket/segment programs launched",
     "segment_runs.engaged": "device segment reductions (fused launches, "
@@ -141,6 +167,14 @@ STAGE_CATALOG: dict[str, str] = {
                         "scan device does not hold f64 exactly "
                         "(ops/placement.f64_exact)",
     "kernel_ms": "fused segment-aggregate kernels",
+    "kernel.pad_ms": "inside kernel_ms: aggregate_column_host's pads of "
+                     "values, validity, segment ids and rank to a row "
+                     "size class",
+    "kernel.dispatch_ms": "inside kernel_ms: the call of a jitted "
+                          "aggregate program up to its return — "
+                          "segment_aggregate with its implicit puts, the "
+                          "fused program with the wait for its dispatch "
+                          "lock; a compile lands here",
     "kernel.fetch_ms": "the aggregate's blocking result fetch: what is "
                        "left of the device's run + the device→host "
                        "transfer",
@@ -148,8 +182,6 @@ STAGE_CATALOG: dict[str, str] = {
     "finalize_ms": "vectorized finalizers + output rendering",
     "factorize_ms": "group-key factorization (values → dense codes)",
     "group_count": "output group cardinality per query",
-    "group_spill": "group-by accumulator epochs spilled to disk by the "
-                   "memory broker's GroupSpiller (sql/executor.py)",
     "matview.hit": "aggregate queries rewritten to read sealed buckets "
                    "from a materialized rollup",
     "matview.miss": "rewrite-eligible aggregate queries no registered "
@@ -201,6 +233,16 @@ STAGE_CATALOG: dict[str, str] = {
                             "queries' hedges can bleed in)",
     "mesh.plan_ms": "mesh exec lane: global segment/label layout + "
                     "shard-major staging (ops/mesh_exec._build_prep)",
+    "mesh.mask_ms": "mesh exec lane, inside mesh.plan_ms: host_row_mask "
+                    "of every batch",
+    "mesh.layout_ms": "mesh exec lane, inside mesh.plan_ms: "
+                      "host_group_layout of every batch, the global "
+                      "label / field / bucket tables, per-row global "
+                      "segment ids, presence and the first/last rank",
+    "mesh.stage_ms": "mesh exec lane, inside mesh.plan_ms: the "
+                     "shard-major padded staging arrays (segments, "
+                     "validity, rank, every column) and the f64 run "
+                     "plans",
     "mesh.upload_ms": "mesh exec lane: sharded host→device uploads "
                       "(NamedSharding over the shard axis)",
     "mesh.collective_ms": "mesh exec lane: collective merge programs — "
@@ -247,7 +289,8 @@ STAGE_CATALOG: dict[str, str] = {
 #   rpc_<method>_ms — server-side wall time of one RPC handler dispatch
 #   string_path.<path> — string predicates per strkernels lane
 #     (per_unique / ngram_skip / host_fallback)
-DYNAMIC_STAGE_PREFIXES = ("rpc_", "string_path.")
+#   cpu.<stage> — a traced stage's thread CPU time in ms (module docstring)
+DYNAMIC_STAGE_PREFIXES = ("rpc_", "string_path.", "cpu.")
 
 _profile: contextvars.ContextVar = contextvars.ContextVar(
     "cnos_query_profile", default=None)
@@ -530,10 +573,11 @@ def _annotation(prof: "QueryProfile", name: str):
 
 
 class _TracedStage:
-    """One stage of a traced request: the sum, the collector span, the
-    profiler annotation, and the (start, end) pair for `untraced_ms`."""
+    """One stage of a traced request: the sum, the thread's CPU time
+    beside it, the collector span, the profiler annotation, and the
+    (start, end) pair for `untraced_ms`."""
 
-    __slots__ = ("prof", "name", "span", "ann", "t0")
+    __slots__ = ("prof", "name", "span", "ann", "t0", "c0")
 
     def __init__(self, prof: QueryProfile, name: str):
         self.prof, self.name = prof, name
@@ -544,46 +588,68 @@ class _TracedStage:
         self.ann = _annotation(self.prof, self.name)
         if self.ann is not None:
             self.ann.__enter__()
+        # the thread clock is read inside the wall's two ends: cpu <= wall
         self.t0 = time.perf_counter()
+        self.c0 = time.thread_time()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        cpu_ms = (time.thread_time() - self.c0) * 1e3
         t1 = time.perf_counter()
         if self.ann is not None:
             self.ann.__exit__(exc_type, exc, tb)
+        self.span.set_tag("cpu_ms", round(cpu_ms, 3))
         self.span.__exit__(exc_type, exc, tb)
         self.prof.add_ms(self.name, (t1 - self.t0) * 1e3)
+        self.prof.add_ms("cpu." + self.name, cpu_ms)
         self.prof.add_interval(self.t0, t1)
         return False
 
 
-@contextmanager
+class _Stage:
+    """One stage of a request that is not traced: the sum alone — and,
+    for a write request's profile, the profiler annotation."""
+
+    __slots__ = ("prof", "name", "ann", "t0")
+
+    def __init__(self, prof: QueryProfile, name: str):
+        self.prof, self.name = prof, name
+
+    def __enter__(self):
+        self.ann = _annotation(self.prof, self.name) \
+            if self.prof.annotate else None
+        if self.ann is not None:
+            self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.prof.add_ms(self.name, (time.perf_counter() - self.t0) * 1e3)
+        if self.ann is not None:
+            self.ann.__exit__(exc_type, exc, tb)
+        return False
+
+
+# with no profile in scope a stage books nothing
+_NO_STAGE = nullcontext()
+
+
 def stage(name: str):
+    """→ the context manager of one stage of the active profile."""
     prof = _profile.get()
     if prof is None:
-        yield
-        return
+        return _NO_STAGE
     if prof.traced:
-        with _TracedStage(prof, name):
-            yield
-        return
-    ann = _annotation(prof, name) if prof.annotate else None
-    t0 = time.perf_counter()
-    try:
-        if ann is None:
-            yield
-        else:
-            with ann:
-                yield
-    finally:
-        prof.add_ms(name, (time.perf_counter() - t0) * 1e3)
+        return _TracedStage(prof, name)
+    return _Stage(prof, name)
 
 
 def book(name: str, t0: float) -> None:
     """Book [t0, now] (`perf_counter` seconds), an interval the caller
     timed itself because it starts on another thread (the ingress wait).
     The sum always; for a traced request also the span and the pair for
-    `untraced_ms`. No profiler annotation: that cannot be back-dated."""
+    `untraced_ms`. No profiler annotation: that cannot be back-dated. No
+    `cpu.<stage>`: the interval is not this thread's."""
     prof = _profile.get()
     if prof is None:
         return

@@ -608,7 +608,8 @@ def test_scan_books_its_plan_native_and_trim_stages(http):
     # a flushed store: every series off the index, which this scan built
     assert st["scan_plan.indexed_series"] == 6
     assert st["scan_plan.index_builds"] == 1
-    assert "scan_plan.merged_series" not in st
+    # booked where the decision is taken, so "none" reads 0, not nothing
+    assert st["scan_plan.merged_series"] == 0
     spans = _trace_spans(http, "feedc0de0036")
     by_id = {s["span_id"]: s for s in spans}
     for key in sections:
@@ -623,7 +624,7 @@ def test_scan_books_its_plan_native_and_trim_stages(http):
         "FROM cpu", "FROM cpu WHERE time >= '2023-01-01T00:00:00Z'")
     st, _full = traced(later, "feedc0de0037")
     assert st["scan_miss"] == 1 and st["scan_plan.indexed_series"] == 6
-    assert "scan_plan.index_builds" not in st
+    assert st["scan_plan.index_builds"] == 0
     # unflushed rows over two of the six: those two are read and merged
     lines = "\n".join(
         f"cpu,host=h{i} usage={t}i {1672531200000000000 + t * 10 * 10**9}"
@@ -634,12 +635,13 @@ def test_scan_books_its_plan_native_and_trim_stages(http):
                        "feedc0de0038")
     assert st["scan_miss"] == 1 and st["scan_plan.merged_series"] == 2
     assert st["scan_plan.indexed_series"] == 4
-    assert "scan_plan.index_builds" not in st
+    assert st["scan_plan.index_builds"] == 0
     status, body, _ = http.request(
         "POST", "/api/v1/sql?db=public", "EXPLAIN ANALYZE " + later)
     assert status == 200, body
     for key in sections + counts:
         assert key in stages.STAGE_CATALOG
+        # (a delta scan of the unflushed rows asks for no file's index)
         assert key in body or key == "scan_plan.index_builds", (key, body)
 
 
@@ -692,7 +694,8 @@ def test_sorted_scans_reduce_runs_and_say_so(http, monkeypatch, lane):
     launch (resp. every mesh merge program) over a batch long enough for
     the run path (kernels.run_pad_for) reduces runs, and the profile
     counts it from the program's own flag — `segment_runs.engaged` equals
-    the launches, `segment_runs.fallback` never shows."""
+    the launches, `segment_runs.fallback` reads 0 (booked beside it, so
+    a reader finds the key)."""
     if lane == "mesh":
         _seed_sharded_ints(http, monkeypatch, hosts=64, steps=2100)
         db, launches = "mesh4", "mesh.columns"
@@ -710,7 +713,7 @@ def test_sorted_scans_reduce_runs_and_say_so(http, monkeypatch, lane):
         assert k in stages.STAGE_CATALOG, k
     assert st.get(launches, 0) >= 1, sorted(st)
     assert st.get("segment_runs.engaged") == st[launches], sorted(st.items())
-    assert "segment_runs.fallback" not in st
+    assert st["segment_runs.fallback"] == 0
 
 
 def test_traced_mesh_request_holds_the_lane_under_http_sql(http, monkeypatch):
@@ -807,6 +810,240 @@ def test_stage_with_flag_off_keeps_no_interval_and_opens_no_span():
     assert prof.intervals == [] and prof.dropped == 0
     assert len(GLOBAL_COLLECTOR.spans(limit=10**6)) == before
     assert "untraced_ms" not in prof.finish(wall_ms=5.0).ms
+
+
+# ------------------------------------------------- work told from wait
+def test_cpu_time_is_booked_for_a_traced_stage_and_for_no_other(monkeypatch):
+    """`cpu.<stage>` rides beside a traced stage's sum; a profile that is
+    not traced reads no thread clock and books no `cpu.*` key, and
+    `book()` — another thread's interval — books none either way."""
+    traced = stages.QueryProfile()
+    traced.traced = True
+    with stages.profile_scope(traced):
+        with stages.stage("decode_ms"):
+            with stages.stage("scan.plan_ms"):
+                pass
+        stages.book("ingress_wait_ms", time.perf_counter() - 0.001)
+    assert set(traced.ms) == {"decode_ms", "cpu.decode_ms", "scan.plan_ms",
+                              "cpu.scan.plan_ms", "ingress_wait_ms"}
+    assert 0 <= traced.ms["cpu.scan.plan_ms"] <= traced.ms["cpu.decode_ms"]
+    assert "cpu.decode_ms" in traced.snapshot() \
+        and "cpu.decode_ms" in traced.to_dict()["ms"] \
+        and "cpu.decode_ms" in traced.stage_totals()
+    assert "cpu.".startswith(stages.DYNAMIC_STAGE_PREFIXES)
+
+    def no_clock():
+        raise AssertionError("an untraced stage read the thread clock")
+
+    monkeypatch.setattr(time, "thread_time", no_clock)
+    for annotate in (False, True):      # a query's profile, a write's
+        plain = stages.QueryProfile()
+        plain.annotate = annotate
+        with stages.profile_scope(plain):
+            with stages.stage("decode_ms"):
+                pass
+            stages.book("ingress_wait_ms", time.perf_counter() - 0.001)
+        assert set(plain.ms) == {"decode_ms", "ingress_wait_ms"}
+    with stages.stage("decode_ms"):     # no profile in scope: nothing
+        pass
+
+
+def _sleep_50ms():
+    time.sleep(0.05)
+
+
+def _spin_50ms():
+    end = time.perf_counter() + 0.05
+    while time.perf_counter() < end:
+        pass
+
+
+@pytest.mark.parametrize("body,holds", [
+    (_sleep_50ms, lambda wall, cpu: wall >= 50 and cpu < 5),
+    (_spin_50ms, lambda wall, cpu: wall >= 50 and abs(wall - cpu)
+     <= 0.2 * wall),
+], ids=["a_sleep_is_wait", "a_spin_is_work"])
+def test_cpu_beside_wall_tells_work_from_wait(body, holds):
+    """A stage that sleeps 50 ms books under 5 ms of CPU; one that spins
+    50 ms books its wall to within 20 % (best of three: a busy machine
+    can take the core away from the spin)."""
+    seen = []
+    for _attempt in range(3):
+        prof = stages.QueryProfile()
+        prof.traced = True
+        with stages.profile_scope(prof), stages.stage("decode_ms"):
+            body()
+        seen.append((prof.ms["decode_ms"], prof.ms["cpu.decode_ms"]))
+        if holds(*seen[-1]):
+            break
+    assert holds(*seen[-1]), seen
+
+
+# parent span → the stages PR 37 nests inside it
+_NESTED = {
+    "upload_ms": ("upload.meta_ms", "upload.stage_ms", "upload.put_ms"),
+    "mesh.plan_ms": ("mesh.mask_ms", "mesh.layout_ms", "mesh.stage_ms"),
+    "scan.plan_ms": ("scan.alloc_ms",),
+    "kernel_ms": ("kernel.pad_ms", "kernel.dispatch_ms"),
+}
+_PANEL = ("SELECT date_bin(INTERVAL '10 minutes', time) AS t, max(usage), "
+          "max(idle) FROM cpu WHERE host = 'h3' GROUP BY t")
+
+
+def _traced(h, sql, tid, db="public"):
+    status, body, hdrs = h.request(
+        "POST", f"/api/v1/sql?db={db}", sql,
+        headers={"X-CnosDB-Profile": "1", "cnos-trace-id": tid})
+    assert status == 200, body
+    summary = json.loads(hdrs["X-CnosDB-Profile-Summary"])
+    return summary, _trace_spans(h, tid)
+
+
+def _check_nested(summary, spans, parent):
+    """Every child key of `parent` is documented, booked, a span whose
+    nearest ancestor among the catalog's parents is `parent`, with its CPU
+    time beside it; the children's sum does not exceed the parent."""
+    st = summary["stages"]
+    by_id = {s["span_id"]: s for s in spans}
+    for key in _NESTED[parent]:
+        assert key in stages.STAGE_CATALOG, key
+        assert key in st and "cpu." + key in st, (key, sorted(st))
+        found = [s for s in spans if s["name"] == key]
+        assert found, key
+        for s in found:
+            chain = _ancestors(s, by_id)
+            assert chain[0] == parent and chain[-1] == "http:sql", chain
+            assert "cpu_ms" in s["tags"], s
+    assert sum(st[k] for k in _NESTED[parent]) <= st[parent] + 0.05, \
+        {k: st[k] for k in (parent,) + _NESTED[parent]}
+    assert "dropped" not in summary
+
+
+@pytest.mark.parametrize("parent", sorted(_NESTED))
+def test_new_stages_nest_inside_their_parent_span(http, monkeypatch, parent):
+    """The aggregate half split where the work changes hands: each new
+    key lies inside the span the catalog names, on the thread that did
+    the work, and the parts do not exceed the whole."""
+    tid = "feedc0de37" + f"{sorted(_NESTED).index(parent):02d}"
+    if parent == "mesh.plan_ms":
+        _seed_sharded_ints(http, monkeypatch)
+        summary, spans = _traced(http, _BUCKETED, tid, db="mesh4")
+    else:
+        # the fused lane (a DeviceBatch uploaded, one fused program) and,
+        # for the tag-predicate panel, aggregate_column_host a column
+        monkeypatch.setenv("CNOSDB_TPU_FORCE_DEVICE_PATH", "1")
+        monkeypatch.setenv("CNOSDB_MESH", "0")
+        _seed_flushed_ints(http, hosts=6, fields=("usage", "idle"))
+        summary, spans = _traced(http, _BUCKETED, tid)
+        if parent == "kernel_ms":
+            st = summary["stages"]
+            assert st["fused_launches"] == 1 and "kernel.pad_ms" not in st
+            assert 0 < st["kernel.dispatch_ms"] <= st["kernel_ms"]
+            summary, spans = _traced(http, _PANEL, tid + "aa")
+            assert "fused_launches" not in summary["stages"]
+    _check_nested(summary, spans, parent)
+
+
+def test_counts_that_must_read_zero_are_booked_as_zero(http, monkeypatch):
+    """A warmed, frozen store: the four counts that must read 0 are in the
+    aggregate's profile as 0 — booked where each decision is taken — so a
+    reader reads 0 and not nothing."""
+    monkeypatch.setenv("CNOSDB_TPU_FORCE_DEVICE_PATH", "1")
+    monkeypatch.setenv("CNOSDB_MESH", "0")
+    _seed_flushed_ints(http, hosts=40, steps=1700)
+    status, body, _ = http.request("POST", "/api/v1/sql?db=public",
+                                   _BUCKETED)       # builds the index
+    assert status == 200, body
+    later = _BUCKETED.replace(
+        "FROM cpu", "FROM cpu WHERE time >= '2023-01-01T00:00:10Z'")
+    status, body, hdrs = http.request(
+        "POST", "/api/v1/sql?db=public", later,
+        headers={"X-CnosDB-Profile": "1"})
+    assert status == 200, body
+    st = json.loads(hdrs["X-CnosDB-Profile-Summary"])["stages"]
+    assert st["scan_miss"] == 1 and st["segment_runs.engaged"] == 1
+    for key in ("segment_runs.fallback", "render.percell_columns",
+                "scan_plan.merged_series", "scan_plan.index_builds"):
+        assert key in stages.STAGE_CATALOG
+        assert st.get(key, "absent") == 0, (key, sorted(st.items()))
+
+
+def test_fleet_shaped_summary_carries_every_booked_key(http, monkeypatch):
+    """Ten fields by host and bucket, traced: the summary header holds
+    every key the profile booked — the stages, their `cpu.*` and the
+    counts — with nothing dropped under PROFILE_SUMMARY_MAX."""
+    from cnosdb_tpu.server.http import PROFILE_SUMMARY_MAX
+
+    monkeypatch.setenv("CNOSDB_TPU_FORCE_DEVICE_PATH", "1")
+    monkeypatch.setenv("CNOSDB_MESH", "0")
+    fields = tuple(f"usage_{i}" for i in range(10))
+    _seed_flushed_ints(http, hosts=40, steps=1700, fields=fields)
+    sql = ("SELECT date_bin(INTERVAL '1 hour', time) AS t, host, "
+           + ", ".join(f"avg({f})" for f in fields)
+           + " FROM cpu GROUP BY t, host")
+    status, body, hdrs = http.request(
+        "POST", "/api/v1/sql?db=public", sql,
+        headers={"X-CnosDB-Profile": "1"})
+    assert status == 200, body
+    text = hdrs["X-CnosDB-Profile-Summary"]
+    summary = json.loads(text)
+    assert len(text) <= PROFILE_SUMMARY_MAX // 2 and "dropped" not in summary
+    full = json.loads(http.request(
+        "GET", f"/debug/profile?qid={summary['qid']}")[1])
+    st = summary["stages"]
+    # (the ring's copy is taken where the executor seals the profile,
+    # before the render)
+    assert set(st) == set(full["ms"]) | set(full["counts"]) | {
+        "render_ms", "cpu.render_ms", "render.percell_columns"}
+    for key in ("upload.meta_ms", "upload.stage_ms", "upload.put_ms",
+                "kernel.dispatch_ms", "scan.alloc_ms", "untraced_ms"):
+        assert key in st, (key, sorted(st))
+    # a stage entered on one thread cannot burn more CPU than its wall
+    for key in ("plan_ms", "render_ms", "upload.meta_ms", "kernel.fetch_ms"):
+        assert st["cpu." + key] <= st[key] * 1.05 + 0.5, (key, st)
+    # every duration has its CPU reading but those no stage() times: the
+    # two book() back-dates, and what finish() derives
+    walls = {k for k in st if k.endswith("_ms") and not k.startswith("cpu.")}
+    assert {"cpu." + k for k in walls - {
+        "ingress_wait_ms", "memcache_wait_ms", "untraced_ms"}} \
+        == {k for k in st if k.startswith("cpu.")}
+
+
+def test_new_stages_are_profiler_annotations_with_their_qid(
+        http, monkeypatch, tmp_path):
+    """Every stage of a traced request is a `cnosdb.<stage>` event of a
+    profiler trace, on the thread that did the work, with the request's
+    qid — the new ones too, by going through `stage()`: what a later
+    reader of the trace needs of the program."""
+    import glob
+
+    import jax
+
+    monkeypatch.setenv("CNOSDB_TPU_FORCE_DEVICE_PATH", "1")
+    monkeypatch.setenv("CNOSDB_MESH", "0")
+    _seed_flushed_ints(http, hosts=6, fields=("usage", "idle"))
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        fused, _ = _traced(http, _BUCKETED, "feedc0de3790")
+        panel, _ = _traced(http, _PANEL, "feedc0de3791")
+    finally:
+        jax.profiler.stop_trace()
+    paths = glob.glob(str(tmp_path / "trace" / "**" / "*.xplane.pb"),
+                      recursive=True)
+    assert len(paths) == 1, paths
+    seen: dict[str, set] = {}
+    for plane in jax.profiler.ProfileData.from_file(paths[0]).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("cnosdb."):
+                    seen.setdefault(ev.name[len("cnosdb."):], set()).update(
+                        str(v) for k, v in ev.stats if k == "qid")
+    want = {k: {str(fused["qid"])} for k in
+            ("upload_ms",) + _NESTED["upload_ms"] + _NESTED["scan.plan_ms"]}
+    want["kernel.pad_ms"] = {str(panel["qid"])}
+    want["kernel.dispatch_ms"] = {str(fused["qid"]), str(panel["qid"])}
+    for key, qids in want.items():
+        assert qids <= seen.get(key, set()), (key, seen.get(key))
 
 
 @pytest.mark.parametrize("intervals,want", [
@@ -982,7 +1219,8 @@ def test_traced_request_over_unflushed_rows_holds_the_memcache_stages(http):
     assert status == 200, body
     for key in ("memcache_ms", "memcache_wait_ms", "memcache.series",
                 "memcache.rows"):
-        assert key in body, (key, body)
+        # (a delta scan of the unflushed rows asks for no file's index)
+        assert key in body or key == "scan_plan.index_builds", (key, body)
         assert key in stages.STAGE_CATALOG
     # rows all in files: a scan books the wait for its cut, nothing else
     status, body, _ = http.request("POST", "/api/v1/sql?db=public", "FLUSH")

@@ -258,11 +258,14 @@ def test_fused_program_by_runs_equals_by_rows(rng, case):
 
 
 def test_fetch_books_the_program_s_word(rng):
-    """PendingFused.fetch pops the flag row and books one count a launch."""
+    """PendingFused.fetch pops the flag row and books the launch under the
+    key of its path — and 0 under the other, so a request that never fell
+    back reads `segment_runs.fallback` 0 and not nothing."""
     mat = np.zeros((3, 64))
     mat[0, :5] = 7
-    for word, key in ((1.0, "segment_runs.engaged"),
-                      (0.0, "segment_runs.fallback")):
+    for word, key, other in (
+            (1.0, "segment_runs.engaged", "segment_runs.fallback"),
+            (0.0, "segment_runs.fallback", "segment_runs.engaged")):
         mat[2] = word
         pending = fused.PendingFused(
             mat, [("__presence__", "count"), ("v", "sum"),
@@ -271,8 +274,7 @@ def test_fetch_books_the_program_s_word(rng):
         with stages.profile_scope(prof):
             out = pending.fetch()
         assert sorted(out) == ["__presence__", "v"]
-        assert prof.counts.get(key) == 1, prof.counts
-        assert len(prof.counts) == 1
+        assert prof.counts == {key: 1, other: 0}, prof.counts
 
 
 def test_host_wrapper_takes_the_callers_bound(rng):
@@ -292,8 +294,11 @@ def test_host_wrapper_takes_the_callers_bound(rng):
             got = kernels.aggregate_column_host(vals, valid, seg, rank, 64,
                                                 wants, max_runs=max_runs)
         runs = {k: v for k, v in prof.counts.items()
-                if k.startswith("segment_runs")}
+                if k.startswith("segment_runs") and v}
         assert runs == ({booked: 1} if booked else {}), (max_runs, runs)
+        # a launch with the run path compiled in books both keys
+        assert sum(k.startswith("segment_runs") for k in prof.counts) \
+            == (2 if booked else 0), prof.counts
         for k in ref:
             assert np.array_equal(got[k], ref[k]), (max_runs, k)
 
@@ -376,7 +381,8 @@ def test_host_wrapper_matches_the_numpy_oracle(rng, case, path):
             max_runs=runs if path == "by_runs" else None)
     booked = {k: v for k, v in prof.counts.items()
               if k.startswith("segment_runs")}
-    assert booked == ({"segment_runs.engaged": 1} if path == "by_runs"
+    assert booked == ({"segment_runs.engaged": 1,
+                       "segment_runs.fallback": 0} if path == "by_runs"
                       else {})
     assert sorted(got) == sorted(ref)
     for k in ref:
