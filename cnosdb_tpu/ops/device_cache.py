@@ -4,10 +4,11 @@ The reference keeps hot TSM pages in a host LRU (tskv/src/tsfamily/
 version.rs TsmReader cache). On TPU the equivalent — and the dominant
 performance lever, since host↔device transfer is the bottleneck — is
 keeping decoded scan columns resident in HBM: a ScanBatch ships to the
-device ONCE (timestamps, series ordinals, field columns + validity,
-time-order rank), and every subsequent query against the same batch runs
-entirely device-side (bucket/segment computation included), transferring
-only group parameters in and [num_segments] partials out.
+device ONCE (timestamps, series ordinals, field columns + validity; the
+time-order rank when a first/last query first asks for it), and every
+subsequent query against the same batch runs entirely device-side
+(bucket/segment computation included), transferring only group
+parameters in and [num_segments] partials out.
 
 Invalidation: ScanBatches are immutable snapshots; the device arrays are
 attached to the batch object itself, and batches are cached per vnode
@@ -32,7 +33,8 @@ import numpy as np
 import jax
 
 from ..models.schema import ValueType
-from ..utils import stages
+from ..storage import native
+from ..utils import lockwatch, stages
 from .kernels import pad_rows
 
 # live device uploads, weakly held — the broker's device_uploads pool
@@ -73,17 +75,25 @@ class DeviceBatch:
     to the batch epoch — 64-bit integer/float arithmetic is software-
     emulated on TPU (measured ~1000× slower than i32 for division), so the
     device NEVER touches an i64 timestamp; bucket indices are derived from
-    the i32 pair with exact integer math (see fused._bucket_arith).
+    the i32 pair with exact integer math (see fused._bucket_arith). A span
+    the i32 seconds cannot hold never gets here (`launch_scan_aggregate`
+    keeps it on the host).
+
+    The meta is built for the program that takes it: the ns remainder is
+    materialised and put only where one is non-zero, and the first/last
+    time-order rank is sorted, put and kept where `rank_dev()` is first
+    asked — a query whose aggregates are avg / sum / count / min / max
+    never pays for it.
     """
 
     __slots__ = ("n_rows", "n_pad", "n_series", "epoch_ns", "ts_sec", "ts_ns",
-                 "sid_ordinal", "rank", "in_rows", "fields", "ts_min", "ts_max",
-                 "i32_ok", "ns_all_zero", "field_all_valid", "_rank_np",
+                 "sid_ordinal", "rank", "in_rows", "fields", "ns_all_zero",
+                 "field_all_valid", "_ts", "_rank_np", "_rank_lock",
                  "series_params", "est_bytes", "__weakref__")
 
-    def __init__(self, batch):
+    def __init__(self, batch, n_threads: int = 1):
         with stages.stage("upload_ms"):
-            self._init_meta(batch)
+            self._init_meta(batch, n_threads)
             pre = getattr(batch, "_preuploaded", None)
             pre_cols = pre[1] if pre is not None and pre[0] == self.n_pad \
                 else {}
@@ -104,30 +114,25 @@ class DeviceBatch:
             self.est_bytes = self._estimate_bytes()
             _LIVE_BATCHES.add(self)
 
-    def _init_meta(self, batch):
+    def _init_meta(self, batch, n_threads: int = 1):
         """Everything except the field columns: row counts, the i32
-        timestamp pair, series ordinals, lazy rank."""
+        timestamp pair, series ordinals; the rank waits for `rank_dev()`.
+        `n_threads` sizes the native split's pool."""
         n = batch.n_rows
         self.n_rows = n
         self.n_pad = pad_rows(max(n, 1))
         self.n_series = batch.n_series
         with stages.stage("upload.meta_ms"):
-            self.ts_min = int(batch.ts.min()) if n else 0
-            self.ts_max = int(batch.ts.max()) if n else 0
-            self.epoch_ns = self.ts_min
-            rel = batch.ts - self.epoch_ns
-            # i32 seconds covers ~68 years of batch span; beyond that the
-            # host path handles it (flag checked in _device_eligible)
-            self.i32_ok = n == 0 \
-                or bool(rel.max() < (2**31 - 2) * 1_000_000_000)
-            sec = (rel // 1_000_000_000).astype(np.int32)
-            ns = (rel - sec.astype(np.int64) * 1_000_000_000).astype(np.int32)
+            # the snapshot's one pair: `_bucket_geometry` derives the
+            # launch's bucket constants from the same epoch
+            self.epoch_ns = batch.ts_minmax()[0]
             # an optional input is skipped (static kernel flag) when
             # derivable — a buffer not passed is a buffer not uploaded or
-            # kept in HBM:
-            self.ns_all_zero = bool((ns == 0).all())   # second-aligned data
-        self.ts_ns = None if self.ns_all_zero \
-            else _put_padded(ns, self.n_pad)
+            # kept in HBM: `ns` is None for second-aligned data
+            sec, ns = _split_ts(batch.ts, self.epoch_ns, self.n_pad,
+                                n_threads)
+        stages.count("upload.rank_builds", 0)
+        self.ns_all_zero = ns is None
         # Regular-series fast path: when every series is a contiguous run
         # with a constant whole-second stride (the normal telemetry shape),
         # ship ONLY [n_series, 3] params (row_start, sec0, stride_s); the
@@ -145,22 +150,22 @@ class DeviceBatch:
                 "CNOSDB_TPU_REGULAR", "0") == "1":
             with stages.stage("upload.meta_ms"):
                 self.series_params = _regular_series_params(
-                    batch.sid_ordinal, sec, batch.n_series, self.n_pad)
-        if self.series_params is not None:
-            self.ts_sec = None
-            self.sid_ordinal = None
-        else:
-            self.ts_sec = _put_padded(sec, self.n_pad)
-            self.sid_ordinal = _put_padded(batch.sid_ordinal, self.n_pad)
+                    batch.sid_ordinal, sec[:n], batch.n_series, self.n_pad)
+        with stages.stage("upload.put_ms"):
+            # both come out of the split padded: put as they lie
+            self.ts_ns = None if ns is None else _put(ns)
+            self.ts_sec = None if self.series_params is not None \
+                else _put(sec)
+        self.sid_ordinal = None if self.series_params is not None \
+            else _put_padded(batch.sid_ordinal, self.n_pad)
         # in_rows derives from iota < n_rows inside the kernel (no buffer)
         self.in_rows = None
-        # globally unique time-order rank (first/last selection key),
-        # shipped lazily — only first/last kernels reference it
-        with stages.stage("upload.meta_ms"):
-            order = np.argsort(batch.ts, kind="stable")
-            rank = np.empty(n, dtype=np.int32)
-            rank[order] = np.arange(n, dtype=np.int32)
-        self._rank_np = rank
+        # globally unique time-order rank (first/last selection key): only
+        # first/last kernels reference it, so `rank_dev()` builds it from
+        # the batch's `ts` (the ScanBatch owns both; the twin dies with it)
+        self._ts = batch.ts
+        self._rank_np = None
+        self._rank_lock = lockwatch.Lock("device_cache.rank")
         self.rank = None
         self.fields: dict[str, tuple[ValueType, object, object]] = {}
         self.field_all_valid: dict[str, bool] = {}
@@ -179,9 +184,23 @@ class DeviceBatch:
         return total
 
     def rank_dev(self):
+        """The device's time-order rank, built by the first first/last
+        query of the batch (booked as upload where it happens) and kept.
+        Queries that ask at the same moment get the one array: the sort
+        runs once under the batch's lock; the put — device dispatch, so
+        outside it — may be raced, and the first one is published."""
         if self.rank is None:
-            self.rank = _put(_pad_to(self._rank_np, self.n_pad, 0))
-            self.est_bytes += int(getattr(self.rank, "nbytes", 0) or 0)
+            with stages.stage("upload_ms"):
+                with self._rank_lock:
+                    if self._rank_np is None:
+                        with stages.stage("upload.meta_ms"):
+                            self._rank_np = _time_rank(self._ts)
+                        stages.count("upload.rank_builds")
+                rank = _put_padded(self._rank_np, self.n_pad)
+            with self._rank_lock:
+                if self.rank is None:
+                    self.est_bytes += int(getattr(rank, "nbytes", 0) or 0)
+                    self.rank = rank
         return self.rank
 
 
@@ -213,8 +232,8 @@ class EagerUploader:
             batch._preuploaded = (self.n_pad, self._cols)
 
 
-def merged_device_batch(merged, cached, delta,
-                        append_gather: np.ndarray) -> "DeviceBatch | None":
+def merged_device_batch(merged, cached, delta, append_gather: np.ndarray,
+                        n_threads: int = 1) -> "DeviceBatch | None":
     """Build the device twin of a delta-merged batch by gathering the
     unchanged rows from the cached twin ON DEVICE — the cached field
     columns never re-cross the host↔device pipe; only the (small) delta
@@ -223,9 +242,10 @@ def merged_device_batch(merged, cached, delta,
     groups, each field picks its winner independently and one shared
     row-gather would be wrong — callers fall back to a lazy full build.
 
-    The i32 timestamp pair / ordinals / rank rebuild on host (cheap i32
-    work); → the attached DeviceBatch, or None when the cached twin is
-    missing or shaped incompatibly."""
+    The i32 timestamp pair / ordinals rebuild on host (`_init_meta`, as
+    for any batch; the rank waits for a first/last query); → the attached
+    DeviceBatch, or None when the cached twin is missing or shaped
+    incompatibly."""
     old = getattr(cached, "_device_batch", None)
     if old is None or old.series_params is not None:
         return None
@@ -234,7 +254,7 @@ def merged_device_batch(merged, cached, delta,
     with stages.stage("upload_ms"):
         n_c, n_d = cached.n_rows, delta.n_rows
         db = DeviceBatch.__new__(DeviceBatch)
-        db._init_meta(merged)
+        db._init_meta(merged, n_threads)
         # gather index into [cached rows | delta rows | zero sentinel];
         # pad rows hit the sentinel so they read (0, invalid) regardless
         # of kernel-side pad masking
@@ -294,8 +314,47 @@ def merged_device_batch(merged, cached, delta,
                     [ov, dv, jnp.zeros(1, dtype=bool)])
                 valid_dev = vcat[g_dev]
             db.fields[name] = (vt, vals_dev, valid_dev)
+        db.est_bytes = db._estimate_bytes()
+        _LIVE_BATCHES.add(db)
         merged._device_batch = db
         return db
+
+
+def _split_ts(ts: np.ndarray, epoch: int, n_pad: int,
+              n_threads: int = 1) -> tuple[np.ndarray, np.ndarray | None]:
+    """i64 ns timestamps → the i32 pair relative to `epoch`, each zero-
+    padded to `n_pad` rows where the put reads it: (whole seconds, ns
+    remainder | None where every remainder is 0). One native pass writes
+    the seconds and says whether a remainder is non-zero; only then does a
+    second materialise `ns`. Without the library the numpy expressions
+    (eight whole-array passes) are the one fallback."""
+    sec = np.empty(n_pad, dtype=np.int32)
+    any_ns = native.split_ts_i32(ts, epoch, sec, None, n_threads)
+    if any_ns is None:
+        return _split_ts_numpy(ts, epoch, n_pad)
+    if not any_ns:
+        return sec, None
+    ns = np.empty(n_pad, dtype=np.int32)
+    native.split_ts_i32(ts, epoch, None, ns, n_threads)
+    return sec, ns
+
+
+def _split_ts_numpy(ts: np.ndarray, epoch: int, n_pad: int):
+    rel = ts - epoch
+    sec = (rel // 1_000_000_000).astype(np.int32)
+    ns = (rel - sec.astype(np.int64) * 1_000_000_000).astype(np.int32)
+    return _pad_to(sec, n_pad, 0), \
+        _pad_to(ns, n_pad, 0) if ns.any() else None
+
+
+def _time_rank(ts: np.ndarray) -> np.ndarray:
+    """Globally unique i32 time-order rank of every row: position in the
+    stable sort by timestamp, so ties between series break by row order."""
+    n = len(ts)
+    order = np.argsort(ts, kind="stable")
+    rank = np.empty(n, dtype=np.int32)
+    rank[order] = np.arange(n, dtype=np.int32)
+    return rank
 
 
 def _regular_series_params(sid_ordinal: np.ndarray, sec: np.ndarray,
@@ -349,8 +408,8 @@ def _put_column(vt: ValueType, vals: np.ndarray, valid: np.ndarray,
 
 
 def _put_padded(a: np.ndarray, n_pad: int):
-    """One per-row i32 array of the batch's meta (seconds, ns remainder,
-    series ordinals) → its zero-padded device twin, split like a column's."""
+    """One per-row i32 array of the batch's meta (series ordinals, the
+    rank) → its zero-padded device twin, split like a column's."""
     with stages.stage("upload.stage_ms"):
         host = _pad_to(a, n_pad, 0)
     with stages.stage("upload.put_ms"):
@@ -384,10 +443,10 @@ def put_sharded(a: np.ndarray, mesh, spec):
     return jax.device_put(a, NamedSharding(mesh, spec))
 
 
-def device_batch(batch) -> DeviceBatch:
+def device_batch(batch, n_threads: int = 1) -> DeviceBatch:
     """Get-or-build the device twin of a ScanBatch (attached to it)."""
     db = getattr(batch, "_device_batch", None)
     if db is None:
-        db = DeviceBatch(batch)
+        db = DeviceBatch(batch, n_threads)
         batch._device_batch = db
     return db

@@ -274,12 +274,10 @@ def _gf_layout(batch: ScanBatch, group_fields: list[str], n: int):
 
 def _bucket_geometry(batch: ScanBatch, time_bucket):
     """→ (ts_lo, ts_hi, origin, interval, bmin, dense_span); min/max are
-    immutable per scan snapshot and cached (a 100M-row i64 min+max costs
-    ~150ms — pure waste on every repeated query)."""
-    mm = getattr(batch, "_ts_minmax", None)
-    if mm is None:
-        mm = batch._ts_minmax = (int(batch.ts.min()), int(batch.ts.max()))
-    ts_lo, ts_hi = mm
+    the snapshot's one cached pair (`ScanBatch.ts_minmax`: a 100M-row i64
+    min+max costs ~150ms), the pair its device twin takes its epoch
+    from — `bucket_arith_params(ts_lo, ...)` holds only for that epoch."""
+    ts_lo, ts_hi = batch.ts_minmax()
     if time_bucket is not None:
         origin, interval = time_bucket
         bmin = (ts_lo - origin) // interval
@@ -529,7 +527,7 @@ def launch_scan_aggregate(batch: ScanBatch, query: TpuQuery):
         else:
             bucket_starts = None
         num_segments = n_groups * n_buckets
-        dbatch = device_batch(batch)
+        dbatch = device_batch(batch, _kernel_threads(query))
         pending = launch_fused(dbatch, query.filter, group_of_series,
                                n_groups, n_buckets, arith, col_wants)
 

@@ -1016,7 +1016,8 @@ class Coordinator:
                     from ..ops.device_cache import merged_device_batch
 
                     with stages.stage("merge_ms"):
-                        merged_device_batch(merged, cached, delta, gather)
+                        merged_device_batch(merged, cached, delta, gather,
+                                            n_threads)
                 except Exception:
                     stages.count_error("scan.device_merge")
         stages.count("delta_hit")

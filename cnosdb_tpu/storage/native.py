@@ -116,6 +116,13 @@ def _get_lib_locked():
                 ctypes.c_void_p, ctypes.c_void_p,  # first, first_ts
                 ctypes.c_void_p, ctypes.c_void_p,  # last, last_ts
                 ctypes.c_int]                      # n_threads
+        if hasattr(lib, "split_ts_i32"):
+            lib.split_ts_i32.restype = ctypes.c_int
+            lib.split_ts_i32.argtypes = [
+                ctypes.c_void_p, ctypes.c_int64,   # ts, n
+                ctypes.c_int64,                    # epoch
+                ctypes.c_void_p, ctypes.c_void_p,  # out_sec, out_ns
+                ctypes.c_int64, ctypes.c_int]      # n_pad, n_threads
         _LIB = lib
     except OSError:
         _LIB = None
@@ -276,6 +283,32 @@ def decode_pages(base: np.ndarray, desc: np.ndarray,
         len(out_vals), 1 if check_crc else 0, n_threads,
         status.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
     return status
+
+
+def split_ts_i32(ts: np.ndarray, epoch: int, out_sec: np.ndarray | None,
+                 out_ns: np.ndarray | None,
+                 n_threads: int = 1) -> bool | None:
+    """One GIL-free pass over i64 ns timestamps → the i32 pair relative
+    to `epoch` (native/segagg.cpp): whole seconds into `out_sec`, the ns
+    remainder into `out_ns` — contiguous i32 arrays of one length ≥
+    len(ts), either may be None; rows past len(ts) are zeroed.
+    → whether any remainder is non-zero, or None when unavailable."""
+    lib = get_lib()
+    if lib is None or not hasattr(lib, "split_ts_i32"):
+        return None
+    ts = np.ascontiguousarray(ts, dtype=np.int64)
+    outs = [a for a in (out_sec, out_ns) if a is not None]
+    if not outs or any(a.dtype != np.int32 or a.ndim != 1
+                       or not a.flags.c_contiguous
+                       or len(a) != len(outs[0]) or len(a) < len(ts)
+                       for a in outs):
+        raise ValueError("split_ts_i32: outputs must be contiguous i32 "
+                         "arrays of one length >= len(ts)")
+    return bool(lib.split_ts_i32(
+        ts.ctypes.data, len(ts), epoch,
+        out_sec.ctypes.data if out_sec is not None else None,
+        out_ns.ctypes.data if out_ns is not None else None,
+        len(outs[0]), n_threads))
 
 
 def decode_xor_f64(comp: bytes, n: int) -> np.ndarray | None:

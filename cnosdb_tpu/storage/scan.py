@@ -65,6 +65,16 @@ class ScanBatch:
     def n_series(self) -> int:
         return len(self.series_ids)
 
+    def ts_minmax(self) -> tuple[int, int]:
+        """(min, max) of `ts`, (0, 0) for no rows: immutable per scan
+        snapshot, so computed once and kept (two i64 passes over every
+        row otherwise, again on every query and every device twin)."""
+        mm = getattr(self, "_ts_minmax", None)
+        if mm is None:
+            mm = self._ts_minmax = (int(self.ts.min()), int(self.ts.max())) \
+                if len(self.ts) else (0, 0)
+        return mm
+
 
 def _time_mask(ts: np.ndarray, trs: TimeRanges) -> np.ndarray | None:
     if trs.is_all:

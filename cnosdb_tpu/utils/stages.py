@@ -143,9 +143,14 @@ STAGE_CATALOG: dict[str, str] = {
                    "intervals: time no span covers (traced requests only)",
     "upload_ms": "host→device column uploads",
     "upload.meta_ms": "inside upload_ms: DeviceBatch._init_meta less its "
-                      "pads and puts — min / max, the i32 (sec, ns) "
-                      "split, the argsort and scatter of the first/last "
-                      "rank (host arithmetic over every row)",
+                      "pads and puts — the one-pass i32 (sec, ns) split "
+                      "— and, where a first/last query first asks "
+                      "(rank_dev), the argsort and scatter of the rank "
+                      "(host arithmetic over every row)",
+    "upload.rank_builds": "first/last time-order ranks sorted and put: 0 "
+                          "at every DeviceBatch's build, 1 where a query's "
+                          "aggregates first ask for the rank (0 for avg / "
+                          "sum / count / min / max)",
     "upload.stage_ms": "inside upload_ms: a column's astype, pad to the "
                        "row size class and valid.all() — host copies",
     "upload.put_ms": "inside upload_ms: the device_put calls alone — the "

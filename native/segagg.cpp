@@ -182,4 +182,57 @@ int fused_seg_agg_f64(
     return 0;
 }
 
+// One pass over a scan batch's i64 ns timestamps → the i32 pair the
+// device is handed (ops/device_cache.py: the device never touches an
+// i64 timestamp): sec = floor((ts - epoch) / 1e9) into out_sec, the ns
+// remainder into out_ns; either output may be null. Rows [0, n) are
+// written, rows [n, n_pad) zeroed, so an output is put as it lies.
+// Value for value numpy's `(rel // 1e9).astype(i32)` and
+// `(rel - sec.astype(i64) * 1e9).astype(i32)`, wrap-around included.
+// → 1 if any remainder is non-zero (the caller then asks for out_ns),
+// else 0.
+int split_ts_i32(const int64_t* ts, int64_t n, int64_t epoch,
+                 int32_t* out_sec, int32_t* out_ns, int64_t n_pad,
+                 int n_threads) {
+    const int64_t NS = 1000000000;
+    // a thread is worth starting for 2^18 rows or more
+    int64_t want = (n + (1 << 18) - 1) >> 18;
+    if (n_threads > 16) n_threads = 16;
+    if ((int64_t)n_threads > want) n_threads = (int)want;
+    if (n_threads < 1) n_threads = 1;
+    std::vector<int> any(n_threads, 0);
+
+    auto work = [&](int t) {
+        int64_t lo = n * t / n_threads;
+        int64_t hi = n * (t + 1) / n_threads;
+        int32_t acc = 0;
+        for (int64_t i = lo; i < hi; i++) {
+            int64_t rel = (int64_t)((uint64_t)ts[i] - (uint64_t)epoch);
+            int32_t sec = (int32_t)floordiv(rel, NS);
+            int32_t ns = (int32_t)((uint64_t)rel
+                                   - (uint64_t)(int64_t)sec * (uint64_t)NS);
+            if (out_sec) out_sec[i] = sec;
+            if (out_ns) out_ns[i] = ns;
+            acc |= ns;
+        }
+        any[t] = acc != 0;
+    };
+
+    if (n_threads == 1) {
+        work(0);
+    } else {
+        std::vector<std::thread> threads;
+        for (int t = 0; t < n_threads; t++) threads.emplace_back(work, t);
+        for (auto& th : threads) th.join();
+    }
+    if (n_pad > n) {
+        size_t pad = (size_t)(n_pad - n) * sizeof(int32_t);
+        if (out_sec) std::memset(out_sec + n, 0, pad);
+        if (out_ns) std::memset(out_ns + n, 0, pad);
+    }
+    for (int t = 0; t < n_threads; t++)
+        if (any[t]) return 1;
+    return 0;
+}
+
 }  // extern "C"
