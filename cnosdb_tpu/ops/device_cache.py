@@ -185,15 +185,18 @@ class DeviceBatch:
 
     def rank_dev(self):
         """The device's time-order rank, built by the first first/last
-        query of the batch (booked as upload where it happens) and kept.
-        Queries that ask at the same moment get the one array: the sort
-        runs once under the batch's lock; the put — device dispatch, so
-        outside it — may be raced, and the first one is published."""
+        query of the batch and kept: the sort is `upload.rank_ms` inside
+        `upload.meta_ms`, its pad and put `upload.stage_ms` and
+        `upload.put_ms`, all inside `upload_ms`. Queries that ask at the
+        same moment get the one array: the sort runs once under the
+        batch's lock; the put — device dispatch, so outside it — may be
+        raced, and the first one is published."""
         if self.rank is None:
             with stages.stage("upload_ms"):
                 with self._rank_lock:
                     if self._rank_np is None:
-                        with stages.stage("upload.meta_ms"):
+                        with stages.stage("upload.meta_ms"), \
+                                stages.stage("upload.rank_ms"):
                             self._rank_np = _time_rank(self._ts)
                         stages.count("upload.rank_builds")
                 rank = _put_padded(self._rank_np, self.n_pad)

@@ -213,6 +213,9 @@ def launch_fused(dbatch: DeviceBatch, filter_expr: Expr | None,
     stages.count("fused.row_gathers", int(_row_gathers(
         {n: dbatch.fields[n][1].dtype for n in present if n in col_wants},
         col_wants, run_pad, regular)))
+    stages.count("fused.rank_columns", sum(
+        1 for n in present if n in col_wants
+        and (col_wants[n].get("want_first") or col_wants[n].get("want_last"))))
 
     ns = max(dbatch.n_series, 1)
     gos = np.zeros(ns, dtype=np.int32)

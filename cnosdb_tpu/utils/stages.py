@@ -149,8 +149,11 @@ STAGE_CATALOG: dict[str, str] = {
     "upload.meta_ms": "inside upload_ms: DeviceBatch._init_meta less its "
                       "pads and puts — the one-pass i32 (sec, ns) split "
                       "— and, where a first/last query first asks "
-                      "(rank_dev), the argsort and scatter of the rank "
-                      "(host arithmetic over every row)",
+                      "(rank_dev), the rank's sort (upload.rank_ms)",
+    "upload.rank_ms": "inside upload.meta_ms: the first/last time-order "
+                      "rank's stable argsort and scatter over every row "
+                      "of the batch, where rank_dev() builds it (host "
+                      "arithmetic; absent where no first/last query asks)",
     "upload.rank_builds": "first/last time-order ranks sorted and put: 0 "
                           "at every DeviceBatch's build, 1 where a query's "
                           "aggregates first ask for the rank (0 for avg / "
@@ -172,6 +175,11 @@ STAGE_CATALOG: dict[str, str] = {
                          "(first / last, a floating reduction, no run "
                          "bound, the regular mode): 1 such a launch, 0 "
                          "one that maps only its run ends",
+    "fused.rank_columns": "aggregated columns a fused launch reduces by "
+                          "rank selection (first / last: a segment max "
+                          "or min of the rank, its gather a row, the "
+                          "selected values' sum); booked at every fused "
+                          "launch, 0 where none asks",
     "segment_runs.engaged": "device segment reductions (fused launches, "
                             "segment_aggregate calls, mesh merge programs) "
                             "that reduced contiguous equal-segment runs",

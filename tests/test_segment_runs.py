@@ -386,13 +386,15 @@ def _series_major_batch(n_series, rows_per):
     ("max", 2000, 0),          # 80 000 rows, 40 series × 67 buckets: by runs
     ("avg", 2000, 0),
     ("last", 2000, 1),         # the rank scatters read per-row ids
+    ("first", 2000, 1),
     ("max", 100, 1),           # 4 000 rows: run_pad 0, the row scatter
 ])
 def test_fused_row_gathers_says_which_program_ran(monkeypatch, func,
                                                   rows_per, booked):
     """`fused.row_gathers` is booked at every fused launch: 0 where the
     program maps only its run ends, 1 where it gathers a group id for
-    every row — and the answer is the host's either way."""
+    every row — and the answer is the host's either way. Beside it
+    `fused.rank_columns`: the columns selected by rank (first / last)."""
     from cnosdb_tpu.ops.tpu_exec import (AggSpec, TpuQuery,
                                          execute_scan_aggregate)
 
@@ -414,6 +416,7 @@ def test_fused_row_gathers_says_which_program_ran(monkeypatch, func,
     assert answers[0] == answers[1]
     assert prof.counts["fused_launches"] == 1
     assert prof.counts["fused.row_gathers"] == booked
+    assert prof.counts["fused.rank_columns"] == int(func in ("first", "last"))
     # the run path engaged wherever the program has one
     assert prof.counts.get("segment_runs.engaged") == (
         None if rows_per == 100 else 1)

@@ -231,8 +231,11 @@ def test_first_and_last_build_the_rank_once(func, monkeypatch):
     assert dev == host
     assert batch._device_batch is db                 # the cached twin
     assert prof.counts["upload.rank_builds"] == 1
-    # the sort is still booked as upload, where it happens
-    assert prof.ms["upload.meta_ms"] <= prof.ms["upload_ms"]
+    # the sort is still booked as upload, where it happens: its own stage
+    # inside upload.meta_ms, and the pad and put beside it as every put's
+    assert 0 < prof.ms["upload.rank_ms"] <= prof.ms["upload.meta_ms"] \
+        <= prof.ms["upload_ms"]
+    assert "upload.stage_ms" in prof.ms and prof.ms["upload.put_ms"] > 0
     assert np.array_equal(db._rank_np, _eager_rank(batch.ts))
     on_device = np.asarray(db.rank)
     assert on_device.dtype == np.int32 and on_device.shape == (db.n_pad,)
