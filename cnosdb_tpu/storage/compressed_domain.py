@@ -10,6 +10,13 @@ aggregate plan:
   skip    a conjunct is provably false for every row (or the conjunct
           column is absent ⇒ all-NULL ⇒ fails) — the page leaves the plan:
           never fetched, never decoded, zero rows.
+            dominated  no conjunct, and every first/last the page would
+                    answer is outranked: another planned page of the
+                    series (and bucket) proves a surviving non-NULL row
+                    strictly newer (last) / older (first) than any of
+                    this page's, from its time stats and a NULL-free
+                    column page. Nothing is read and no partial folds;
+                    compressed.jobs_dominated counts the jobs not queued.
   answer  every projected aggregate is computable without materializing
           the page's value rows:
             meta    pure PageMeta algebra — count from n_rows/n_values,
@@ -437,6 +444,14 @@ def _stat_value(vt: ValueType, v):
 _DELTA_ENCODINGS = (int(Encoding.DELTA), int(Encoding.DELTA_TS))
 
 
+def _in_ranges(ts: np.ndarray, trs) -> np.ndarray:
+    """→ bool over `ts`: inside one of the scan's time ranges."""
+    from .scan import _time_mask
+
+    got = _time_mask(ts, trs)
+    return np.ones(len(ts), dtype=bool) if got is None else got
+
+
 class ScanLane:
     """Per-(vnode scan, query) lane state: classify pages out of the
     native plan, collect meta partials, run deferred closed-form jobs
@@ -455,6 +470,7 @@ class ScanLane:
         self.pages_answered = 0
         self.pages_skipped = 0
         self.pages_masked = 0
+        self.jobs_dominated = 0       # first/last jobs outranked, not queued
         self.bytes_materialized = 0   # job page bytes the lane DID read
 
     # -- plan filtering ---------------------------------------------------
@@ -467,23 +483,94 @@ class ScanLane:
     def has_masks(self) -> bool:
         return bool(self.mask_pages)
 
-    def filter_pages(self, inside: np.ndarray, page_at) -> np.ndarray:
-        """Classify the scan's planned pages → bool over them, False
-        where a page leaves the plan (skipped or answered). `inside[k]`:
-        page k lies inside one of the query's time ranges; `page_at(k)`
-        → (sid, reader, ChunkMeta, {query column: ColumnMeta}, the
-        page's position in the chunk)."""
+    def filter_pages(self, pages, pfiles, page_at) -> np.ndarray:
+        """Classify the scan's planned pages (storage/scan's _Pages over
+        its _PlanFile list) → bool over them, False where a page leaves
+        the plan (skipped or answered). `pages.inside[k]`: page k lies
+        inside one of the query's time ranges; `page_at(k)` → (sid,
+        reader, ChunkMeta, {query column: ColumnMeta}, the page's
+        position in the chunk)."""
+        inside = pages.inside
         keep = np.ones(len(inside), dtype=bool)
         # rows outside the query's time ranges can't be answered away:
         # the page must materialize so assembly's trim drops them
         n_trim = len(inside) - int(np.count_nonzero(inside))
         if n_trim:
             count_outcome("mat", "trim", n_trim)
+        beaten = self._outranked(pages, pfiles)
         for k in np.flatnonzero(inside).tolist():
-            keep[k] = not self._classify(*page_at(k))
+            keep[k] = not self._classify(*page_at(k),
+                                         beaten.get(k, frozenset()))
         return keep
 
-    def _classify(self, sid, r, cm, cols, i) -> int:
+    def _outranked(self, pages, pfiles) -> dict:
+        """Time dominance for first / last, over EVERY planned page (cut
+        ones too) → {planned page: the (func, column) pairs whose answer
+        there provably loses}.
+
+        A page proves a timestamp of its series (and, bucketed, of that
+        timestamp's bucket) when the column's page there holds no NULL
+        and the page's t_max (else t_min) lies inside a time range: that
+        row survives the scan with a value. With no conjunct every
+        surviving row counts, so a page whose max_ts is strictly below
+        the newest proven timestamp (min_ts strictly above the oldest,
+        for first) cannot give the answer. Empty where the spec asks no
+        first / last or has a conjunct."""
+        spec = self.spec
+        ranked = sorted({(func, col) for func, col, _a in spec.aggs
+                         if func in ("first", "last")})
+        if not ranked or spec.conjuncts or not len(pages):
+            return {}
+        n = len(pages)
+        t_min = np.empty(n, dtype=np.int64)
+        t_max = np.empty(n, dtype=np.int64)
+        full = {col: np.zeros(n, dtype=bool) for _f, col in ranked}
+        for fi, f in enumerate(pfiles):
+            at = pages.file == fi
+            page, chunk = pages.page[at], pages.chunk[at]
+            t_min[at] = f.index.t_min[page]
+            t_max[at] = f.index.t_max[page]
+            for col, ok in full.items():
+                ci = f.cols.get(col)
+                if ci is None:
+                    continue
+                n_rows, n_values = ci.desc[page, 3], ci.desc[page, 5]
+                ok[at] = (n_rows > 0) & (n_values == n_rows) \
+                    & ci.present[chunk] \
+                    & (ci.vt0[chunk] == int(spec.col_types[col]))
+        in_min, in_max = _in_ranges(t_min, self.trs), \
+            _in_ranges(t_max, self.trs)
+        series = pages.series.astype(np.int64)
+        out = np.zeros((n, len(ranked)), dtype=bool)
+        for j, (func, col) in enumerate(ranked):
+            last = func == "last"
+            proves = full[col] & (in_min | in_max)
+            if last:
+                s = np.where(in_max, t_max, t_min)
+                bar_init, reduce = np.iinfo(np.int64).min, np.maximum
+            else:
+                s = np.where(in_min, t_min, t_max)
+                bar_init, reduce = np.iinfo(np.int64).max, np.minimum
+            if spec.bucket is None:
+                own, mine, n_groups = series, series, int(series.max()) + 1
+            else:
+                # a proven timestamp counts in its own bucket only: the
+                # lane's partial key (sid, bts); a page asks in the
+                # bucket of its min_ts (one that straddles materializes)
+                origin, interval = spec.bucket
+                bucket = (np.concatenate((s, t_min)) - origin) // interval
+                pairs = np.stack((np.concatenate((series, series)), bucket),
+                                 axis=1)
+                groups, ids = np.unique(pairs, axis=0, return_inverse=True)
+                ids = ids.reshape(-1)
+                own, mine, n_groups = ids[:n], ids[n:], len(groups)
+            bar = np.full(n_groups, bar_init, dtype=np.int64)
+            reduce.at(bar, own[proves], s[proves])
+            out[:, j] = t_max < bar[mine] if last else t_min > bar[mine]
+        return {k: frozenset(ranked[j] for j in np.flatnonzero(out[k]))
+                for k in np.flatnonzero(out.any(axis=1)).tolist()}
+
+    def _classify(self, sid, r, cm, cols, i, outranked) -> int:
         """Classify page i of a chunk, one inside a time range → the
         rows it takes out of the plan (0: it must materialize)."""
         spec = self.spec
@@ -531,7 +618,7 @@ class ScanLane:
             return _mat("pred_mixed")
 
         # ---- all conjuncts TRUE: try to answer every aggregate
-        return self._answer(sid, r, cm, cols, i, tp, _mat)
+        return self._answer(sid, r, cm, cols, i, tp, _mat, outranked)
 
     def _col_mixed(self, r, cols, i, colname, cons) -> bool:
         pm = cols[colname].pages[i]
@@ -608,7 +695,10 @@ class ScanLane:
         return verdict
 
     # -- aggregate answering ---------------------------------------------
-    def _answer(self, sid, r, cm, cols, i, tp, _mat) -> int:
+    def _answer(self, sid, r, cm, cols, i, tp, _mat, outranked) -> int:
+        """Answer page i from its metadata and deferred closed forms →
+        the rows it takes out of the plan. `outranked`: the (func,
+        column) first / last pairs another planned page beats here."""
         spec = self.spec
         straddle = False
         bts = None
@@ -622,6 +712,7 @@ class ScanLane:
         meta_parts: list[tuple] = []    # (func, alias, value)
         job_aggs: list[tuple] = []      # (func, col, alias, pm, evt)
         count_aliases: list[str] = []   # straddle counts (per-bucket job)
+        n_outranked = 0
         for func, col, alias in spec.aggs:
             colmeta = cols.get(col) if col is not None else None
             pm = colmeta.pages[i] if colmeta is not None else None
@@ -673,9 +764,18 @@ class ScanLane:
                 return _mat("string_agg")
             if tp.encoding not in _DELTA_ENCODINGS:
                 return _mat("encoding")
+            if (func, col) in outranked:
+                n_outranked += 1    # its answer would lose: no job
+                continue
             job_aggs.append((func, col, alias, pm, evt))
         if count_aliases and tp.encoding not in _DELTA_ENCODINGS:
             return _mat("encoding")
+        self.jobs_dominated += n_outranked
+        if n_outranked and not (meta_parts or job_aggs or count_aliases):
+            # nothing left to contribute: the page leaves the plan unread
+            count_outcome("skip", "dominated")
+            self.pages_skipped += 1
+            return tp.n_rows
 
         # answered: remove the page from the plan, book its contribution
         self.pages_answered += 1
@@ -885,3 +985,5 @@ class ScanLane:
         batch._compressed_engaged = self.engaged
         if self.pages_answered:
             stages.count("compressed.pages_answered", self.pages_answered)
+        # booked at every lane, 0 where none, so the key is always there
+        stages.count("compressed.jobs_dominated", self.jobs_dominated)

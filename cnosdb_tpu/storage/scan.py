@@ -1137,7 +1137,7 @@ def _scan_vnode_native(vnode: VnodeCut, table: str,
                     f.chunk_cols(c), i)
 
         with stages.stage("compressed_ms"):
-            keep = lane.filter_pages(pages.inside, lane_page)
+            keep = lane.filter_pages(pages, pfiles, lane_page)
         if lane.engaged:
             any_pruned = True
             pages.take(keep)

@@ -221,6 +221,11 @@ STAGE_CATALOG: dict[str, str] = {
     "compressed.pages_answered": "pages whose aggregate contribution "
                                  "came from stats/closed forms — never "
                                  "decoded into rows",
+    "compressed.jobs_dominated": "first / last closed-form jobs (page, "
+                                 "column) the lane did not queue: another "
+                                 "planned page proves a newer (last) or "
+                                 "older (first) surviving value; booked "
+                                 "at every lane, 0 where none",
     "compressed.bytes_materialized": "page bytes that DID enter a decode "
                                      "lane (the ≥5× drop the lane exists "
                                      "to produce on selective scans)",

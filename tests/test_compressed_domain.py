@@ -392,6 +392,166 @@ def test_sql_parity_unflushed_memcache_unaffected(db, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# time dominance: a first / last page another planned page outranks
+# ---------------------------------------------------------------------------
+STEP = 54_000_000_000            # 200 rows = 3 h, BASE is 3 h-aligned
+N_ROWS, CHUNK = 300, 100         # three flushes: three chunks a host
+
+
+def _t(i: int) -> int:
+    return BASE + i * STEP
+
+
+@pytest.fixture
+def chunked(db):
+    """Two hosts, three chunks each (rows 0-99, 100-199, 200-299, one
+    page a chunk); `c` is NULL on rows 100-149. → (executor, the written
+    values: host → column → list, None for NULL)."""
+    ex, engine = db
+    ex.execute_one("CREATE TABLE d (a BIGINT, b DOUBLE, c BIGINT, "
+                   "TAGS(host))")
+    r = np.random.default_rng(5)
+    data = {h: {"a": [], "b": [], "c": []} for h in ("h0", "h1")}
+    for lo in range(0, N_ROWS, CHUNK):
+        rows = []
+        for i in range(lo, lo + CHUNK):
+            for h, cols in data.items():
+                cols["a"].append(int(r.integers(-1000, 1000)))
+                cols["b"].append(round(float(r.normal()), 3))
+                cols["c"].append(None if 100 <= i < 150
+                                 else int(r.integers(-99, 99)))
+                rows.append(f"({_t(i)}, '{h}', {cols['a'][-1]}, "
+                            f"{cols['b'][-1]}, "
+                            f"{'NULL' if cols['c'][-1] is None else cols['c'][-1]})")
+        ex.execute_one("INSERT INTO d (time, host, a, b, c) VALUES "
+                       + ", ".join(rows))
+        engine.flush_all(sync=True)
+    return ex, data
+
+
+def _reference(data, aggs, lo, hi, bucket_rows=None):
+    """The plain answer: per host (and bucket of `bucket_rows` rows) each
+    (func, column)'s oldest / newest non-NULL value of rows [lo, hi)."""
+    out = []
+    for h, cols in data.items():
+        edges = range(lo, hi) if bucket_rows is None else \
+            range(lo - lo % bucket_rows, hi, bucket_rows)
+        for b0 in ([lo] if bucket_rows is None else edges):
+            b1 = hi if bucket_rows is None else min(hi, b0 + bucket_rows)
+            row = []
+            for func, col in aggs:
+                got = [cols[col][i] for i in range(max(lo, b0), b1)
+                       if cols[col][i] is not None]
+                row.append(got[-1 if func == "last" else 0] if got else None)
+            out.append(((h,) if bucket_rows is None else (_t(b0), h))
+                       + tuple(row))
+    return _norm(out)
+
+
+# (name, aggs, rows [lo, hi), bucketed?, extra conjunct, jobs dominated,
+#  pages answered)
+DOMINANCE = [
+    # the bound cuts chunk 2, whose first row proves a and b: chunk 1's
+    # a and b lose; c is NULL there, so chunk 1 still answers c
+    ("last_null_in_cut_page", (("last", "a"), ("last", "b"),
+                               ("last", "c")), (0, 150), False, None, 4, 2),
+    # a lower bound cutting chunk 1: its last row outranks chunks 2, 3
+    ("first_lower_bound", (("first", "a"), ("first", "b")),
+     (50, N_ROWS), False, None, 8, 0),
+    # chunk 3 (bucket 1) proves nothing for bucket 0: only chunk 1 loses,
+    # to chunk 2 — which the same query unbucketed also prunes
+    ("last_bucketed", (("last", "a"),), (0, 250), True, None, 2, 2),
+    ("last_unbucketed", (("last", "a"),), (0, 250), False, None, 4, 0),
+    # a conjunct: rows may fail it, so nothing is proven
+    ("last_with_conjunct", (("last", "a"),), (0, 250), False,
+     "a > -100000", 0, 4),
+]
+
+
+@pytest.mark.parametrize("case", DOMINANCE, ids=[c[0] for c in DOMINANCE])
+def test_outranked_first_last_pages_leave_the_plan(chunked, monkeypatch,
+                                                   case):
+    from cnosdb_tpu.utils import stages
+
+    ex, data = chunked
+    _name, aggs, (lo, hi), bucketed, conjunct, dominated, answered = case
+    where = [f"time >= {_t(lo)}" if lo else None,
+             f"time < {_t(hi)}" if hi < N_ROWS else None, conjunct]
+    sel = ", ".join(f"{f}({c})" for f, c in aggs)
+    q = ("SELECT " + ("time_bucket(time, '3h') AS t, " if bucketed else "")
+         + f"host, {sel} FROM d WHERE "
+         + " AND ".join(w for w in where if w)
+         + " GROUP BY " + ("t, host" if bucketed else "host"))
+    prof = stages.QueryProfile()
+    with stages.profile_scope(prof):
+        got = _norm(ex.execute_one(q).rows())
+    assert got == _reference(data, aggs, lo, hi, 200 if bucketed else None)
+    assert prof.counts["compressed.jobs_dominated"] == dominated
+    assert prof.counts.get("compressed.pages_answered", 0) == answered
+    monkeypatch.setenv("CNOSDB_COMPRESSED_DOMAIN", "0")
+    assert _norm(ex.execute_one(q).rows()) == got
+
+
+def _series_vnode(tmp_path):
+    """One series, val == row index at ts == 10 × row, three chunks of
+    100 rows (ts 0-990, 1000-1990, 2000-2990)."""
+    v = VnodeStorage(1, str(tmp_path / "vn"), schemas=_cold_schema())
+    for lo in range(0, 300, 100):
+        wb = WriteBatch()
+        wb.add_series("cpu", SeriesRows(
+            SeriesKey("cpu", {"host": "h1"}),
+            [10 * i for i in range(lo, lo + 100)],
+            {"val": (int(ValueType.INTEGER), list(range(lo, lo + 100)))}))
+        v.write(wb)
+        v.flush()
+    return v
+
+
+def _scan_last(v, trs, spec):
+    """→ (the newest (ts, val) of the scan, partials and rows alike,
+    compressed.jobs_dominated)."""
+    from cnosdb_tpu.utils import stages
+
+    prof = stages.QueryProfile()
+    with stages.profile_scope(prof):
+        b = scan_vnode(v, "cpu", time_ranges=trs, field_names=["val"],
+                       compressed_spec=spec)
+    cands = []
+    cp = getattr(b, "compressed_partials", None)
+    for parts in (cp["rows"].values() if cp else ()):
+        if "l" in parts:
+            cands.append((int(parts["l__ts"]), int(parts["l"])))
+    if b.n_rows:
+        _vt, vals, valid = b.fields["val"]
+        k = np.flatnonzero(np.asarray(valid))[-1]
+        cands.append((int(b.ts[k]), int(vals[k])))
+    return max(cands), prof.counts.get("compressed.jobs_dominated")
+
+
+@pytest.mark.parametrize("ranges,want,dominated", [
+    # chunk 2 is admitted (it meets the second range) but both its
+    # endpoints lie in gaps and no row of it survives: it proves nothing,
+    # and chunk 1 answers
+    (((0, 990), (1505, 1506)), (990, 99), 0),
+    # chunk 2's t_max lies in the gap: it proves its t_min (1000), which
+    # outranks chunk 1; chunk 3's endpoints both lie in gaps
+    (((0, 1200), (2500, 2600)), (2600, 260), 1),
+])
+def test_disjoint_ranges_prove_by_an_endpoint_inside_only(
+        tmp_path, ranges, want, dominated):
+    from cnosdb_tpu.models.predicate import TimeRange, TimeRanges
+
+    v = _series_vnode(tmp_path)
+    trs = TimeRanges([TimeRange(a, b) for a, b in ranges])
+    spec = cd.CompressedSpec((("last", "val", "l"),), None, {},
+                             {"val": ValueType.INTEGER})
+    assert _scan_last(v, trs, spec) == (want, dominated)
+    # the decode lanes alone: the same newest row
+    b = scan_vnode(v, "cpu", time_ranges=trs, field_names=["val"])
+    assert (int(b.ts[-1]), int(b.fields["val"][1][-1])) == want
+
+
+# ---------------------------------------------------------------------------
 # cold tier: answered pages are never downloaded
 # ---------------------------------------------------------------------------
 def _cold_schema():

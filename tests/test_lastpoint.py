@@ -52,6 +52,12 @@ def _sql(hi: int) -> str:
             + f" FROM cpu WHERE time < {hi} GROUP BY hostname")
 
 
+def _first_sql(lo: int) -> str:
+    return ("SELECT hostname, "
+            + ", ".join(f"first({f}) AS {f}" for f in devops.FIELDS)
+            + f" FROM cpu WHERE time >= {lo} GROUP BY hostname")
+
+
 def _profiled(node, sql: str) -> tuple[str, dict]:
     text, hdrs = node.sql(sql, {"X-CnosDB-Profile": "1"})
     return text, json.loads(hdrs["X-CnosDB-Profile-Summary"])["stages"]
@@ -86,13 +92,20 @@ class _Store:
     def last(self, hi: int) -> dict:
         """hostname → each field's newest non-NULL value below `hi`: a
         host's rows read newest first, one field at a time."""
-        k1 = int(np.searchsorted(self.ts, hi))
+        return self._pick(0, int(np.searchsorted(self.ts, hi)), -1)
+
+    def first(self, lo: int) -> dict:
+        """hostname → each field's oldest non-NULL value at or after
+        `lo`."""
+        return self._pick(int(np.searchsorted(self.ts, lo)), len(self.ts), 0)
+
+    def _pick(self, k0: int, k1: int, at: int) -> dict:
         out = {}
         for h in range(self.ds.hosts):
             row = []
             for f in range(F):
-                present = np.flatnonzero(self.valid[f, h, :k1])
-                row.append(int(self.vals[f, h, present[-1]])
+                present = k0 + np.flatnonzero(self.valid[f, h, k0:k1])
+                row.append(int(self.vals[f, h, present[at]])
                            if len(present) else None)
             if any(v is not None for v in row):
                 out[self.ds.hostnames[h]] = row
@@ -115,6 +128,80 @@ def node(tmp_path_factory):
     finally:
         h.close()
         mp.undo()
+
+
+# the cell's layout: the load fills the memcache past a flush, so each
+# host has an older chunk wholly below every drawn bound and a newer one
+# the bound cuts
+TWO_HOSTS, TWO_STEPS, TWO_FLUSH = 40, 720, 420
+
+
+@pytest.fixture(scope="module")
+def two_chunks(tmp_path_factory):
+    h, mp, n = _start(_ENV, tmp_path_factory, "lastpoint_two_chunks",
+                      devops.Dataset(SEED + 2, TWO_HOSTS, TWO_STEPS),
+                      flush_at=TWO_FLUSH)
+    try:
+        yield n
+    finally:
+        h.close()
+        mp.undo()
+
+
+def test_an_outranked_chunk_is_never_read(two_chunks):
+    """With two chunks a host, each drawn bound cuts the newer one, whose
+    first row lies below the bound with all ten fields: it outranks the
+    older chunk's page in every field. That page leaves the plan unread —
+    `compressed.jobs_dominated` hosts × 10, no page answered, no partial
+    to merge — and each answer is still the store's."""
+    node = two_chunks
+    gen = devops.ClassGenerator(LASTPOINT, node.ds,
+                                np.random.default_rng([SEED, 5]))
+    store = _Store(node.ds, 0)
+    for _ in range(3):
+        req = gen.draw()
+        hi = req.spec["hi"]
+        assert node.ds.step_ns(TWO_FLUSH) < hi
+        st = node.ask(req)
+        assert _answer(st["answer"]) == store.last(hi)
+        assert st["compressed.jobs_dominated"] == TWO_HOSTS * F, st
+        assert st.get("compressed.pages_answered", 0) == 0, st
+        assert "merge_ms" not in st and st["fused.rank_columns"] == F
+
+    # first() under a lower bound that cuts the older chunk: its last
+    # row outranks the newer chunk's page in every field
+    lo = node.ds.step_ns(TWO_FLUSH // 2) + 1
+    text, st = _profiled(node, _first_sql(lo))
+    rows = list(csv.reader(io.StringIO(text)))
+    got = {r[0]: [int(c) for c in r[1:]] for r in rows[1:]}
+    assert got == store.first(lo)
+    assert st["compressed.jobs_dominated"] == TWO_HOSTS * F, st
+    assert st.get("compressed.pages_answered", 0) == 0, st
+
+
+def test_a_null_in_the_cut_page_proves_nothing_for_its_field(two_chunks):
+    """A host's newest rows, flushed into a third chunk the bound cuts:
+    its first row lacks usage_idle. That page proves the other nine
+    fields' newer values, so the second chunk's page answers usage_idle
+    alone — the value of its last row — and every other host's older
+    chunk is outranked by its second as before."""
+    node = two_chunks
+    store = _Store(node.ds, 2)
+    k = TWO_STEPS
+    partial = {f: 50 + i for i, f in enumerate(devops.FIELDS)
+               if f != "usage_idle"}
+    store.write(node, k, 3, partial)
+    store.write(node, k + 1, 3, {f: 2 for f in devops.FIELDS})
+    node.sql("FLUSH")
+    hi = int(store.ts[k]) + 1
+    text, st = _profiled(node, _sql(hi))
+    want = store.last(hi)
+    idle = devops.FIELDS.index("usage_idle")
+    assert want["host_3"][idle] == node.ds.v[idle, 3, k - 1]
+    assert _answer(text) == want
+    # every host's first chunk (all ten fields), host 3's second (nine)
+    assert st["compressed.jobs_dominated"] == TWO_HOSTS * F + F - 1, st
+    assert st["compressed.pages_answered"] == TWO_HOSTS, st
 
 
 def test_the_status_board_is_the_references(node):

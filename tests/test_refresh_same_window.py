@@ -76,9 +76,11 @@ class _Node:
         return {**st, "answer": text}
 
 
-def _start(env: dict, tmp_path_factory, name: str, ds) -> tuple:
+def _start(env: dict, tmp_path_factory, name: str, ds,
+           flush_at: int | None = None) -> tuple:
     """A served node with `env` set before it starts, `ds` loaded over
-    /api/v1/write a batch at a time and flushed."""
+    /api/v1/write a batch at a time and flushed — also once the steps
+    before `flush_at` are in, where given, so each series has two chunks."""
     from test_deadline import _Harness
 
     mp = pytest.MonkeyPatch()
@@ -93,6 +95,8 @@ def _start(env: dict, tmp_path_factory, name: str, ds) -> tuple:
         status, body, _ = h.request("POST", "/api/v1/write?db=public",
                                     ds.lines(k, min(k + 30, ds.steps)).decode())
         assert status == 200, body          # acknowledged before the next
+        if flush_at is not None and k < flush_at <= k + 30:
+            node.sql("FLUSH")
     node.sql("FLUSH")
     text, _ = node.sql("SELECT count(*) FROM cpu")
     assert int(text.splitlines()[1]) == ds.hosts * ds.steps
